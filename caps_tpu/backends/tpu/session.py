@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from caps_tpu import obs
 from caps_tpu.backends.tpu.table import DeviceBackend, DeviceTableFactory
+from caps_tpu.ops import default_interpret
 from caps_tpu.obs import clock
 from caps_tpu.okapi.config import DEFAULT_CONFIG
 from caps_tpu.relational.session import (RelationalCypherSession,
@@ -168,7 +169,8 @@ class TPUCypherSession(RelationalCypherSession):
 
     def metrics_snapshot(self) -> dict:
         """Session snapshot extended with the device backend's counters
-        (communication accounting, fallbacks, size syncs) and the fused
+        (communication accounting, fallbacks, size syncs, Pallas kernel
+        launches per family and whether they ran compiled) and the fused
         executor's record/replay stats — the scattered stats the obs
         registry absorbs (ISSUE 3 tentpole)."""
         snap = super().metrics_snapshot()
@@ -181,6 +183,11 @@ class TPUCypherSession(RelationalCypherSession):
             "backend.salted_joins": be.salted_joins,
             "backend.fallbacks": be.fallbacks,
             "backend.syncs": be.syncs,
+            "backend.kernel.expand": be.kernel_launches["expand"],
+            "backend.kernel.segment": be.kernel_launches["segment"],
+            "backend.kernel.sort": be.kernel_launches["sort"],
+            # compiled on TPU, Pallas interpret mode elsewhere
+            "backend.kernels_compiled": int(not default_interpret()),
             "fused.recordings": self.fused.recordings,
             "fused.replays": self.fused.replays,
             "fused.generic_replays": self.fused.generic_replays,

@@ -1,12 +1,16 @@
 """Lazy loader for the native host runtime (native/csrc/host_runtime.cpp).
 
-Compiles the CPython extension with g++ on first import (cached by source
-mtime), imports it, and exposes it as ``native.lib``; ``lib is None`` means
-no toolchain — callers fall back to pure Python.  Opt out with
-``CAPS_TPU_NO_NATIVE=1`` (useful for differential tests).
+Compiles the CPython extension with g++ on first import, imports it, and
+exposes it as ``native.lib``.  The built file's name carries a hash of the
+source's content, so what loads was built from the ``host_runtime.cpp``
+beside it — a binary left over from another source is never picked up.
+``lib is None`` means no toolchain (``build_error`` says why) — callers
+fall back to pure Python.  Opt out with ``CAPS_TPU_NO_NATIVE=1`` (useful
+for differential tests).
 """
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import os
 import subprocess
@@ -23,16 +27,23 @@ lib = None
 build_error: str | None = None
 
 
+def source_hash() -> str:
+    """Content hash of the C++ source (12 hex digits of its SHA-256)."""
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()[:12]
+
+
 def _so_path() -> str:
     tag = sysconfig.get_config_var("SOABI") or "none"
-    return os.path.join(_BUILD_DIR, f"_caps_host.{tag}.so")
+    return os.path.join(_BUILD_DIR,
+                        f"_caps_host.{source_hash()}.{tag}.so")
 
 
 def _build(so: str) -> None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
     include = sysconfig.get_paths()["include"]
     # build to a temp path + atomic rename: an interrupted link must not
-    # leave a fresh-mtime corrupt .so that disables the runtime forever
+    # leave a corrupt .so under the final name
     tmp = f"{so}.tmp.{os.getpid()}"
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
            f"-I{include}", _SRC, "-o", tmp]
@@ -54,8 +65,7 @@ def _load():
         return
     so = _so_path()
     try:
-        if (not os.path.exists(so)
-                or os.path.getmtime(so) < os.path.getmtime(_SRC)):
+        if not os.path.exists(so):
             _build(so)
         spec = importlib.util.spec_from_file_location("_caps_host", so)
         mod = importlib.util.module_from_spec(spec)

@@ -54,11 +54,9 @@ class EngineConfig:
         default_factory=lambda: _env_bool("CAPS_TPU_USE_PALLAS", True))
     # Bitonic sort-permutation kernel (ops/sort.py) for order_by /
     # distinct / group sorts on supported tile capacities (compiled TPU
-    # only; rides use_pallas + the probe's "sort" family).  Default ON:
-    # validated on live TPU v5e 2026-07-31 (``python -m
-    # caps_tpu.ops.sort_validate``: 18 compiled cases, 0 failures —
-    # recorded in TUNNEL_r05.md probe #6).  CAPS_TPU_SORT_KERNEL=0
-    # restores the lax.sort path.
+    # only; rides use_pallas + the "sort" family of
+    # ops/kernel_table.py).  CAPS_TPU_SORT_KERNEL=0 restores the
+    # lax.sort path.
     use_sort_kernel: bool = dataclasses.field(
         default_factory=lambda: _env_bool("CAPS_TPU_SORT_KERNEL", True))
     # HBM-resident CSR adjacency as the relationship scan's physical
@@ -153,13 +151,6 @@ class EngineConfig:
     # downstream relation-checked consume guards raises at query end.
     debug_obj_guard: bool = dataclasses.field(
         default_factory=lambda: _env_bool("CAPS_TPU_DEBUG_OBJ_GUARD", False))
-    # Persistent XLA compilation cache directory ("" = disabled).  Repeat
-    # processes skip device compiles entirely — on remote-compile
-    # transports this turns a ~100 s cold start into seconds.
-    compile_cache_dir: str = dataclasses.field(
-        default_factory=lambda: os.environ.get(
-            "CAPS_TPU_COMPILE_CACHE_DIR",
-            os.path.join(os.path.expanduser("~"), ".cache", "caps_tpu_xla")))
     # Determinism check (SURVEY.md §5.2): run each query twice and compare
     # result digests; raises NondeterministicResultError on mismatch.
     determinism_check: bool = dataclasses.field(

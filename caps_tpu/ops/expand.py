@@ -49,10 +49,22 @@ from jax.experimental.pallas import tpu as pltpu
 # expand positions: invert offsets = cumsum(counts) for every output slot
 # ---------------------------------------------------------------------------
 
+# Output slots per grid step.  Fixed at 1,024: the input windows are 1-D
+# blocks whose block index is data-dependent (scalar prefetch), and for
+# those Mosaic accepts only the full 1,024-element tile of a 1-D 32-bit
+# operand — 256 and 512 are refused when compiling for v5e ("Input
+# offsets outside of the first tile").  Same rule as ops/segment.py.
+TILE = 1024
+
 
 def _expand_kernel(blk_ref, seg_base_ref, total_ref,
                    offs_a, offs_b, lo_a, lo_b, orig_a, orig_b,
                    l_out, pos_out, valid_out, *, tile: int):
+    # int32 literal, not a Python 0: the engine runs with x64 on, where a
+    # bare 0 reaches jnp.where as a weak i64 scalar and its convert back
+    # to i32 recurses without end in the Mosaic lowering (RecursionError
+    # in _convert_helper when compiling for v5e, jax 0.9.0)
+    zero = jnp.int32(0)
     i = pl.program_id(0)
     t = i * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)  # (1,T)
     offs = jnp.concatenate([offs_a[:], offs_b[:]]).reshape(2 * tile, 1)
@@ -62,15 +74,16 @@ def _expand_kernel(blk_ref, seg_base_ref, total_ref,
     # the prelude-computed base when the window has no hit (cnt == 0 can
     # only happen when the tile starts exactly at a block boundary, in
     # which case seg_base IS offsets[l_idx-1]).
-    seg = jnp.max(jnp.where(le, offs, 0), axis=0)
+    seg = jnp.max(jnp.where(le, offs, zero), axis=0)
     seg = jnp.maximum(seg, seg_base_ref[i])
     # one-hot select of lo / original-row at window position cnt
     w = jax.lax.broadcasted_iota(jnp.int32, (2 * tile, tile), 0)
     onehot = w == cnt.reshape(1, tile)
     lo_win = jnp.concatenate([lo_a[:], lo_b[:]]).reshape(2 * tile, 1)
     orig_win = jnp.concatenate([orig_a[:], orig_b[:]]).reshape(2 * tile, 1)
-    lo_t = jnp.sum(jnp.where(onehot, lo_win, 0), axis=0, dtype=jnp.int32)
-    orig_t = jnp.sum(jnp.where(onehot, orig_win, 0), axis=0, dtype=jnp.int32)
+    lo_t = jnp.sum(jnp.where(onehot, lo_win, zero), axis=0, dtype=jnp.int32)
+    orig_t = jnp.sum(jnp.where(onehot, orig_win, zero), axis=0,
+                     dtype=jnp.int32)
     tt = t.reshape(tile)
     l_out[:] = orig_t
     pos_out[:] = lo_t + (tt - seg)
@@ -88,9 +101,10 @@ def expand_positions(counts: jnp.ndarray, lo: jnp.ndarray, out_cap: int,
     Returns (l_idx int32, r_pos int32, out_valid bool), each (out_cap,).
     """
     cap_l = counts.shape[0]
-    tile = 256 if out_cap % 512 else 512
+    tile = TILE
     if out_cap % tile:
-        # non-tileable capacity (custom bucket_sizes): jnp twin is exact
+        # non-tileable capacity (the 256 bucket, custom bucket_sizes): a
+        # static choice by shape — the jnp twin is exact
         return expand_positions_ref(counts, lo, out_cap)
     n_tiles = out_cap // tile
 

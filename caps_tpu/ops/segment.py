@@ -31,15 +31,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Blocks are 1-D; the live TPU stack verifies Mosaic's derived layout
+# Blocks are 1-D; the TPU compiler verifies Mosaic's derived layout
 # against XLA's, and XLA tiles a 1-D 32-bit operand of padded size S at
 # T(min(1024, S)) — so every 1-D block (inputs AND output) must be
 # exactly min(1024, padded_array_size) or Mosaic is rejected with
 # "XLA layout ({0:T(1024)}) does not match Mosaic layout ({0:T(512)})"
-# (observed on v5e 2026-07-31 at s32[4096]/block 512 and s32[256]/block
-# 128).  Rows therefore pad to 1024 multiples with a fixed 1024 tile;
-# the segment axis uses ONE whole-array block up to 1024 and 1024-tiles
-# beyond.
+# (observed on v5e at s32[4096]/block 512 and s32[256]/block 128).
+# Rows therefore pad to 1024 multiples with a fixed 1024 tile; the
+# segment axis uses ONE whole-array block up to 1024 and 1024-tiles
+# beyond.  ops/expand.py's TILE follows the same rule.
 ROW_TILE = 1024
 SEG_QUANTUM = 128
 
@@ -82,7 +82,7 @@ def _agg_kernel(codes_ref, ok_ref, val_ref, out_ref, *, kind: str,
         v = jnp.where(ok_ref[:] != 0, val_ref[:], jnp.float32(0))
         # HIGHEST: the MXU's default f32 precision truncates operands to
         # bf16, which is visible data loss in an aggregate (observed
-        # ~2e-2 abs drift on live v5e); bf16x6 passes restore f32 sums
+        # ~2e-2 abs drift on v5e); bf16x6 passes restore f32 sums
         part = jnp.dot(v.reshape(1, row_tile), hit.astype(jnp.float32),
                        preferred_element_type=jnp.float32,
                        precision=jax.lax.Precision.HIGHEST
@@ -180,7 +180,7 @@ def dense_segment_agg(codes: jnp.ndarray, ok: jnp.ndarray,
 @functools.lru_cache(maxsize=256)
 def _sharded_agg_fn(mesh, num_segments: int, kind: str, interpret: bool):
     from caps_tpu.obs.compile import charged as _compile_charged
-    from caps_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     # rows split over EVERY mesh axis (matches DeviceBackend.place_rows):

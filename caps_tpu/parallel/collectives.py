@@ -9,6 +9,8 @@ The engine's "shuffle service" (SURVEY.md §5.8): thin wrappers over
                         frontier expansion against resident shards
     broadcast_concat    all_gather of a small build side — broadcast join
     global_sum          psum tree — global aggregates
+    global_max          64-bit-safe max over the axis (sizing scalars)
+    sum_scatter         64-bit-safe reduce-scatter of node-indexed partials
 
 All take the mesh axis name; they only mean something inside shard_map.
 """
@@ -134,3 +136,37 @@ def broadcast_concat(x: jnp.ndarray, axis: str) -> jnp.ndarray:
 def global_sum(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     note_collective("psum", x)
     return lax.psum(x, axis)
+
+
+# XLA's TPU backend lowers 64-bit collectives by rewriting them into
+# 32-bit halves, and only some have that rewrite: psum, ppermute,
+# all_gather and all_to_all compile for int64/float64; ``pmax``/``pmin``
+# ("Supported lowering only of Sum all reduce") and ``psum_scatter``
+# ("rewriting ... not implemented: reduce-scatter") are refused
+# (compiled for v5e, jax 0.9.0 / libtpu 0.0.34).  The engine's row
+# counts and path counts are int64, so the two below are built from the
+# collectives that do lower; on CPU they compute the same values.
+
+
+def global_max(x: jnp.ndarray, axis) -> jnp.ndarray:
+    """``lax.pmax`` that also takes int64: the value splits into a signed
+    high word and a biased low word (int32 order == the original's, as
+    in ops/sort.py ``split_planes``) and the maximum is found word by
+    word with two 32-bit ``pmax``."""
+    if x.dtype != jnp.int64:
+        return lax.pmax(x, axis)
+    hi = (x >> 32).astype(jnp.int32)
+    lo = ((x & 0xFFFFFFFF) - (1 << 31)).astype(jnp.int32)
+    hi_max = lax.pmax(hi, axis)
+    lo_max = lax.pmax(
+        jnp.where(hi == hi_max, lo, jnp.iinfo(jnp.int32).min), axis)
+    return (hi_max.astype(jnp.int64) << 32) \
+        | (lo_max.astype(jnp.int64) + (1 << 31))
+
+
+def sum_scatter(x: jnp.ndarray, axis: str, n_shards: int) -> jnp.ndarray:
+    """``lax.psum_scatter(x, axis, scatter_dimension=0, tiled=True)`` for
+    any dtype: an all_to_all delivers block i of every device's ``x`` to
+    device i — the bytes a reduce-scatter moves — and the sum is local."""
+    parts = x.reshape((n_shards, x.shape[0] // n_shards) + x.shape[1:])
+    return lax.all_to_all(parts, axis, 0, 0).sum(axis=0)

@@ -57,12 +57,13 @@ jax.config.update("jax_enable_x64", True)  # int64 join keys/sentinels
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from caps_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 from caps_tpu.parallel.collectives import (
     bin_positions as _bin_positions,
     broadcast_concat as _broadcast_concat,
     exchange_binned as _exchange,
+    global_max as _global_max,
     salted_dest as _dest_for,
 )
 
@@ -189,8 +190,8 @@ def make_radix_join_phase1(mesh: Mesh, axis: Axis, n_shards: int,
         hi = jnp.searchsorted(rk_sorted, lk, side="right")
         counts = jnp.where(lok_recv, hi - lo, 0)
         my_total = counts.sum()
-        max_total = lax.pmax(my_total, axis)
-        max_left = lax.pmax(
+        max_total = _global_max(my_total, axis)
+        max_left = _global_max(
             (counts + jnp.where(lok_recv & (counts == 0), 1, 0)).sum(), axis)
         dropped = lax.psum(l_drop + r_drop, axis)
         sent_l = lax.psum(sent_l, axis)
@@ -250,7 +251,7 @@ def make_broadcast_join(mesh: Mesh, axis: Axis, n_l: int, n_r: int,
         counts = jnp.where(l_ok, hi - lo, 0)
         eff = jnp.where(left_join & l_ok & (counts == 0), 1, counts) \
             if left_join else counts
-        max_total = lax.pmax(eff.sum(), axis)
+        max_total = _global_max(eff.sum(), axis)
         if count_only:
             live_r = lax.psum(r_ok.sum(), axis)
             return (max_total, live_r)

@@ -25,7 +25,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from caps_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 from caps_tpu.parallel.collectives import (
     broadcast_concat, exchange_by_shard, global_sum, ring_shift, shard_of,

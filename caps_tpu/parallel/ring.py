@@ -24,8 +24,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from caps_tpu.obs.compile import charged as _compile_charged
-from caps_tpu.parallel.collectives import note_collective
-from caps_tpu.parallel.compat import pcast, shard_map
+from caps_tpu.parallel.collectives import note_collective, sum_scatter
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -59,8 +60,7 @@ def _ring_hop(cnt_block, edge_src, edge_dst, edge_ok, *, axis: str,
                                     num_segments=n_nodes)
     # psum + scatter back to node blocks in one collective
     note_collective("ring.psum_scatter", local_out)
-    return jax.lax.psum_scatter(local_out, axis, scatter_dimension=0,
-                                tiled=True)
+    return sum_scatter(local_out, axis, n_shards)
 
 
 def make_ring_khop(mesh: Mesh, n_nodes: int, n_hops: int,
@@ -155,8 +155,7 @@ def _ring_hop_matrix(f_block, edge_src, edge_dst, edge_ok, *, axis: str,
     local_out = jax.ops.segment_sum(per_edge.T, edge_dst,
                                     num_segments=n_nodes)  # (N, S)
     note_collective("ring.psum_scatter", local_out)
-    out = jax.lax.psum_scatter(local_out, axis, scatter_dimension=0,
-                               tiled=True)  # (nb, S)
+    out = sum_scatter(local_out, axis, n_shards)  # (nb, S)
     return out.T
 
 
@@ -204,8 +203,7 @@ def make_ring_varexpand(mesh: Mesh, n_nodes: int, lengths: tuple,
                 # (see docstring)
                 loc = _r2_vector(edge_src, edge_dst, edge_ok, n_nodes,
                                  f.dtype, correction)
-                corr = jax.lax.psum_scatter(loc, axis, scatter_dimension=0,
-                                            tiled=True)  # (nb,)
+                corr = sum_scatter(loc, axis, n_shards)  # (nb,)
                 f = f - f0_block * corr[None, :]
             if length in lengths:
                 out = out + f * tmask_block[None, :]
@@ -264,8 +262,7 @@ def make_ring_varexpand3(mesh: Mesh, n_nodes: int, lengths: tuple,
     def body(f0, e_src, e_dst, e_ok, tmask, s13_src, s13_dst, s13_w,
              st_src, st_dst, st_w):
         loc = _r2_vector(e_src, e_dst, e_ok, n_nodes, f0.dtype, correction)
-        r2 = jax.lax.psum_scatter(loc, axis, scatter_dimension=0,
-                                  tiled=True)  # (nb,) node-block sharded
+        r2 = sum_scatter(loc, axis, n_shards)  # (nb,) node-block sharded
         out = jnp.zeros_like(f0)
         if 0 in lengths:
             out = out + f0 * tmask[None, :]

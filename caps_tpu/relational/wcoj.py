@@ -274,8 +274,6 @@ class MultiwayJoinOp(RelationalOperator):
             raise _Unsuitable("mesh-sharded session")
         if not backend.config.use_wcoj:
             raise _Unsuitable("use_wcoj disabled")
-        config = backend.config
-        use_pallas = bool(config.use_pallas and OPS.pallas_usable("prefetch"))
         interpret = OPS.default_interpret()
         seg = self.seg
 
@@ -421,6 +419,7 @@ class MultiwayJoinOp(RelationalOperator):
                 lambda: W.probe_adj(S, u_ids, valid, jnp.int64(n)))
             total, t_live = backend.consume_rows(W.adj_total(counts))
             out_cap = backend.bucket(total)
+            use_pallas = backend.use_expand_kernel(out_cap)
             l_idx, cand, erow, ok = charged_shape(
                 f"extend:e{S.shape[0]}b{cap}x{out_cap}",
                 lambda: W.extend(S, P, u_ids, valid, n, out_cap,
@@ -465,6 +464,7 @@ class MultiwayJoinOp(RelationalOperator):
                                      jnp.int64(n)))
             total, t_live = backend.consume_rows(W.adj_total(counts))
             out_cap = backend.bucket(total)
+            use_pallas = backend.use_expand_kernel(out_cap)
             l_idx, erow, _ok = charged_shape(
                 f"close:e{S.shape[0]}b{cap}x{out_cap}",
                 lambda: W.close(S, P, state[("id", e.frm)],

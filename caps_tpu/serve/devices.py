@@ -153,18 +153,21 @@ def replicate_graph(graph, session):
 
 
 def _acquire_devices(n: int) -> List[Any]:
-    """Real accelerator devices when the platform has them, else
+    """``n`` real accelerator devices, or — on the CPU platform only —
     simulated devices (None): per-session isolation is the part of the
-    fault domain the failover logic observes, and it needs no
-    accelerator."""
-    try:
-        import jax
-        devs = jax.devices()
-        if devs and devs[0].platform != "cpu" and len(devs) >= n:
-            return list(devs[:n])
-    except Exception:  # pragma: no cover — jax-less / broken platform
-        pass
-    return [None] * n
+    fault domain the failover logic observes, and tests need no
+    accelerator for it.  On an accelerator, asking for more replica
+    devices than the platform has is an error, never a silent
+    simulation."""
+    import jax
+    devs = jax.devices()
+    if devs[0].platform == "cpu":
+        return [None] * n
+    if len(devs) < n:
+        raise ReplicationUnsupported(
+            f"ServerConfig(devices={n}) but the {devs[0].platform} "
+            f"platform has {len(devs)} device(s)")
+    return list(devs[:n])
 
 
 class DeviceReplica:

@@ -12,8 +12,8 @@ function owns that decision:
   execution path is expected to succeed on a retry.  Device-runtime
   errors with retryable status words (``RESOURCE_EXHAUSTED`` from an
   HBM allocator under pressure, ``UNAVAILABLE``/``ABORTED`` from a
-  flapping transport), connection/timeout errors from remote-device
-  tunnels, and anything explicitly marked ``caps_transient = True``
+  flapping transport), connection/timeout errors, and anything
+  explicitly marked ``caps_transient = True``
   (the fault-injection harness and backend code use the marker).
   The worker retries these with exponential backoff
   (:mod:`caps_tpu.serve.retry`), charging the request's deadline.
@@ -35,9 +35,8 @@ function owns that decision:
   then trips its family's circuit breaker.
 
 The classifier is import-light on purpose: it never imports jax —
-device-runtime exceptions are recognized by MRO class *name*
-(``XlaRuntimeError`` moved modules across jaxlib versions) plus status
-words in the message.
+device-runtime exceptions are recognized by MRO class *name* plus
+status words in the message.
 """
 from __future__ import annotations
 
@@ -50,7 +49,7 @@ POISONED_PLAN = "poisoned_plan"
 FATAL = "fatal"
 
 #: Device-runtime exception class names treated as device errors
-#: regardless of which module currently defines them.
+#: (``jax.errors.JaxRuntimeError`` and its older spelling).
 _DEVICE_ERROR_NAMES = frozenset({"XlaRuntimeError", "JaxRuntimeError"})
 
 #: Status words (gRPC / XLA canonical codes) that mark a device error
@@ -78,9 +77,8 @@ def device_fault(exc: BaseException) -> bool:
     the cached plan — the only failures the per-device health ladder
     (serve/devices.py) counts.  An explicit ``caps_device_fault`` marker
     wins (the device-scoped fault injectors stamp it); otherwise
-    device-runtime errors by MRO name and connection failures (a dead
-    device tunnel) qualify.  A user's bad query must never take a
-    device down."""
+    device-runtime errors by MRO name and connection failures qualify.
+    A user's bad query must never take a device down."""
     marker = getattr(exc, "caps_device_fault", None)
     if marker is not None:
         return bool(marker)

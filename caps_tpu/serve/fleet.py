@@ -630,13 +630,29 @@ def backend_main(spec_json: str) -> None:  # pragma: no cover — child
     backend.shutdown(drain=False)
 
 
-def spawn_backend(spec: BackendSpec, env: Optional[Dict[str, str]] = None):
-    """Launch ``python -m caps_tpu.serve.fleet`` with ``spec`` and wait
-    for its port line.  Returns ``(process, port)``; the caller owns
-    the process (terminate/kill/wait)."""
+#: How long a spawned process may take to report its port.  One process
+#: owns a chip: a second device-backed backend started on an occupied
+#: chip fails in the runtime's start-up — or waits there for the chip —
+#: and the deadline turns the wait into an error as well.
+SPAWN_TIMEOUT_S = 300.0
+
+
+def spawn_process(module: str, spec_json: str, marker: str, what: str,
+                  pin_cpu: bool, env: Optional[Dict[str, str]] = None):
+    """Launch ``python -m <module> <spec_json>`` and wait for its
+    ``<marker> <port>`` line.  Returns ``(process, port)``; the caller
+    owns the process (terminate/kill/wait).  ``pin_cpu``: the process
+    runs no device code (a router, an oracle-backed backend), so it is
+    kept off the accelerator — it must never take a chip from a process
+    that needs one.  A device-backed process gets the caller's
+    environment untouched; give each its own chip through ``env``
+    (docs/tpu.md).  The child's stderr is the caller's: a start-up
+    failure says why there."""
+    import selectors
     import subprocess
     child_env = dict(os.environ)
-    child_env.setdefault("JAX_PLATFORMS", "cpu")
+    if pin_cpu:
+        child_env["JAX_PLATFORMS"] = "cpu"
     # the child must import caps_tpu regardless of the caller's cwd:
     # put the package's parent dir on its PYTHONPATH explicitly
     pkg_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -647,17 +663,35 @@ def spawn_backend(spec: BackendSpec, env: Optional[Dict[str, str]] = None):
     if env:
         child_env.update(env)
     proc = subprocess.Popen(
-        [sys.executable, "-m", "caps_tpu.serve.fleet", spec.to_json()],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-        env=child_env, text=True)
-    line = proc.stdout.readline()
-    while line and not line.startswith("CAPS_FLEET_PORT"):
-        line = proc.stdout.readline()
-    if not line:
-        proc.kill()
-        raise QueryFailed(
-            f"fleet backend {spec.name!r} exited before reporting a port")
-    return proc, int(line.split()[1])
+        [sys.executable, "-m", module, spec_json],
+        stdout=subprocess.PIPE, env=child_env, text=True)
+    deadline = clock.now() + SPAWN_TIMEOUT_S
+    line = None  # None: deadline passed; "": stdout closed (child exited)
+    with selectors.DefaultSelector() as sel:
+        sel.register(proc.stdout, selectors.EVENT_READ)
+        while sel.select(timeout=max(0.0, deadline - clock.now())):
+            line = proc.stdout.readline()
+            if not line or line.startswith(marker):
+                break
+            line = None
+    if line:
+        return proc, int(line.split()[1])
+    proc.kill()
+    proc.wait()
+    raise QueryFailed(
+        f"{what} "
+        + (f"reported no port within {SPAWN_TIMEOUT_S:.0f} s"
+           if line is None else "exited before reporting a port")
+        + " — see its stderr above; a device-backed process needs a "
+          "chip that no other process holds")
+
+
+def spawn_backend(spec: BackendSpec, env: Optional[Dict[str, str]] = None):
+    """Launch ``python -m caps_tpu.serve.fleet`` with ``spec`` and wait
+    for its port line (see :func:`spawn_process`)."""
+    return spawn_process("caps_tpu.serve.fleet", spec.to_json(),
+                         "CAPS_FLEET_PORT", f"fleet backend {spec.name!r}",
+                         pin_cpu=spec.backend == "local", env=env)
 
 
 if __name__ == "__main__":  # pragma: no cover — child process

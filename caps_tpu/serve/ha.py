@@ -55,6 +55,7 @@ from caps_tpu.obs import clock
 from caps_tpu.obs.lockgraph import make_rlock
 from caps_tpu.obs.metrics import MetricsRegistry, global_registry
 from caps_tpu.serve import wire
+from caps_tpu.serve.fleet import spawn_process
 from caps_tpu.serve.errors import (FleetUnavailable, QueryFailed, ServeError,
                                    ServerClosed, StaleEpoch, WireError)
 from caps_tpu.serve.router import FleetRouter, RouterConfig
@@ -514,29 +515,11 @@ def spawn_router(spec: RouterSpec,
     """Launch ``python -m caps_tpu.serve.ha`` with ``spec`` and wait for
     its port line.  Returns ``(process, port)``; the caller owns the
     process (terminate/kill/wait) — the chaos bench SIGKILLs the active
-    one mid-soak."""
-    import subprocess
-    child_env = dict(os.environ)
-    child_env.setdefault("JAX_PLATFORMS", "cpu")
-    pkg_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    parent = os.path.dirname(pkg_root)
-    existing = child_env.get("PYTHONPATH")
-    child_env["PYTHONPATH"] = (
-        parent if not existing else parent + os.pathsep + existing)
-    if env:
-        child_env.update(env)
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "caps_tpu.serve.ha", spec.to_json()],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-        env=child_env, text=True)
-    line = proc.stdout.readline()
-    while line and not line.startswith("CAPS_ROUTER_PORT"):
-        line = proc.stdout.readline()
-    if not line:
-        proc.kill()
-        raise QueryFailed(
-            f"router {spec.name!r} exited before reporting a port")
-    return proc, int(line.split()[1])
+    one mid-soak.  A router runs no device code and is pinned to the
+    CPU (serve/fleet.py :func:`spawn_process`)."""
+    return spawn_process("caps_tpu.serve.ha", spec.to_json(),
+                         "CAPS_ROUTER_PORT", f"router {spec.name!r}",
+                         pin_cpu=True, env=env)
 
 
 if __name__ == "__main__":  # pragma: no cover — child process

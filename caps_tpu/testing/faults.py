@@ -15,7 +15,7 @@ This module provides them:
 * :func:`slow_compile` — deterministic delay + accounting inflation at
   every compile-boundary charge (obs/compile.py), so cold-cliff and
   AOT-warmup tests run on the fake clock instead of real XLA compiles;
-* :func:`device_oom` — a realistic ``XlaRuntimeError``-shaped
+* :func:`device_oom` — a realistic ``JaxRuntimeError``-shaped
   ``RESOURCE_EXHAUSTED``, injected at an operator boundary or into
   ingest placement;
 * :func:`device_loss` / :func:`sick_device` — device-SCOPED faults for
@@ -84,6 +84,7 @@ import contextlib
 import warnings
 from typing import Callable, Dict, List, Optional, Type, Union
 
+import jax
 import jax.numpy as jnp
 
 from caps_tpu.obs import clock
@@ -91,27 +92,14 @@ from caps_tpu.obs.lockgraph import make_lock, make_rlock
 from caps_tpu.obs.metrics import global_registry
 
 
-def xla_runtime_error_class() -> Type[BaseException]:
-    """The real jaxlib ``XlaRuntimeError`` when available (so injected
-    device faults are indistinguishable from genuine ones), else a
-    same-named stub."""
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-        return XlaRuntimeError
-    except Exception:  # pragma: no cover — stub for jaxlib-less installs
-        class XlaRuntimeError(RuntimeError):
-            pass
-        return XlaRuntimeError
-
-
 def make_oom(note: str = "") -> BaseException:
     """A fresh ``RESOURCE_EXHAUSTED`` in the exact shape the TPU runtime
     raises it (message prefix included — serve/failure.py classifies by
     those status words)."""
-    cls = xla_runtime_error_class()
-    return cls("RESOURCE_EXHAUSTED: Attempting to allocate 2.50G. That was"
-               " not possible. There are 1.25G free."
-               + (f" [{note}]" if note else ""))
+    return jax.errors.JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Attempting to allocate 2.50G. That was"
+        " not possible. There are 1.25G free."
+        + (f" [{note}]" if note else ""))
 
 
 def _resolve_operator(op_name: str) -> type:
@@ -590,9 +578,9 @@ def _make_device_down(device_index: int) -> BaseException:
     raises it (serve/failure.py classifies the status word TRANSIENT —
     the retry lands on a DIFFERENT device — and ``device_fault`` counts
     it against this device's health ladder)."""
-    cls = xla_runtime_error_class()
-    exc = cls(f"UNAVAILABLE: device {device_index} has halted; "
-              f"transport closed [injected device loss]")
+    exc = jax.errors.JaxRuntimeError(
+        f"UNAVAILABLE: device {device_index} has halted; "
+        f"transport closed [injected device loss]")
     exc.caps_device_fault = True
     return exc
 
@@ -660,9 +648,9 @@ def _make_shard_down(group: str, member: Optional[int]) -> BaseException:
     member (serve/shards.py): ``caps_device_fault`` makes the group's
     ladder count it, ``caps_shard_member`` attributes the member so the
     MEMBER breaker (not the group's) climbs."""
-    cls = xla_runtime_error_class()
-    exc = cls(f"UNAVAILABLE: shard member {member} of group {group!r} "
-              f"has halted; transport closed [injected shard loss]")
+    exc = jax.errors.JaxRuntimeError(
+        f"UNAVAILABLE: shard member {member} of group {group!r} "
+        f"has halted; transport closed [injected shard loss]")
     exc.caps_device_fault = True
     if member is not None:
         exc.caps_shard_member = member
@@ -745,7 +733,7 @@ def sick_shard(group: str, member: Optional[int] = None,
 @contextlib.contextmanager
 def device_oom(phase: str = "execute", op_name: str = "Scan",
                session=None, n_times: Optional[int] = 1):
-    """A realistic device ``RESOURCE_EXHAUSTED`` (XlaRuntimeError-shaped,
+    """A realistic device ``RESOURCE_EXHAUSTED`` (JaxRuntimeError-shaped,
     classified TRANSIENT by serve/failure.py).
 
     ``phase="execute"`` raises from the named operator's compute (any
@@ -770,9 +758,8 @@ def _make_write_abort() -> BaseException:
     classifies it TRANSIENT, so the server retries the write — which is
     SAFE precisely because the commit it interrupted rolled back
     completely (the atomicity the abort_write tests assert)."""
-    cls = xla_runtime_error_class()
-    return cls("ABORTED: transfer interrupted mid-commit "
-               "[injected write abort]")
+    return jax.errors.JaxRuntimeError(
+        "ABORTED: transfer interrupted mid-commit [injected write abort]")
 
 
 @contextlib.contextmanager

@@ -626,3 +626,17 @@ def test_soak_device_killed_mid_run(monkeypatch):
 @pytest.mark.slow
 def test_soak_device_killed_mid_run_long():
     _device_loss_soak(n_devices=4, per_thread=30)
+
+
+def test_more_replicas_than_accelerators_is_an_error(monkeypatch):
+    """On an accelerator, ``devices=N`` beyond what the platform has must
+    not quietly simulate; the CPU keeps simulating (these tests)."""
+    import types
+    import jax
+    from caps_tpu.serve.devices import _acquire_devices
+    assert _acquire_devices(3) == [None, None, None]
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [chip])
+    assert _acquire_devices(1) == [chip]
+    with pytest.raises(ReplicationUnsupported, match="has 1 device"):
+        _acquire_devices(2)

@@ -75,3 +75,60 @@ def test_profile_query(backend):
     assert profiled.profile["rows"] == len(rows)
     assert "rows=" in profiled.plans["profile"]
     assert n_events > 0
+
+
+# -- chip_smoke.py (repo root): the chip's proof, rehearsed on the CPU ------
+
+REPO_ROOT = os.path.dirname(EXAMPLES_DIR)
+
+
+def test_chip_smoke_phases_on_cpu(capsys):
+    """Every phase of the one-chip script at a tiny size, in-process.
+    The device check is stood in for HERE — the script has no option
+    that skips it."""
+    import json
+    sys.path.insert(0, REPO_ROOT)
+    import chip_smoke
+    import jax
+
+    def cpu_device(chips):
+        d = jax.devices()
+        return {"platform": d[0].platform, "kind": d[0].device_kind,
+                "count": len(d)}
+
+    args = chip_smoke.parse_args(["--people", "2000", "--edges", "10000"])
+    device = chip_smoke.run(args, device_phase=cpu_device)
+    assert device["platform"] == "cpu"
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    by_phase = {ln["phase"]: ln for ln in lines}
+    assert list(by_phase) == ["ingest", "count", "paths", "serve",
+                              "assert-no-fallback", "pushdown-ingest",
+                              "count-pushdown"]
+    assert by_phase["ingest"]["people"] == 2000
+    assert by_phase["count"]["count"] == by_phase["count"]["oracle"]
+    assert {r["fused_mode"] for r in by_phase["paths"]["first_runs"]} \
+        == {"record", "replay_gen"}
+    assert by_phase["serve"]["completed"] == by_phase["serve"]["requests"]
+    nf = by_phase["assert-no-fallback"]
+    assert nf["fallback_count"] == 0 and nf["fallback_reasons"] == []
+    # on the CPU the static table turns on the interpreted families
+    assert nf["kernel_families_on"] == ["expand", "segment"]
+    assert all(nf["kernel_launches"][f] > 0 for f in nf["kernel_families_on"])
+    assert nf["kernels_compiled"] is False
+    assert by_phase["count-pushdown"]["strategy"] == "fused-spmv"
+
+
+def test_chip_smoke_refuses_the_cpu():
+    """As the driver runs it where there is no chip: non-zero exit at the
+    ``device`` phase, and no result line."""
+    import json
+    import subprocess
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO_ROOT, "chip_smoke.py")],
+        capture_output=True, text=True, env=env, cwd=REPO_ROOT, timeout=300)
+    assert proc.returncode != 0
+    lines = [json.loads(ln) for ln in proc.stdout.splitlines()]
+    assert [ln["phase"] for ln in lines] == ["device", "failed"]
+    assert lines[0]["platform"] == "cpu"
+    assert not any("ok" in ln and ln["ok"] for ln in lines)

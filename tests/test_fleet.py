@@ -393,3 +393,19 @@ def test_rows_digest_is_order_insensitive():
     rows = [{"a": 1, "b": "x"}, {"a": 2, "b": "y"}]
     assert rows_digest(rows) == rows_digest(list(reversed(rows)))
     assert rows_digest(rows) != rows_digest(rows[:1])
+
+
+# -- process launcher (serve/fleet.py spawn_process) ------------------------
+
+def test_spawn_process_reports_exit_and_deadline(monkeypatch):
+    """A child that dies, or never reports a port (a backend waiting for
+    a chip another process holds), is an error — never a hang."""
+    from caps_tpu.serve import fleet
+    with pytest.raises(QueryFailed, match="exited before reporting"):
+        fleet.spawn_process("caps_tpu.serve.no_such_module", "{}",
+                            "CAPS_FLEET_PORT", "backend 'x'", pin_cpu=True)
+    monkeypatch.setattr(fleet, "SPAWN_TIMEOUT_S", 1.0)
+    with pytest.raises(QueryFailed, match="reported no port within 1 s"):
+        # a child that stays alive and silent on stdout
+        fleet.spawn_process("http.server", "0", "CAPS_FLEET_PORT",
+                            "backend 'y'", pin_cpu=True)

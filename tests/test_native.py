@@ -115,3 +115,16 @@ def test_make_column_native_matches_python(make_session, monkeypatch):
     s2 = make_session("tpu")
     rows2 = s2.table_factory.from_columns(data, types).rows()
     assert rows1 == rows2
+
+
+def test_loaded_library_is_named_by_its_source_hash():
+    """What loads was built from the host_runtime.cpp beside it: the
+    file name carries the source's content hash (no mtime test)."""
+    import hashlib
+    import os
+    src = os.path.join(os.path.dirname(native.__file__), "csrc",
+                       "host_runtime.cpp")
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    assert native.source_hash() == digest
+    assert f"_caps_host.{digest}." in os.path.basename(native.lib.__file__)

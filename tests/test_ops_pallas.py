@@ -123,3 +123,63 @@ def test_bitonic_sort_unsupported_caps():
     assert not sort_cap_supported(384)        # R=3
     assert not sort_cap_supported(32768)      # R=256
     assert sort_cap_supported(256) and sort_cap_supported(16384)
+
+
+# -- ops/kernel_table.py: which families the engine routes to ---------------
+
+def test_kernel_table_on_cpu_is_interpret_mode():
+    """Off the TPU: expand and segment interpreted, sort off (its network
+    is slower interpreted than lax.sort); row-sharded operands only where
+    the family has a shard_map form."""
+    from caps_tpu.ops import kernel_table
+    assert [f for f in kernel_table.FAMILIES
+            if kernel_table.pallas_usable(f)] == ["expand", "segment"]
+    assert [f for f in kernel_table.FAMILIES
+            if kernel_table.pallas_usable(f, sharded=True)] == ["segment"]
+    with pytest.raises(ValueError, match="unknown kernel family"):
+        kernel_table.pallas_usable("prefetch")
+
+
+def test_kernel_table_unknown_tpu_kind_raises(monkeypatch):
+    import types
+    import jax
+    from caps_tpu.ops import kernel_table
+
+    def fake_devices(kind):
+        return lambda *a, **k: [types.SimpleNamespace(platform="tpu",
+                                                      device_kind=kind)]
+
+    monkeypatch.setattr(jax, "devices", fake_devices("TPU v5 lite"))
+    assert all(kernel_table.pallas_usable(f) for f in kernel_table.FAMILIES)
+    monkeypatch.setattr(jax, "devices", fake_devices("TPU v9 future"))
+    with pytest.raises(kernel_table.UnknownDeviceKind, match="TPU v9"):
+        kernel_table.pallas_usable("segment")
+
+
+def test_failing_kernel_raises_instead_of_falling_back(monkeypatch):
+    """A kernel that is on and fails must surface: no catch-and-degrade
+    to the sorted path, no latched kill flag, no fallback entry."""
+    import caps_tpu
+    from caps_tpu import ops as OPS
+    from caps_tpu.testing.factory import create_graph
+    session = caps_tpu.local_session(backend="tpu")
+    graph = create_graph(session, """
+        CREATE (:P {ok: true, v: 1}), (:P {ok: false, v: 2}),
+               (:P {ok: true, v: 3})""")
+    query = "MATCH (p:P) RETURN p.ok AS ok, count(*) AS n, max(p.v) AS hi"
+    want = [{"ok": False, "n": 1, "hi": 2}, {"ok": True, "n": 2, "hi": 3}]
+    rows = graph.cypher(query).records.to_maps()
+    assert sorted(rows, key=lambda r: r["ok"]) == want
+    assert session.backend.kernel_launches["segment"] > 0
+
+    class PlantedMosaicError(Exception):
+        pass
+
+    def broken(*a, **k):
+        raise PlantedMosaicError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(OPS, "dense_segment_agg", broken)
+    with pytest.raises(PlantedMosaicError):
+        graph.cypher(query + " ORDER BY ok").records.to_maps()
+    assert session.fallback_count == 0
+    assert session.backend.fallback_reasons == []

@@ -438,3 +438,38 @@ def test_varexpand_matrix_seed_blocking(monkeypatch):
     ve = [m for m in res.metrics["operators"] if m["op"] == "VarExpand"]
     assert ve and ve[0]["strategy"] == "matrix", ve
     assert tpu.fallback_count == 0
+
+
+def test_int64_safe_collectives_match_lax(mesh):
+    """``global_max``/``sum_scatter`` stand in for ``lax.pmax`` /
+    ``lax.psum_scatter``, which the TPU backend does not lower for 64-bit
+    values (tests/test_tpu_compile.py compiles them for a v5e); here they
+    must compute what the lax forms compute."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    from caps_tpu.parallel.collectives import global_max, sum_scatter
+    n = int(mesh.devices.size)
+    axis = mesh.axis_names[0]
+
+    def on_mesh(body, x, out_specs):
+        return jax.jit(shard_map(body, mesh=mesh, in_specs=(P(axis),),
+                                 out_specs=out_specs))(x)
+
+    edge = [5, -3, 2**40, 2**40 + 7, -(2**62), 2**31, 2**32 - 1,
+            -(2**31) - 1]
+    for rot in range(len(edge)):
+        vals = np.array((edge[rot:] + edge[:rot]) * n, np.int64)[:n]
+        got = on_mesh(lambda v: global_max(v[0], axis), jnp.asarray(vals),
+                      P())
+        assert int(got) == int(vals.max()), (vals, int(got))
+    got32 = on_mesh(lambda v: global_max(v[0], axis),
+                    jnp.arange(n, dtype=jnp.int32), P())
+    assert int(got32) == n - 1
+
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randint(-2**40, 2**40, (n * 2 * n, 3))
+                    .astype(np.int64))
+    got = on_mesh(lambda v: sum_scatter(v, axis, n), x, P(axis))
+    want = on_mesh(lambda v: jax.lax.psum_scatter(
+        v, axis, scatter_dimension=0, tiled=True), x, P(axis))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

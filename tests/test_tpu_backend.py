@@ -99,3 +99,66 @@ def test_distinct_aggregates_stay_on_device():
                     "ORDER BY g").records.to_maps()
     assert rows == [{"g": "a", "c": 2}, {"g": "b", "c": 2}]
     assert session.fallback_count == before, session.backend.fallback_reasons
+
+
+# -- persistent compile cache placement (backends/tpu/table.py) -------------
+
+@pytest.mark.parametrize("env_dir", [None, "/some/dir"])
+def test_compile_cache_is_placed_from_outside(monkeypatch, env_dir):
+    """With JAX_COMPILATION_CACHE_DIR set the engine configures no
+    directory (JAX reads the variable itself); unset, it is one fixed
+    path inside the checkout — never under ~/.cache, never a temp name.
+    The min-compile-time threshold drops to 0 either way."""
+    import os
+    import jax
+    from caps_tpu.backends.tpu import table as T
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    updates = {}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    T._place_compile_cache()
+    assert updates.pop("jax_persistent_cache_min_compile_time_secs") == 0.0
+    if env_dir is None:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert updates == {
+            "jax_compilation_cache_dir": os.path.join(repo, ".jax_cache")}
+    else:
+        assert updates == {}
+
+
+def test_compile_cache_untouched_on_cpu(monkeypatch):
+    import jax
+    from caps_tpu.backends.tpu import table as T
+    monkeypatch.setattr(jax.config, "update", lambda k, v: pytest.fail(
+        f"CPU session configured {k}"))
+    T._place_compile_cache()
+
+
+# -- datasets/foaf.py: the smoke's oracles against the oracle backend -------
+
+def test_foaf_oracles_honour_relationship_uniqueness():
+    """Self-loops on seed people make r1 == r2 reachable; the numpy
+    oracles must agree with the local backend there."""
+    import numpy as np
+    from caps_tpu.datasets import foaf
+    session = LocalCypherSession()
+    graph, src, dst, names, ages = foaf.build_graph(
+        session, 40, 400, 6, np.random.RandomState(5))
+    is_alice = np.asarray(names) == "Alice"
+    assert ((src == dst) & is_alice[src]).sum() > 0  # the case at stake
+    seeds = ["Alice", names[int(src[0])]]
+    want = foaf.expected_paths(src, dst, names, seeds)
+    for seed in seeds:
+        got = graph.cypher(foaf.PARAM_QUERY, {"seed": seed}) \
+            .records.to_maps()[0]["c"]
+        assert got == want[seed], seed
+        assert graph.cypher(foaf.AGE_TOP_QUERY, {"seed": seed}) \
+            .records.to_maps() \
+            == foaf.expected_age_top(src, dst, names, ages, seed)
+        assert graph.cypher(foaf.AGE_SPLIT_QUERY, {"seed": seed}) \
+            .records.to_maps() \
+            == foaf.expected_age_split(src, dst, names, ages, seed)
