@@ -118,7 +118,7 @@ class AnalysisConfig:
         "compile", "mem", "slowlog", "warmup", "bucket", "planstore",
         "cost", "stats", "replan", "shard", "paging", "wcoj",
         "fleet", "router", "wire", "rescache", "algo", "wal",
-        "chaos"})
+        "chaos", "xla"})
     #: the structured event log module (obs/log.py) and the correlation
     #: fields every emit site must pass — the structured-log pass's
     #: contract (a missing module is a finding, not a silent skip)

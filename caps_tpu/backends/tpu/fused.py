@@ -35,6 +35,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
 
 from caps_tpu.backends.tpu.table import (DeviceBackend, DeviceTable,
                                           FusedReplayMismatch)
+from caps_tpu.obs import profiler_span
 from caps_tpu.serve.errors import CancellationError
 
 _graph_epochs = itertools.count()
@@ -356,7 +357,8 @@ class FusedExecutor:
             cursor = [0]
             backend.count_mode = ("replay", entries, cursor)
             try:
-                yield
+                with profiler_span("caps_tpu.fused.replay"):
+                    yield
             finally:
                 backend.count_mode = None
             if cursor[0] != len(entries):
@@ -375,7 +377,8 @@ class FusedExecutor:
             backend._obj_unguarded = 0
             backend.count_mode = ("replay_gen", entries, cursor)
             try:
-                yield
+                with profiler_span("caps_tpu.fused.replay_gen"):
+                    yield
             finally:
                 backend.count_mode = None
             if cursor[0] != len(entries):
@@ -394,15 +397,17 @@ class FusedExecutor:
             viol = backend._replay_viol
             backend._replay_viol = None
             if viol is not None:
-                backend.syncs += 1  # the one end-of-query check
+                # the one end-of-query check (ONE counted host_read).
                 # Batch the flag read with the result table's exact row
                 # count (DeviceTable.prime_exact): steady state then
                 # pays exactly ONE round trip per query — a later
                 # to_maps reads the pre-paid exact-count cache.
                 table = getattr(getattr(state.get("result"), "records",
                                         None), "table", None)
-                bad = (table.prime_exact(viol)
-                       if isinstance(table, DeviceTable) else bool(viol))
+                with profiler_span("caps_tpu.fused.epilogue"):
+                    bad = (table.prime_exact(viol)
+                           if isinstance(table, DeviceTable)
+                           else bool(backend.host_read(viol)))
                 if bad:
                     raise FusedReplayMismatch(
                         "generic replay relation violated (an actual "
@@ -414,7 +419,8 @@ class FusedExecutor:
         rec: List[Tuple] = []
         backend.count_mode = ("record", rec)
         try:
-            yield
+            with profiler_span("caps_tpu.fused.record"):
+                yield
         finally:
             backend.count_mode = None
         self._memo.pop(key, None)

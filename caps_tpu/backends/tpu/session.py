@@ -11,7 +11,7 @@ from __future__ import annotations
 from caps_tpu import obs
 from caps_tpu.backends.tpu.table import DeviceBackend, DeviceTableFactory
 from caps_tpu.ops import default_interpret
-from caps_tpu.obs import clock
+from caps_tpu.obs import clock, xla_events
 from caps_tpu.okapi.config import DEFAULT_CONFIG
 from caps_tpu.relational.session import (RelationalCypherSession,
                                          degraded_state)
@@ -38,6 +38,9 @@ class TPUCypherSession(RelationalCypherSession):
         from caps_tpu.backends.tpu.fused import FusedExecutor
         self.fused = FusedExecutor(self.backend,
                                    max_entries=self.config.compile_cache_size)
+        # every jax trace / backend compile of the process, counted
+        # (xla.* in metrics_snapshot): once per process, not per session
+        xla_events.install()
 
     @property
     def table_factory(self) -> DeviceTableFactory:
@@ -169,10 +172,12 @@ class TPUCypherSession(RelationalCypherSession):
 
     def metrics_snapshot(self) -> dict:
         """Session snapshot extended with the device backend's counters
-        (communication accounting, fallbacks, size syncs, Pallas kernel
-        launches per family and whether they ran compiled) and the fused
+        (communication accounting, fallbacks, size syncs with the host
+        seconds and bytes of every device->host read, Pallas kernel
+        launches per family and whether they ran compiled), the fused
         executor's record/replay stats — the scattered stats the obs
-        registry absorbs (ISSUE 3 tentpole)."""
+        registry absorbs (ISSUE 3 tentpole) — and the process's jax
+        trace/compile counts (obs/xla_events.py)."""
         snap = super().metrics_snapshot()
         be = self.backend
         snap.update({
@@ -183,6 +188,8 @@ class TPUCypherSession(RelationalCypherSession):
             "backend.salted_joins": be.salted_joins,
             "backend.fallbacks": be.fallbacks,
             "backend.syncs": be.syncs,
+            "backend.sync_wait_s": be.sync_wait_s,
+            "backend.d2h_bytes": be.d2h_bytes,
             "backend.kernel.expand": be.kernel_launches["expand"],
             "backend.kernel.segment": be.kernel_launches["segment"],
             "backend.kernel.sort": be.kernel_launches["sort"],
@@ -195,6 +202,7 @@ class TPUCypherSession(RelationalCypherSession):
             "fused.batches": self.fused.batches,
             "fused.batch_members": self.fused.batch_members,
         })
+        snap.update(xla_events.snapshot())
         return snap
 
     @property

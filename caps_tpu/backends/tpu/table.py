@@ -18,6 +18,7 @@ hot path stayed on-device.
 """
 from __future__ import annotations
 
+import functools
 import math
 import os
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
@@ -35,7 +36,8 @@ from caps_tpu.backends.tpu.column import (
 from caps_tpu.backends.tpu.expr import DeviceExprCompiler, UnsupportedOnDevice
 from caps_tpu.backends.tpu.pool import make_pool
 from caps_tpu.ir.exprs import Expr
-from caps_tpu.obs import active_tracer
+from caps_tpu.obs import active_tracer, profiler_span, timed_span
+from caps_tpu.obs.lockgraph import make_lock
 from caps_tpu.okapi.config import EngineConfig
 from caps_tpu.okapi.types import CTBoolean, CTInteger, CypherType
 from caps_tpu.relational.header import RecordHeader
@@ -48,6 +50,47 @@ from caps_tpu.relational.table import AggSpec, Table, TableFactory
 DEFAULT_COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))), ".jax_cache")
+
+
+#: span of the host blocked on the device while it dispatches a query
+#: (``DeviceBackend.host_read``), and of a result's columns read back
+#: (``DeviceBackend.result_read``: the serving tier does that after it
+#: has released the execution lock, and a ``caps_tpu.`` name on a thread
+#: that is not dispatching would take the dispatching thread's idle gaps)
+SYNC_SPAN = "caps_tpu.sync"
+RESULT_READ_SPAN = "table.to_host"
+
+
+def _spanned(name: str):
+    """Run a ``DeviceTable`` method under the profiler span ``name``:
+    the level below the operator spans (one span a table method or join
+    step, never one a ``jnp`` call)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with profiler_span(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco
+
+
+def _host_nbytes(out) -> int:
+    """Bytes of the numpy array(s) a device->host read returned."""
+    if isinstance(out, np.ndarray):
+        return out.nbytes
+    if isinstance(out, (tuple, list)):
+        return sum(_host_nbytes(x) for x in out)
+    return 0
+
+
+def _column_read_nbytes(col: Column, n: int) -> int:
+    """Bytes ``column_to_host`` brings back for ``n`` rows of ``col``
+    (a ``valid`` that is already numpy is not read)."""
+    parts = [col.data, col.valid]
+    if col.lens is not None:
+        parts.append(col.lens)
+    return sum(n * a.dtype.itemsize * math.prod(a.shape[1:])
+               for a in parts if not isinstance(a, np.ndarray))
 
 
 def _place_compile_cache() -> None:
@@ -96,6 +139,12 @@ class DeviceBackend:
         self.fallbacks = 0
         self.fallback_reasons: List[str] = []
         self.syncs = 0  # device->host scalar materializations (perf metric)
+        # host seconds blocked in device->host reads and the bytes they
+        # brought back (counted syncs, PROFILE's barrier and result
+        # materialization alike): all through account_wait()
+        self.sync_wait_s = 0.0
+        self.d2h_bytes = 0
+        self._wait_lock = make_lock("table.DeviceBackend._wait_lock")
         # Pallas kernel launches per family (ops/kernel_table.py FAMILIES):
         # counted where the engine dispatches the kernel itself, not a
         # jnp twin chosen by shape
@@ -206,6 +255,38 @@ class DeviceBackend:
             self.kernel_launches["expand"] += 1
         return on
 
+    def account_wait(self, seconds: float, nbytes: int = 0,
+                     syncs: int = 0) -> None:
+        """Add one device->host wait to the three counters.  Under a
+        lock: a worker materializing a result (``result_read``) runs
+        beside the worker that holds the execution lock."""
+        with self._wait_lock:
+            self.syncs += syncs
+            self.sync_wait_s += seconds
+            self.d2h_bytes += nbytes
+
+    def host_read(self, read):
+        """THE counted device->host sync: every place the dispatching
+        host blocks on a device value goes through here, so the count
+        (``syncs``), the clock (``sync_wait_s``), the bytes
+        (``d2h_bytes``) and the span are in one place.  ``read`` is a
+        device array (brought back as numpy) or ``consume_obj``'s thunk,
+        which does its reads and returns host arrays."""
+        with timed_span(SYNC_SPAN) as t:
+            out = read() if callable(read) else np.asarray(read)
+        self.account_wait(t.seconds, _host_nbytes(out), syncs=1)
+        return out
+
+    def result_read(self, read, nbytes: int):
+        """A result's values read back by the thunk ``read``: timed and
+        sized like a sync, never counted in ``syncs`` (which counts what
+        stands between the host and its next dispatch), and under the
+        off-stream span name."""
+        with timed_span(RESULT_READ_SPAN) as t:
+            out = read()
+        self.account_wait(t.seconds, nbytes)
+        return out
+
     def consume_count(self, dev_scalar, relation: str = "exact") -> int:
         """Materialize a data-dependent size (see ``count_mode``).
 
@@ -227,11 +308,9 @@ class DeviceBackend:
         value can never reach results."""
         mode = self.count_mode
         if mode is None:
-            self.syncs += 1
-            return int(dev_scalar)
+            return int(self.host_read(dev_scalar))
         if mode[0] == "record":
-            self.syncs += 1
-            v = int(dev_scalar)
+            v = int(self.host_read(dev_scalar))
             mode[1].append(("size", v, relation))
             return v
         v = self._next_entry(mode, "size")
@@ -271,11 +350,9 @@ class DeviceBackend:
         exact without a sync."""
         mode = self.count_mode
         if mode is None:
-            self.syncs += 1
-            return int(dev_scalar), None
+            return int(self.host_read(dev_scalar)), None
         if mode[0] == "record":
-            self.syncs += 1
-            v = int(dev_scalar)
+            v = int(self.host_read(dev_scalar))
             mode[1].append(("rows", v))
             return v, None
         v = self._next_entry(mode, "rows")
@@ -358,11 +435,9 @@ class DeviceBackend:
         at the end of the query (fused.py epilogue)."""
         mode = self.count_mode
         if mode is None:
-            self.syncs += 1
-            return make()
+            return self.host_read(make)
         if mode[0] == "record":
-            self.syncs += 1
-            v = make()
+            v = self.host_read(make)
             mode[1].append(("__obj__", v))
             return v
         v = self._next_entry(mode, "__obj__")[1]
@@ -402,11 +477,16 @@ class DeviceTable(Table):
         if self._local is not None:
             return self._local
         n = self._exact_n()
-        data = {c: column_to_host(col, n, self.backend.pool)
+        data = {c: self._column_to_host(col, n)
                 for c, col in self._cols.items()}
         types = {c: col.ctype for c, col in self._cols.items()}
         return LocalTable(tuple(self._cols.keys()), data, types,
                           size=n)
+
+    def _column_to_host(self, col: Column, n: int) -> List[Any]:
+        be = self.backend
+        return be.result_read(lambda: column_to_host(col, n, be.pool),
+                              _column_read_nbytes(col, n))
 
     def _fallback(self, reason: str) -> "DeviceTable":
         self.backend.fallbacks += 1
@@ -446,8 +526,7 @@ class DeviceTable(Table):
         if self._live is None:
             return self._n
         if self._exact_cache is None:
-            self.backend.syncs += 1
-            self._exact_cache = int(self._live)
+            self._exact_cache = int(self.backend.host_read(self._live))
         return self._exact_cache
 
     def exact_size(self) -> int:
@@ -491,28 +570,34 @@ class DeviceTable(Table):
         if self._local is not None:
             return
         try:
-            for col in self._cols.values():
-                col.data.block_until_ready()
-                col.valid.block_until_ready()
-                if col.lens is not None:
-                    col.lens.block_until_ready()
-            if self._live is not None and hasattr(self._live,
-                                                 "block_until_ready"):
-                self._live.block_until_ready()
+            with timed_span(SYNC_SPAN) as t:
+                self._block_until_ready()
+            self.backend.account_wait(t.seconds)
         except Exception:  # pragma: no cover — profiling must not fail a query
             pass
+
+    def _block_until_ready(self) -> None:
+        for col in self._cols.values():
+            col.data.block_until_ready()
+            col.valid.block_until_ready()
+            if col.lens is not None:
+                col.lens.block_until_ready()
+        if self._live is not None and hasattr(self._live,
+                                             "block_until_ready"):
+            self._live.block_until_ready()
 
     def prime_exact(self, viol) -> bool:
         """Read the generic-replay violation flag batched with this
         table's exact live count in ONE transfer; primes the exact-count
         cache when the flag is clear (so a later ``to_maps`` pays no
         second round trip).  Returns the flag's truth value.  Falls back
-        to a plain flag read when there is nothing to batch."""
+        to a plain flag read when there is nothing to batch.  Either way
+        it is the ONE counted sync of a generic replay."""
+        read = self.backend.host_read
         if self._live is None or self._exact_cache is not None:
-            return bool(viol)
-        both = np.asarray(jnp.stack(
-            [jnp.asarray(viol).astype(jnp.int32),
-             jnp.asarray(self._live).astype(jnp.int32)]))
+            return bool(read(viol))
+        both = read(jnp.stack([jnp.asarray(viol).astype(jnp.int32),
+                               jnp.asarray(self._live).astype(jnp.int32)]))
         bad = bool(both[0])
         if not bad:
             self._exact_cache = int(both[1])
@@ -553,6 +638,7 @@ class DeviceTable(Table):
 
     # -- column ops ------------------------------------------------------
 
+    @_spanned("caps_tpu.table.select")
     def select(self, cols: Sequence[str]) -> "DeviceTable":
         if self._local is not None:
             return self._wrap_local(self._local.select(cols))
@@ -601,6 +687,7 @@ class DeviceTable(Table):
         out[name] = col
         return self._with_cols(out)
 
+    @_spanned("caps_tpu.table.with_column")
     def with_column(self, name, expr: Expr, header: RecordHeader,
                     parameters, ctype) -> "DeviceTable":
         if self._local is not None:
@@ -633,6 +720,7 @@ class DeviceTable(Table):
 
     # -- row ops ---------------------------------------------------------
 
+    @_spanned("caps_tpu.table.filter")
     def filter(self, expr: Expr, header: RecordHeader,
                parameters) -> "DeviceTable":
         if self._local is not None:
@@ -764,30 +852,38 @@ class DeviceTable(Table):
             if dist is not None:
                 return dist
         if csr is not None:
-            # CSR probe: two indptr gathers per row, no sort, no search
-            counts, lo = csr.probe(self._masked_left_key(lcol), l_ok)
             perm = csr.perm
         else:
-            rk_sorted, perm = self._cached_right_sort(other, rcol)
-            counts, lo = K.probe_count(self._masked_left_key(lcol), l_ok,
-                                       rk_sorted)
-        total_dev = K.join_total(counts, l_ok, left_join)
-        total, live = self.backend.consume_rows(total_dev)
+            with profiler_span("caps_tpu.table.join.right_sort"):
+                rk_sorted, perm = self._cached_right_sort(other, rcol)
+        with profiler_span("caps_tpu.table.join.probe"):
+            if csr is not None:
+                # CSR probe: two indptr gathers per row, no sort, no search
+                counts, lo = csr.probe(self._masked_left_key(lcol), l_ok)
+            else:
+                counts, lo = K.probe_count(self._masked_left_key(lcol), l_ok,
+                                           rk_sorted)
+            total_dev = K.join_total(counts, l_ok, left_join)
+            total, live = self.backend.consume_rows(total_dev)
         out_cap = self.backend.bucket(total)
-        if self.backend.use_expand_kernel(out_cap):
-            l_idx, r_idx, out_valid, r_matched = OPS.join_expand_via_positions(
-                counts, lo, perm, l_ok, out_cap, left_join,
-                interpret=OPS.default_interpret())
-        else:
-            l_idx, r_idx, out_valid, r_matched, _ = K.join_expand(
-                counts, lo, perm, l_ok, out_cap, left_join)
-        l_idx = self.backend.place_rows(l_idx)
-        r_idx = self.backend.place_rows(r_idx)
-        out_cols = _gather_cols(self._cols, l_idx)
-        right = _gather_cols(other._cols, r_idx)
-        for c, col in right.items():
-            out_cols[c] = Column(col.kind, col.data, col.valid & r_matched,
-                                 col.ctype, col.lens)
+        with profiler_span("caps_tpu.table.join.expand"):
+            if self.backend.use_expand_kernel(out_cap):
+                l_idx, r_idx, out_valid, r_matched = \
+                    OPS.join_expand_via_positions(
+                        counts, lo, perm, l_ok, out_cap, left_join,
+                        interpret=OPS.default_interpret())
+            else:
+                l_idx, r_idx, out_valid, r_matched, _ = K.join_expand(
+                    counts, lo, perm, l_ok, out_cap, left_join)
+            l_idx = self.backend.place_rows(l_idx)
+            r_idx = self.backend.place_rows(r_idx)
+        with profiler_span("caps_tpu.table.join.gather"):
+            # where _gather_tree's whole-column int64 splits are dispatched
+            out_cols = _gather_cols(self._cols, l_idx)
+            right = _gather_cols(other._cols, r_idx)
+            for c, col in right.items():
+                out_cols[c] = Column(col.kind, col.data, col.valid & r_matched,
+                                     col.ctype, col.lens)
         out = DeviceTable(self.backend, out_cols, total, live=live)
         return out._extra_pair_filter(pairs, left_join)
 
@@ -1122,6 +1218,7 @@ class DeviceTable(Table):
             return S.sort_perm_pallas(keys, cap)
         return K.sort_perm(keys, cap)
 
+    @_spanned("caps_tpu.table.distinct")
     def distinct(self) -> "DeviceTable":
         if self._local is not None:
             return self._wrap_local(self._local.distinct())
@@ -1143,6 +1240,7 @@ class DeviceTable(Table):
                           live=self._live)
         return tmp._compact(keep)
 
+    @_spanned("caps_tpu.table.order_by")
     def order_by(self, items: Sequence[Tuple[str, bool]]) -> "DeviceTable":
         if self._local is not None:
             return self._wrap_local(self._local.order_by(items))
@@ -1184,6 +1282,7 @@ class DeviceTable(Table):
 
     # -- aggregation ------------------------------------------------------
 
+    @_spanned("caps_tpu.table.group")
     def group(self, by: Sequence[str], aggs: Sequence[AggSpec]) -> "DeviceTable":
         if self._local is not None:
             return self._wrap_local(self._local.group(by, aggs))
@@ -1588,8 +1687,7 @@ class DeviceTable(Table):
     def column_values(self, col: str) -> List[Any]:
         if self._local is not None:
             return self._local.column_values(col)
-        return column_to_host(self._cols[col], self._exact_n(),
-                              self.backend.pool)
+        return self._column_to_host(self._cols[col], self._exact_n())
 
     def host_column(self, col: str):
         """(values, ok) numpy host view of an integer column — the

@@ -37,12 +37,12 @@ from caps_tpu.obs.telemetry import (FlightRecorder, OpStatsStore,
                                     RollingCounter, RollingHistogram,
                                     ServingTelemetry, SLOConfig)
 from caps_tpu.obs.tracer import (NULL_SPAN, NullSpan, Span, Tracer, activate,
-                                 active_tracer)
+                                 active_tracer, profiler_span, timed_span)
 
 __all__ = [
     "clock", "lockgraph", "Span", "NullSpan", "NULL_SPAN", "Tracer",
     "activate",
-    "active_tracer", "MetricsRegistry", "global_registry", "diff_snapshots",
+    "active_tracer", "profiler_span", "timed_span", "MetricsRegistry", "global_registry", "diff_snapshots",
     "write_jsonl", "write_chrome_trace", "chrome_trace_events",
     "profile_tree", "render_profile", "tag_timing", "find_executed_rows",
     "SLOConfig", "ServingTelemetry", "FlightRecorder", "OpStatsStore",

@@ -17,6 +17,16 @@ with no session handle — the collective wrappers in
 ``caps_tpu/parallel/collectives.py``, the distributed-join accounting in
 the device backend — emit events into whichever session's tracer is
 currently executing a query.
+
+:func:`profiler_span` is the one place in the engine that opens a
+``jax.profiler.TraceAnnotation``: a host ``TraceMe`` event on the
+profiler's own clock (the clock of the device trace, so no alignment),
+and from the same call a :class:`Span` in the tracer when that is
+enabled, so PROFILE / ``export_trace`` and a profiler trace carry the
+same names.  Names that start with ``caps_tpu.`` are the executing
+thread's on-stream spans (the benchmark's idle-gap breakdown sums them
+by name); states of a thread that does NOT hold the execution lock take
+another prefix (docs/guide.md, "Spans on the profiler's clock").
 """
 from __future__ import annotations
 
@@ -27,6 +37,11 @@ from typing import Any, Dict, Iterator, List, Optional
 
 from caps_tpu.obs import clock
 from caps_tpu.obs.lockgraph import make_lock
+
+try:  # profiling is optional — obs/ stays importable without jax
+    from jax.profiler import TraceAnnotation as _TraceAnnotation
+except Exception:  # pragma: no cover
+    _TraceAnnotation = None
 
 #: Optional provider of the executing device/replica index.  The serving
 #: tier installs ``serve.devices.executing_device_index`` here (obs/ must
@@ -272,3 +287,69 @@ def activate(tracer: Tracer) -> Iterator[Tracer]:
         yield tracer
     finally:
         stack.pop()
+
+
+# -- spans on the profiler's clock --------------------------------------------
+
+
+class _ProfilerSpan:
+    """A ``TraceAnnotation`` and a tracer span (or ``NULL_SPAN``) opened
+    and closed together; yields the tracer's span, so callers annotate
+    without an enabled-check of their own."""
+
+    __slots__ = ("_annotation", "_ctx")
+
+    def __init__(self, annotation, ctx):
+        self._annotation = annotation
+        self._ctx = ctx
+
+    def __enter__(self):
+        span = self._ctx.__enter__()
+        self._annotation.__enter__()
+        return span
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._annotation.__exit__(exc_type, exc, tb)
+        return self._ctx.__exit__(exc_type, exc, tb)
+
+
+def profiler_span(name: str, tracer: Optional[Tracer] = None,
+                  kind: str = "phase", tracer_name: Optional[str] = None,
+                  **args):
+    """Open ``name`` as a profiler annotation and, where ``tracer`` (the
+    thread's active tracer when None) is enabled, as a tracer span of the
+    same name (``tracer_name`` overrides it: operators keep ``op.<Name>``
+    there).  ``args`` become the annotation's arguments and the span's
+    attrs — never part of the event's name.  With no profiler session
+    running and the tracer off, the cost is one ``TraceMe`` that records
+    nothing."""
+    if tracer is None:
+        tracer = active_tracer()
+    ctx = tracer.span(tracer_name or name, kind=kind, **args)
+    if _TraceAnnotation is None:  # pragma: no cover — jax is a dependency
+        return ctx
+    return _ProfilerSpan(_TraceAnnotation(name, **args), ctx)
+
+
+class timed_span:
+    """:func:`profiler_span` plus the clock: ``.seconds`` holds the wall
+    time of the region after exit.  The clock reads live HERE, as
+    ``obs.compile.charged``'s do, so a timed region inside an operator's
+    ``_compute`` path stays clean under capslint's tracer-purity
+    closure."""
+
+    __slots__ = ("_span", "_t0", "seconds")
+
+    def __init__(self, name: str, **args):
+        self._span = profiler_span(name, **args)
+        self._t0 = 0.0
+        self.seconds = 0.0
+
+    def __enter__(self) -> "timed_span":
+        self._span.__enter__()
+        self._t0 = clock.now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.seconds = clock.now() - self._t0
+        return self._span.__exit__(exc_type, exc, tb)

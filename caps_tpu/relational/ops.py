@@ -12,15 +12,10 @@ from __future__ import annotations
 import abc
 import dataclasses
 import itertools
-from contextlib import nullcontext
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 from caps_tpu.obs import clock
-
-try:  # profiling is optional — this layer stays backend-agnostic
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover
-    _TraceAnnotation = None
+from caps_tpu.obs.tracer import profiler_span
 
 from caps_tpu.ir import exprs as E
 from caps_tpu.okapi.types import (
@@ -213,27 +208,25 @@ class RelationalOperator(abc.ABC):
             _cancel_checkpoint("execute")
             name = type(self).__name__.removesuffix("Op")
             tracer = self.context.tracer
-            tr_span = (tracer.span(f"op.{name}", kind="operator")
-                       if tracer is not None and tracer.enabled
-                       else nullcontext())
             t0 = clock.now()
             device_s: Optional[float] = None
-            with tr_span as sp:
-                xla_span = (_TraceAnnotation(f"caps_tpu.{name}")
-                            if _TraceAnnotation is not None else nullcontext())
-                with xla_span:
-                    try:
-                        self._result = self._compute()
-                    except _CancellationError:
-                        raise  # budget expiry, not an operator failure
-                    except Exception as ex:
-                        # only the op that ACTUALLY failed reports; the
-                        # ancestors it unwinds through (parents evaluate
-                        # children lazily inside their own _compute)
-                        # must not re-count it
-                        if getattr(ex, "caps_failed_op", None) is None:
-                            self._propagate_error(ex, name, tracer)
-                        raise
+            # one call opens the profiler annotation (caps_tpu.<Name>, the
+            # idle-gap breakdown's operator level) and, with tracing on,
+            # the tracer's op.<Name> span
+            with profiler_span(f"caps_tpu.{name}", tracer, kind="operator",
+                               tracer_name=f"op.{name}") as sp:
+                try:
+                    self._result = self._compute()
+                except _CancellationError:
+                    raise  # budget expiry, not an operator failure
+                except Exception as ex:
+                    # only the op that ACTUALLY failed reports; the
+                    # ancestors it unwinds through (parents evaluate
+                    # children lazily inside their own _compute)
+                    # must not re-count it
+                    if getattr(ex, "caps_failed_op", None) is None:
+                        self._propagate_error(ex, name, tracer)
+                    raise
                 if tracer is not None and tracer.enabled \
                         and tracer.sync_device:
                     # PROFILE per-op device mode: wait for the dispatched
@@ -290,9 +283,8 @@ class RelationalOperator(abc.ABC):
             # to — rebind() swaps in a fresh list, so stale stamps from
             # an earlier cached-plan execution are detectable.
             self._last_metrics = (self.context.op_metrics, entry)
-            if sp is not None:  # nullcontext (tracing disabled) yields None
-                sp.annotate(rows=entry["rows"], bytes=bytes_in,
-                            device_s=device_s)
+            sp.annotate(rows=entry["rows"], bytes=bytes_in,
+                        device_s=device_s)  # NULL_SPAN: a no-op
         return self._result
 
     def _propagate_error(self, ex: Exception, name: str, tracer) -> None:

@@ -322,6 +322,9 @@ class RelationalCypherSession(CypherSession):
         # metrics_snapshot().  Tracing is off unless config.trace or a
         # PROFILE query force-enables it.
         self.metrics_registry = obs.MetricsRegistry()
+        # in a snapshot (at 0) from the start: a reader of window deltas
+        # must not find the key missing before the first query
+        self.metrics_registry.histogram("query.execute_s")
         self.tracer = obs.Tracer(enabled=self.config.trace)
         # Observed per-operator statistics (obs/telemetry.py): every
         # execution folds its op_metrics entries in, keyed by
@@ -794,13 +797,15 @@ class RelationalCypherSession(CypherSession):
         no_plan_cache, _no_fused = degraded_state()
         cache_key: Optional[Tuple] = None
         if self.plan_cache.enabled and not no_plan_cache:
-            cache_key = self._plan_cache_key(graph, query, params)
-            if cache_key is not None:
-                cached = self.plan_cache.lookup(cache_key, params,
-                                                catalog=self._catalog)
-                if cached is not None:
-                    return self._run_cached(cached, query, params, t0,
-                                            family=cache_key[0])
+            cached = None
+            with obs.profiler_span("caps_tpu.plan.lookup"):
+                cache_key = self._plan_cache_key(graph, query, params)
+                if cache_key is not None:
+                    cached = self.plan_cache.lookup(cache_key, params,
+                                                    catalog=self._catalog)
+            if cached is not None:
+                return self._run_cached(cached, query, params, t0,
+                                        family=cache_key[0])
 
         # Cold path: the full frontend.  Planning sees the parameters
         # through a PlanParams view, which records any plan-time VALUE
@@ -953,12 +958,15 @@ class RelationalCypherSession(CypherSession):
         # cache-wide lock).
         with plan.exec_lock:
             context = plan.context
-            context.rebind(params)
-            reset_plan(plan.root)
-            rcache = self.result_cache
-            if rcache is not None:
-                # seed AFTER reset_plan (reset clears seeded memos)
-                rcache.seed_subplans(plan.root)
+            # the hit's own work before the first operator: same span
+            # name as the lookup (a profiler trace sums spans by name)
+            with obs.profiler_span("caps_tpu.plan.lookup"):
+                context.rebind(params)
+                reset_plan(plan.root)
+                rcache = self.result_cache
+                if rcache is not None:
+                    # seed AFTER reset_plan (reset clears seeded memos)
+                    rcache.seed_subplans(plan.root)
             t1 = clock.now()
             try:
                 with self.tracer.span("execute", kind="phase",

@@ -99,7 +99,7 @@ class Request:
 
     __slots__ = ("request_id", "query", "params", "graph", "priority",
                  "scope", "batch_key", "mode", "handle", "enqueued_t",
-                 "plan_key", "cache_key")
+                 "claimed_t", "plan_key", "cache_key")
 
     def __init__(self, query: str, params: Mapping[str, Any], graph: Any,
                  priority: int, scope: CancelScope,
@@ -124,6 +124,9 @@ class Request:
         self.mode = mode
         self.handle = QueryHandle(self)
         self.enqueued_t = 0.0
+        #: when a worker claimed it (queue wait ends, lock wait starts);
+        #: consumed by the first execution (serve/server.py _LockStay)
+        self.claimed_t = 0.0
         #: ``(result-cache key, snapshot version)`` stamped at admission
         #: when the read missed the result cache — completion offers the
         #: materialized rows back under exactly this key (serve/server.py)

@@ -81,6 +81,7 @@ from typing import Any, Dict, List, Mapping, Optional
 
 from caps_tpu.obs import clock
 from caps_tpu.obs.lockgraph import make_lock
+from caps_tpu.obs.tracer import profiler_span
 from caps_tpu.relational.result_cache import (CachedRows, ResultCacheConfig,
                                               graph_version,
                                               result_cache_key)
@@ -117,6 +118,52 @@ _LADDER = ("fused", "replan", "unfused")
 #: upper bound on a quarantined worker's nap between probe checks —
 #: keeps it responsive to shutdown without hot-spinning
 _PROBE_NAP_S = 0.05
+
+
+def _ids(reqs: List[Request]) -> str:
+    """Request ids as one span argument (spans of one request share it)."""
+    return " ".join(str(r.request_id) for r in reqs)
+
+
+class _LockStay:
+    """Request-side accounting of one stay under ``replica.lock``.
+
+    Built BEFORE ``with replica.lock:`` (opens the ``serve.lock_wait``
+    span), ``acquired()`` as the block's first statement (closes it,
+    charges each request's lock wait since its claim — or since this
+    wait began, for a re-execution — and returns the
+    ``caps_tpu.serve.execute`` span every span of the execution nests
+    under), ``released()`` as its last (the wall time the batch held the
+    lock, charged to each member).  The sums ride ``handle.info`` and
+    are observed once per request in ``_finish``."""
+
+    __slots__ = ("reqs", "_ids", "_wait", "_t0")
+
+    def __init__(self, reqs: List[Request]):
+        self.reqs = reqs
+        self._ids = _ids(reqs)
+        self._t0 = clock.now()      # wait began; from acquired(): hold began
+        # off-stream state: no ``caps_tpu.`` prefix (obs/tracer.py)
+        self._wait = profiler_span("serve.lock_wait", request_ids=self._ids)
+        self._wait.__enter__()
+
+    def acquired(self):
+        self._wait.__exit__(None, None, None)
+        entered, self._t0 = self._t0, clock.now()
+        for req in self.reqs:
+            info = req.handle.info
+            info["lock_wait_s"] = info.get("lock_wait_s", 0.0) + (
+                self._t0 - (req.claimed_t or entered))
+            req.claimed_t = 0.0
+        return profiler_span("caps_tpu.serve.execute",
+                             request_ids=self._ids, batch=len(self.reqs))
+
+    def released(self) -> float:
+        exec_s = clock.now() - self._t0
+        for req in self.reqs:
+            info = req.handle.info
+            info["execute_s"] = info.get("execute_s", 0.0) + exec_s
+        return exec_s
 
 
 def _fresh_copy(ex: BaseException) -> BaseException:
@@ -395,6 +442,12 @@ class QueryServer:
                                               buckets=_BATCH_BUCKETS)
         self._latency = registry.histogram("serve.latency_s")
         self._queue_wait = registry.histogram("serve.queue_wait_s")
+        # the rest of a read's latency, one observation per finished
+        # request: claim -> replica.lock acquired; wall time its batch
+        # held the lock; result materialization after the release
+        self._lock_wait = registry.histogram("serve.lock_wait_s")
+        self._execute = registry.histogram("serve.execute_s")
+        self._materialize = registry.histogram("serve.materialize_s")
         self._registry = registry
         self._threads: List[threading.Thread] = []
         self._started = False
@@ -873,6 +926,7 @@ class QueryServer:
                 req.handle._complete(exception=ex)
                 continue
             wait_s = now - req.enqueued_t
+            req.claimed_t = now
             req.handle.info["queue_wait_s"] = wait_s
             self._queue_wait.observe(wait_s)
             self.telemetry.note_queue_wait(wait_s)
@@ -924,7 +978,9 @@ class QueryServer:
             self._requeue(live)
             clock.sleep(_PROBE_NAP_S)
             return
-        with self._tracked(live):
+        # the request's envelope on this worker (off-stream name)
+        with self._tracked(live), profiler_span(
+                "serve.request", request_ids=_ids(live), batch=len(live)):
             self._execute_live(live, replica)
 
     def _execute_live(self, live: List[Request],
@@ -1055,31 +1111,33 @@ class QueryServer:
         for req in live:
             req.handle.info["batch_size"] = n
             req.handle.info["device"] = replica.index
+        stay = _LockStay(live)
         with replica.lock:
             # service time starts INSIDE the lock: time spent queued
-            # behind another batch on this device's stream is queueing,
-            # not service, and must not inflate the retry_after estimator
-            t0 = clock.now()
-            if n > 1:
-                try:
-                    with replica.activate():
-                        graph = replica.graph_for(live[0].graph)
-                        outcomes = replica.session.cypher_batch(
-                            graph, [(r.query, r.params) for r in live],
-                            scopes=[r.scope for r in live])
-                except BaseException as ex:  # replication / setup failed
-                    outcomes = [ex] + [_fresh_copy(ex)
-                                       for _ in live[1:]]
-            else:
-                req = live[0]
-                try:
-                    with cancel_scope(req.scope), replica.activate():
-                        graph = replica.graph_for(req.graph)
-                        outcomes = [replica.session.cypher_on_graph(
-                            graph, req.query, req.params)]
-                except BaseException as ex:
-                    outcomes = [ex]
-            exec_s = clock.now() - t0
+            # behind another batch on this device's stream is queueing
+            # (serve.lock_wait_s), not service, and must not inflate the
+            # retry_after estimator
+            with stay.acquired():
+                if n > 1:
+                    try:
+                        with replica.activate():
+                            graph = replica.graph_for(live[0].graph)
+                            outcomes = replica.session.cypher_batch(
+                                graph, [(r.query, r.params) for r in live],
+                                scopes=[r.scope for r in live])
+                    except BaseException as ex:  # replication / setup failed
+                        outcomes = [ex] + [_fresh_copy(ex)
+                                           for _ in live[1:]]
+                else:
+                    req = live[0]
+                    try:
+                        with cancel_scope(req.scope), replica.activate():
+                            graph = replica.graph_for(req.graph)
+                            outcomes = [replica.session.cypher_on_graph(
+                                graph, req.query, req.params)]
+                    except BaseException as ex:
+                        outcomes = [ex]
+            exec_s = stay.released()
         # feed the admission controller's retry_after estimator and the
         # telemetry window (service-time + per-device utilization)
         self.admission.observe_service(exec_s / n)
@@ -1279,10 +1337,11 @@ class QueryServer:
         """One (re-)execution of a single request at a ladder level on
         ``replica``'s device.  Returns the result or the raised
         exception; device-ladder bookkeeping included."""
+        stay = _LockStay([req])
         with replica.lock:
-            t0 = clock.now()
             try:
-                with cancel_scope(req.scope), replica.activate():
+                with stay.acquired(), cancel_scope(req.scope), \
+                        replica.activate():
                     graph = replica.graph_for(req.graph)
                     if level == 0:
                         out: Any = replica.session.cypher_on_graph(
@@ -1295,7 +1354,7 @@ class QueryServer:
                 attribute_device(ex, replica.index)
                 out = ex
             finally:
-                exec_s = clock.now() - t0
+                exec_s = stay.released()
         self.admission.observe_service(exec_s)
         self.telemetry.note_service(exec_s)
         self.telemetry.note_device_busy(replica.index, exec_s)
@@ -1351,8 +1410,12 @@ class QueryServer:
             req.handle._complete(exception=outcome)
             return
         rows = None
+        info = req.handle.info
+        t0 = clock.now()
         try:
-            with cancel_scope(req.scope), self._observed():
+            # after the lock's release: an off-stream span name
+            with cancel_scope(req.scope), self._observed(), profiler_span(
+                    "serve.materialize", request_ids=str(req.request_id)):
                 if self.config.materialize:
                     req.scope.raise_if_done("materialize")
                     rows = outcome.to_maps()
@@ -1362,6 +1425,10 @@ class QueryServer:
             self._flight(req, ex)
             req.handle._complete(exception=ex)
             return
+        info["materialize_s"] = clock.now() - t0
+        self._lock_wait.observe(info.get("lock_wait_s", 0.0))
+        self._execute.observe(info.get("execute_s", 0.0))
+        self._materialize.observe(info["materialize_s"])
         self._note_ledger(req, outcome)
         self._store_result(req, rows)
         req.handle.info["latency_s"] = req.scope.elapsed()
@@ -1537,6 +1604,9 @@ class QueryServer:
             "device": info.get("device"),
             "batch_size": info.get("batch_size"),
             "queue_wait_s": info.get("queue_wait_s"),
+            "lock_wait_s": info.get("lock_wait_s"),
+            "execute_s": info.get("execute_s"),
+            "materialize_s": info.get("materialize_s"),
             "latency_s": round(latency_s, 6),
             "phase": req.scope.phase,
             "outcome": "ok" if exc is None else type(exc).__name__,

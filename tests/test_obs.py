@@ -341,3 +341,231 @@ def test_sharded_query_counts_collectives(make_session):
                  and k != "collectives.unit_test_op.calls"
                  and isinstance(v, (int, float)))
     assert traced >= 1, sorted(k for k in snap if "collect" in k)
+
+
+# -- spans on the profiler's clock, sync accounting, xla events --------------
+
+def test_profiler_span_opens_annotation_and_tracer_span_together():
+    from caps_tpu.obs.tracer import profiler_span, timed_span
+    off = Tracer(enabled=False)
+    with profiler_span("caps_tpu.table.filter", off) as sp:
+        assert sp is NULL_SPAN          # the disabled contract holds
+    on = Tracer(enabled=True)
+    with profiler_span("caps_tpu.serve.execute", on, request_ids="7 8",
+                       batch=2) as outer:
+        with profiler_span("caps_tpu.Join", on, kind="operator",
+                           tracer_name="op.Join") as inner:
+            inner.annotate(rows=3)
+    assert outer.name == "caps_tpu.serve.execute"      # same name as the
+    assert outer.attrs == {"request_ids": "7 8", "batch": 2}  # annotation
+    assert [c.name for c in outer.children] == ["op.Join"]
+    assert outer.children[0].kind == "operator"
+    assert outer.children[0].rows == 3
+    # no tracer given, none active on this thread: nothing recorded
+    with profiler_span("caps_tpu.sync") as sp:
+        assert sp is NULL_SPAN
+    with timed_span("caps_tpu.sync") as t:
+        pass
+    assert t.seconds >= 0.0
+
+
+class _TickClock:
+    """Every read of ``now`` is one second later than the last."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def now(self) -> float:
+        self.t += 1.0
+        return self.t
+
+
+FOF2 = ("MATCH (a:Person)-[:KNOWS]->()-[:KNOWS]->(c) WHERE a.name = $name "
+        "RETURN count(DISTINCT c) AS n")
+
+
+def test_sync_accounting_one_syncs_worth_per_generic_replay(make_session,
+                                                            monkeypatch):
+    """``backend.syncs`` counts what it counted before the consolidation;
+    ``backend.sync_wait_s`` and ``backend.d2h_bytes`` grow with it, by
+    exactly one read a generic replay."""
+    session = make_session("tpu")
+    graph = create_graph(session, CREATE)
+    graph.cypher(FOF2, {"name": "Ada"})                   # records
+    assert session.fused.last_mode == "record"
+    snap0 = session.metrics_snapshot()
+    assert snap0["backend.syncs"] >= 1
+    assert snap0["backend.sync_wait_s"] > 0.0
+    assert snap0["backend.d2h_bytes"] >= snap0["backend.syncs"]
+    monkeypatch.setattr(clock, "now", _TickClock().now)
+    for i, name in enumerate(["Bo", "Cy", "Bo"], 1):
+        result = graph.cypher(FOF2, {"name": name})
+        d = diff_snapshots(snap0, session.metrics_snapshot())
+        if session.fused.last_mode == "replay":
+            break   # an exact replay: no sync at all, checked below
+        assert session.fused.last_mode == "replay_gen"
+        assert d["backend.syncs"] == i == d["fused.generic_replays"]
+        # one timed region a replay: two clock reads one second apart
+        assert d["backend.sync_wait_s"] == pytest.approx(float(i))
+        # the violation flag alone (the count's one row is not ``live``)
+        assert d["backend.d2h_bytes"] == i
+    before = session.metrics_snapshot()
+    graph.cypher(FOF2, {"name": "Ada"})                   # exact replay
+    assert session.fused.last_mode == "replay"
+    d = diff_snapshots(before, session.metrics_snapshot())
+    assert d["backend.syncs"] == 0 and d["backend.d2h_bytes"] == 0
+    assert d["backend.sync_wait_s"] == 0.0
+    # materialization is a device->host read too: timed and sized, but
+    # never a counted sync
+    rows = result.records.to_maps()
+    d = diff_snapshots(before, session.metrics_snapshot())
+    assert len(rows) == 1 and d["backend.syncs"] == 0
+    assert d["backend.d2h_bytes"] > 0 and d["backend.sync_wait_s"] >= 1.0
+
+
+def test_wait_accounting_loses_nothing_across_threads(make_session):
+    """A worker materializing a result accounts its reads beside the
+    worker that dispatches: neither's update may be lost."""
+    import threading
+    backend = make_session("tpu").backend
+    base = (backend.syncs, backend.sync_wait_s, backend.d2h_bytes)
+    n = 20_000
+
+    def reads(counted):
+        for _ in range(n):
+            backend.account_wait(0.5, 8, syncs=counted)
+
+    threads = [threading.Thread(target=reads, args=(c,)) for c in (1, 0)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert backend.syncs == base[0] + n
+    assert backend.sync_wait_s == pytest.approx(base[1] + n)  # 2n halves
+    assert backend.d2h_bytes == base[2] + 16 * n
+
+
+def test_xla_events_count_first_shape_compiles_only(make_session):
+    import jax
+    import jax.numpy as jnp
+    session = make_session("tpu")       # installs the listener
+    snap = session.metrics_snapshot()
+    assert {"xla.traces", "xla.compiles", "xla.compile_s"} <= set(snap)
+    # inputs first: making them may compile too, or not (other tests)
+    x11, x13 = jnp.arange(11), jnp.arange(13)
+    jax.block_until_ready((x11, x13))
+    snap = session.metrics_snapshot()
+    fn = jax.jit(lambda x: x * 3 + 1)
+    fn(x11).block_until_ready()                         # first shape
+    first = diff_snapshots(snap, session.metrics_snapshot())
+    assert first["xla.compiles"] >= 1 and first["xla.traces"] >= 1
+    assert first["xla.compile_s"] > 0.0
+    snap = session.metrics_snapshot()
+    fn(x11).block_until_ready()                         # cached
+    again = diff_snapshots(snap, session.metrics_snapshot())
+    assert again["xla.compiles"] == 0 and again["xla.traces"] == 0
+    # a second session does not install a second listener
+    make_session("tpu")
+    snap = session.metrics_snapshot()
+    jax.jit(lambda x: x * 5 - 2)(x13).block_until_ready()
+    once = diff_snapshots(snap, session.metrics_snapshot())
+    assert once["xla.compiles"] == first["xla.compiles"]
+
+
+def _contains(outer, inner) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_profiler_trace_nests_spans_under_the_executing_thread(
+        make_session, tmp_path):
+    """Three served reads (a recording, a generic replay, an exact
+    replay) under ``jax.profiler``: on the thread that holds the
+    execution lock ``caps_tpu.serve.execute`` > ``caps_tpu.fused.*`` >
+    ``caps_tpu.<Operator>`` > ``caps_tpu.table.*`` > ``caps_tpu.sync``;
+    what a worker does without the lock carries no ``caps_tpu.`` name."""
+    import jax
+    from jax.profiler import ProfileData
+    from caps_tpu.serve import QueryServer
+    session = make_session("tpu")
+    graph = create_graph(session, CREATE)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with QueryServer(session, graph=graph) as server:
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            modes = []
+            for name in ("Ada", "Bo", "Ada"):
+                assert len(server.submit(FOF2, {"name": name})
+                           .rows(timeout=60)) == 1
+                modes.append(session.fused.last_mode)
+        finally:
+            jax.profiler.stop_trace()
+    assert modes == ["record", "replay_gen", "replay"]
+    import glob
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    threads = []        # per host thread: [(name, start, end)]
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            evs = [(e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
+                   for e in line.events
+                   if e.name.startswith(("caps_tpu.", "serve.", "table."))]
+            if evs:
+                threads.append(evs)
+    everything = [e for evs in threads for e in evs]
+    names = {e[0] for e in everything}
+    assert {"serve.request", "serve.lock_wait", "serve.materialize",
+            "table.to_host", "caps_tpu.serve.execute", "caps_tpu.plan.lookup",
+            "caps_tpu.fused.record", "caps_tpu.fused.replay_gen",
+            "caps_tpu.fused.replay", "caps_tpu.fused.epilogue",
+            "caps_tpu.Join", "caps_tpu.table.filter",
+            "caps_tpu.table.join.probe", "caps_tpu.table.join.expand",
+            "caps_tpu.table.join.gather", "caps_tpu.table.group",
+            "caps_tpu.sync"} <= names, sorted(names)
+    # arguments never became part of a name (trace_reduce sums by name)
+    assert not [n for n in names if "#" in n or "=" in n]
+    executes = [e for e in everything if e[0] == "caps_tpu.serve.execute"]
+    assert len(executes) == 3
+    for evs in threads:
+        held = [e for e in evs if e[0] == "caps_tpu.serve.execute"]
+        for e in evs:
+            if e[0].startswith("caps_tpu.") and e not in held:
+                # on-stream names only on the thread holding the lock,
+                # while it holds it
+                assert any(_contains(h, e) for h in held), e
+            if e[0] in ("serve.lock_wait", "serve.materialize"):
+                assert not any(_contains(h, e) for h in held), e
+    def inside(name_prefix, outers):
+        return [e for evs in threads for e in evs
+                if e[0].startswith(name_prefix)
+                and any(_contains(o, e) and o is not e for o in outers)]
+    fused = inside("caps_tpu.fused.", executes)
+    assert {e[0] for e in fused} == {
+        "caps_tpu.fused.record", "caps_tpu.fused.replay_gen",
+        "caps_tpu.fused.replay", "caps_tpu.fused.epilogue"}
+    operators = [e for e in inside("caps_tpu.", fused)
+                 if e[0].split(".")[1][:1].isupper()]
+    assert {"caps_tpu.Join", "caps_tpu.Aggregate"} <= {e[0]
+                                                       for e in operators}
+    tables = inside("caps_tpu.table.", operators)
+    assert tables
+    syncs = [e for e in everything if e[0] == "caps_tpu.sync"]
+    # the recording syncs every size inside the table method that needs
+    # it; a generic replay syncs once, in the epilogue; an exact replay
+    # not at all
+    record = [e for e in fused if e[0] == "caps_tpu.fused.record"][0]
+    in_record = [s for s in syncs if _contains(record, s)]
+    assert in_record and all(any(_contains(t, s) for t in tables)
+                             for s in in_record)
+    epilogues = [e for e in fused if e[0] == "caps_tpu.fused.epilogue"]
+    assert len(epilogues) == 1
+    assert [s for s in syncs if _contains(epilogues[0], s)]
+    assert len(syncs) == len(in_record) + 1
+    materialize = [e for e in everything if e[0] == "serve.materialize"]
+    assert len(materialize) == 3
+    # its column reads are named off-stream (a cold plan's statistics
+    # sketch reads columns under the lock, under the same name)
+    assert all([t for t in everything if t[0] == "table.to_host"
+                and _contains(m, t)] for m in materialize)

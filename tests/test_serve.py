@@ -87,6 +87,47 @@ def test_submit_and_rows(backend):
         assert res.to_maps() == [{"c": 3}]
 
 
+@pytest.mark.parametrize("backend", ["local", "tpu"])
+def test_every_served_read_is_accounted_phase_by_phase(backend):
+    """Queue wait, lock wait, execute and materialize: one observation of
+    each per served read, on the handle and in the flight recorder."""
+    session = _session(backend)
+    graph = _graph(session)
+    n = 12
+    with QueryServer(session, graph=graph) as server:
+        before = session.metrics_snapshot()
+        # registered at 0 when the server is built, not at first use
+        for name in ("lock_wait_s", "execute_s", "materialize_s"):
+            assert before[f"serve.{name}.count"] == 0
+            assert before[f"serve.{name}.sum"] == 0.0
+        handles = [server.submit(QUERIES[i % 3][0],
+                                 QUERIES[i % 3][1][i % 3])
+                   for i in range(n)]
+        for h in handles:
+            h.rows(timeout=60)
+        snap = session.metrics_snapshot()
+        flights = {r["request_id"]: r
+                   for r in server.dump_flight_recorder()["records"]}
+    assert snap["serve.lock_wait_s.count"] == n
+    assert snap["serve.execute_s.count"] == n
+    assert snap["serve.materialize_s.count"] == n
+    assert snap["serve.queue_wait_s.count"] == n
+    total = {k: 0.0 for k in ("lock_wait_s", "execute_s", "materialize_s")}
+    for h in handles:
+        info = h.info
+        parts = [info[k] for k in ("queue_wait_s", "lock_wait_s",
+                                   "execute_s", "materialize_s")]
+        assert all(p >= 0.0 for p in parts) and info["execute_s"] > 0.0
+        # the phases are disjoint stretches of the request's life
+        assert sum(parts) <= info["latency_s"] + 1e-6
+        rec = flights[h._request.request_id]
+        for k in total:
+            total[k] += info[k]
+            assert rec[k] == info[k]
+    for k, v in total.items():
+        assert snap[f"serve.{k}.sum"] == pytest.approx(v)
+
+
 def test_submit_after_shutdown_raises():
     session = _session()
     server = QueryServer(session, graph=_graph(session))

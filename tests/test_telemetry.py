@@ -233,6 +233,56 @@ def test_retry_after_falls_back_to_ema_without_samples(fake_clock):
     no_tel.close()
 
 
+# -- the phases of a served read ---------------------------------------------
+
+def test_request_phases_add_up_to_its_latency(fake_clock, monkeypatch):
+    """Queue wait + lock wait + execute + materialize is the request's
+    latency: on the fake clock each phase is made to take a known time
+    and nothing else takes any."""
+    import time
+    from caps_tpu.relational.session import RelationalCypherResult
+    from caps_tpu.testing.faults import slow_operator
+    session = _session()
+    graph = create_graph(session, SOCIAL)
+    server = QueryServer(session, graph=graph, start=False,
+                         config=ServerConfig(workers=1))
+    real_to_maps = RelationalCypherResult.to_maps
+
+    def slow_to_maps(self):
+        clock.sleep(0.25)                      # materialize: 0.25 s
+        return real_to_maps(self)
+
+    monkeypatch.setattr(RelationalCypherResult, "to_maps", slow_to_maps)
+    lock = server.devices.replicas[0].lock
+    h = server.submit(Q_ORDER, {"min": 30})
+    fake_clock.advance(2.0)                    # queued, no worker: 2 s
+    with slow_operator("Filter", 0.5):         # execute: 0.5 s
+        with lock:                             # someone else's batch
+            server.start()
+            deadline = time.monotonic() + 30
+            while "queue_wait_s" not in h.info:    # the worker's claim
+                assert time.monotonic() < deadline
+                time.sleep(0.001)
+            fake_clock.advance(3.0)            # waiting for the lock: 3 s
+        assert [r["n"] for r in h.rows(timeout=30)] == ["Alice", "Bob",
+                                                        "Dana"]
+    server.shutdown()
+    info = h.info
+    assert info["queue_wait_s"] == pytest.approx(2.0)
+    assert info["lock_wait_s"] == pytest.approx(3.0)
+    assert info["execute_s"] == pytest.approx(0.5)
+    assert info["materialize_s"] == pytest.approx(0.25)
+    assert info["latency_s"] == pytest.approx(
+        info["queue_wait_s"] + info["lock_wait_s"] + info["execute_s"]
+        + info["materialize_s"])
+    snap = session.metrics_snapshot()
+    assert snap["serve.lock_wait_s.sum"] == pytest.approx(3.0)
+    assert snap["serve.execute_s.sum"] == pytest.approx(0.5)
+    assert snap["serve.materialize_s.sum"] == pytest.approx(0.25)
+    # the server's service-time estimator still sees the lock's hold
+    assert server.admission.ema_service_s == pytest.approx(0.5)
+
+
 # -- flight recorder ---------------------------------------------------------
 
 def test_flight_recorder_ring_bounds_and_dumps():
