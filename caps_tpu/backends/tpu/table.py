@@ -763,7 +763,7 @@ class DeviceTable(Table):
         count = K.mask_count(mask)
         new_n, live = self.backend.consume_rows(count)
         out_cap = self.backend.bucket(new_n)
-        idx, _ = K.compact_indices(mask, out_cap)
+        idx = K.compact_indices(mask, out_cap)
         idx = self.backend.place_rows(idx)
         return DeviceTable(self.backend, _gather_cols(self._cols, idx),
                            new_n, live=live)
@@ -1201,7 +1201,7 @@ class DeviceTable(Table):
                   else jnp.int32(other._n))
         t = jnp.arange(out_cap)
         mask = (t < live_a) | ((t >= self._n) & (t < self._n + live_b))
-        idx, _ = K.compact_indices(mask, out_cap)
+        idx = K.compact_indices(mask, out_cap)
         return DeviceTable(self.backend, _gather_cols(out, idx), total,
                            live=(live_a + live_b).astype(jnp.int32))
 
@@ -1319,7 +1319,7 @@ class DeviceTable(Table):
             row_ok_sorted = self.row_ok
         out_cap = self.backend.bucket(n_groups)
         if by:
-            start_idx, _ = K.compact_indices(change, out_cap)
+            start_idx = K.compact_indices(change, out_cap)
         else:
             start_idx = jnp.zeros(out_cap, jnp.int32)
 

@@ -1,5 +1,8 @@
 """TPU-backend specifics: differential parity vs the oracle and
 zero-fallback guarantees on the hot path."""
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from caps_tpu.backends.local.session import LocalCypherSession
@@ -51,6 +54,47 @@ def test_differential_parity(graphs, query):
     expected = g_local.cypher(query).records.to_maps()
     actual = g_tpu.cypher(query).records.to_maps()
     assert Bag(actual) == Bag(expected), Bag(expected).diff(Bag(actual))
+
+
+# (n, out_cap, kept rows, whether the shapes select rank search): both
+# sides of compact_indices' shape rule, far from its crossover
+COMPACT_CASES = {
+    "narrow-empty": (4096, 64, [], True),
+    "narrow-first-row": (4096, 64, [0], True),
+    "narrow-last-row": (4096, 64, [4095], True),
+    "narrow-count-is-out_cap": (8192, 64, range(5, 8192, 128), True),
+    "narrow-count-over-out_cap": (8192, 64, range(0, 8192, 3), True),
+    "narrow-dense": (4096, 64, range(4000, 4050), True),
+    "n-far-over-out_cap": (1 << 18, 16, [7, 1 << 17, (1 << 18) - 1], True),
+    "narrow-odd-n": (5000, 32, [0, 1, 2499, 4999], True),
+    "wide-empty": (256, 256, [], False),
+    "wide-first-row": (256, 256, [0], False),
+    "wide-last-row": (256, 256, [255], False),
+    "wide-full": (256, 256, range(256), False),
+    "wide-count-over-out_cap": (1024, 800, range(1024), False),
+    "wide-odd-n": (100, 128, range(1, 100, 2), False),
+    "wide-million": (1 << 20, 1 << 20, range(0, 1 << 20, 1 << 10), False),
+}
+
+
+@pytest.mark.parametrize("case", list(COMPACT_CASES))
+def test_compact_indices_matches_flatnonzero(case):
+    """Values, order, fill, dtype and shape are jnp.nonzero's whichever
+    program the two static shapes select."""
+    from caps_tpu.backends.tpu import kernels as K
+    n, out_cap, kept, rank_search = COMPACT_CASES[case]
+    rows = np.zeros(n, bool)
+    rows[list(kept)] = True
+    expected = np.zeros(out_cap, np.int64)
+    first = np.flatnonzero(rows)[:out_cap]
+    expected[:len(first)] = first
+    mask = jnp.asarray(rows)
+    got = K.compact_indices(mask, out_cap)
+    assert got.dtype == jnp.int64 and got.shape == (out_cap,)
+    np.testing.assert_array_equal(np.asarray(got), expected)
+    program = str(jax.make_jaxpr(
+        lambda m: K.compact_indices(m, out_cap))(mask))
+    assert ("scatter" not in program) == rank_search
 
 
 def test_hot_path_has_no_fallbacks():

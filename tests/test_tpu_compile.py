@@ -108,6 +108,18 @@ def test_expand_kernel_compiles(one_chip, cap_l, out_cap):
     assert _has_kernel(compiled)
 
 
+@pytest.mark.parametrize("n,out_cap,scatter_free", [
+    (1 << 20, 256, True), (4096, 4096, False)])
+def test_compact_indices_compiles(one_chip, n, out_cap, scatter_free):
+    """The filter's compaction of a million-row mask into a small bucket
+    must not scatter one update per input row (64 ms a read on the v5e,
+    PR 28); the same-capacity compaction keeps jnp.nonzero's program."""
+    from caps_tpu.backends.tpu.kernels import compact_indices
+    compiled = compact_indices.lower(one_chip((n,), jnp.bool_),
+                                     out_cap=out_cap).compile()
+    assert ("scatter" not in compiled.as_text()) == scatter_free
+
+
 def test_fused_count_program_compiles(one_chip):
     """Config 1 through the count push-down, from a small graph."""
     from caps_tpu.backends.tpu.session import TPUCypherSession
