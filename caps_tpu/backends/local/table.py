@@ -116,19 +116,23 @@ class LocalTable(Table):
     # -- row ops ------------------------------------------------------------
 
     def filter(self, expr: Expr, header: RecordHeader,
-               parameters: Mapping[str, Any]) -> "LocalTable":
+               parameters: Mapping[str, Any],
+               keep: Optional[Sequence[str]] = None) -> "LocalTable":
         from caps_tpu.backends.local.expr import evaluate
         mask = evaluate(expr, self._size, lambda c: self._data[c], header,
                         parameters)
-        keep = [i for i, v in enumerate(mask) if v is True]
-        return self._take(keep)
+        rows = [i for i, v in enumerate(mask) if v is True]
+        return (self if keep is None else self.select(keep))._take(rows)
 
     def _take(self, idx: List[int]) -> "LocalTable":
         data = {c: [v[i] for i in idx] for c, v in self._data.items()}
         return self._with(self._columns, data, self._types, size=len(idx))
 
     def join(self, other: Table, how: str,
-             pairs: Sequence[Tuple[str, str]]) -> "LocalTable":
+             pairs: Sequence[Tuple[str, str]],
+             keep: Optional[Sequence[str]] = None) -> "LocalTable":
+        if keep is not None:
+            return self.join(other, how, pairs).select(keep)
         assert isinstance(other, LocalTable)
         shared = set(self._columns) & set(other._columns)
         if shared:

@@ -190,6 +190,8 @@ class TPUCypherSession(RelationalCypherSession):
             "backend.syncs": be.syncs,
             "backend.sync_wait_s": be.sync_wait_s,
             "backend.d2h_bytes": be.d2h_bytes,
+            "backend.gathered_columns": be.gathered_columns,
+            "backend.pruned_columns": be.pruned_columns,
             "backend.kernel.expand": be.kernel_launches["expand"],
             "backend.kernel.segment": be.kernel_launches["segment"],
             "backend.kernel.sort": be.kernel_launches["sort"],

@@ -144,6 +144,11 @@ class DeviceBackend:
         # materialization alike): all through account_wait()
         self.sync_wait_s = 0.0
         self.d2h_bytes = 0
+        # projection push-down (relational/live_columns.py): columns
+        # passed to _gather_cols, and columns a join or filter had and
+        # did not gather because its ``keep`` left them out
+        self.gathered_columns = 0
+        self.pruned_columns = 0
         self._wait_lock = make_lock("table.DeviceBackend._wait_lock")
         # Pallas kernel launches per family (ops/kernel_table.py FAMILIES):
         # counted where the engine dispatches the kernel itself, not a
@@ -722,9 +727,10 @@ class DeviceTable(Table):
 
     @_spanned("caps_tpu.table.filter")
     def filter(self, expr: Expr, header: RecordHeader,
-               parameters) -> "DeviceTable":
+               parameters, keep=None) -> "DeviceTable":
         if self._local is not None:
-            return self._wrap_local(self._local.filter(expr, header, parameters))
+            return self._wrap_local(
+                self._local.filter(expr, header, parameters, keep))
         try:
             compiler = DeviceExprCompiler(self._cols, self.capacity, header,
                                           parameters, self.backend.pool,
@@ -733,10 +739,11 @@ class DeviceTable(Table):
             if pred.kind != "bool":
                 raise UnsupportedOnDevice("filter predicate is not boolean")
         except UnsupportedOnDevice as ex:
-            return self._fallback(str(ex)).filter(expr, header, parameters)
+            return self._fallback(str(ex)).filter(expr, header, parameters,
+                                                  keep)
         self._raise_row_errors(compiler)
         mask = pred.data & pred.valid & self.row_ok
-        return self._compact(mask)
+        return self._compact(mask, keep)
 
     def drop_in(self, col: str, values) -> "DeviceTable":
         """Tombstone mask (relational/updates.py snapshot overlay): drop
@@ -759,32 +766,47 @@ class DeviceTable(Table):
         hit = jnp.isin(c.data, jnp.asarray(padded)) & c.valid
         return self._compact(self.row_ok & ~hit)
 
-    def _compact(self, mask: jnp.ndarray) -> "DeviceTable":
+    def _kept(self, keep, also=()) -> Dict[str, Column]:
+        """The columns a join or filter gathers: those in ``keep``
+        (None: all) or ``also``; the rest are counted as pruned."""
+        if keep is None:
+            return self._cols
+        want = set(keep).union(also)
+        cols = {c: col for c, col in self._cols.items() if c in want}
+        self.backend.pruned_columns += len(self._cols) - len(cols)
+        return cols
+
+    def _compact(self, mask: jnp.ndarray, keep=None) -> "DeviceTable":
         count = K.mask_count(mask)
         new_n, live = self.backend.consume_rows(count)
         out_cap = self.backend.bucket(new_n)
         idx = K.compact_indices(mask, out_cap)
         idx = self.backend.place_rows(idx)
-        return DeviceTable(self.backend, _gather_cols(self._cols, idx),
+        return DeviceTable(self.backend,
+                           self._gather(self._kept(keep), idx),
                            new_n, live=live)
 
+    def _gather(self, cols: Dict[str, Column], idx) -> Dict[str, Column]:
+        self.backend.gathered_columns += len(cols)
+        return _gather_cols(cols, idx)
+
     def join(self, other: Table, how: str,
-             pairs: Sequence[Tuple[str, str]]) -> "DeviceTable":
+             pairs: Sequence[Tuple[str, str]], keep=None) -> "DeviceTable":
         if self._local is not None or (isinstance(other, DeviceTable)
                                        and other.is_local):
             return self._wrap_local(self.to_local().join(
-                self._coerce_local(other), how, pairs))
+                self._coerce_local(other), how, pairs, keep))
         assert isinstance(other, DeviceTable)
         shared = set(self.columns) & set(other.columns)
         if shared:
             raise ValueError(f"join column collision: {shared}")
         try:
             if how == "cross":
-                return self._cross_join(other)
-            return self._sort_merge_join(other, how, pairs)
+                return self._cross_join(other, keep)
+            return self._sort_merge_join(other, how, pairs, keep)
         except UnsupportedOnDevice as ex:
             return self._wrap_local(self.to_local().join(
-                other.to_local(), how, pairs))
+                other.to_local(), how, pairs, keep))
 
     def _join_key(self, col: Column, side: str = "l") -> jnp.ndarray:
         if col.kind in ("id", "int", "str", "bool"):
@@ -838,7 +860,8 @@ class DeviceTable(Table):
         return jnp.where(lcol.valid, self._join_key(lcol), K._L_NULL)
 
     def _sort_merge_join(self, other: "DeviceTable", how: str,
-                         pairs: Sequence[Tuple[str, str]]) -> "DeviceTable":
+                         pairs: Sequence[Tuple[str, str]],
+                         keep=None) -> "DeviceTable":
         lc, rc = pairs[0]
         lcol, rcol = self._cols[lc], other._cols[rc]
         l_ok = self.row_ok
@@ -850,7 +873,7 @@ class DeviceTable(Table):
             # of leaving the layout to GSPMD (parallel/dist_join.py).
             dist = self._dist_join(other, how, pairs)
             if dist is not None:
-                return dist
+                return dist if keep is None else dist.select(keep)
         if csr is not None:
             perm = csr.perm
         else:
@@ -878,21 +901,26 @@ class DeviceTable(Table):
             l_idx = self.backend.place_rows(l_idx)
             r_idx = self.backend.place_rows(r_idx)
         with profiler_span("caps_tpu.table.join.gather"):
-            # where _gather_tree's whole-column int64 splits are dispatched
-            out_cols = _gather_cols(self._cols, l_idx)
-            right = _gather_cols(other._cols, r_idx)
+            # where _gather_tree's whole-column int64 splits are dispatched.
+            # Only live columns are written: the probe read the first
+            # pair's keys above, the extra pairs' keys stay until
+            # _extra_pair_filter has read them.
+            extra = [c for p in pairs[1:] for c in p]
+            out_cols = self._gather(self._kept(keep, extra), l_idx)
+            right = self._gather(other._kept(keep, extra), r_idx)
             for c, col in right.items():
                 out_cols[c] = Column(col.kind, col.data, col.valid & r_matched,
                                      col.ctype, col.lens)
         out = DeviceTable(self.backend, out_cols, total, live=live)
-        return out._extra_pair_filter(pairs, left_join)
+        return out._extra_pair_filter(pairs, left_join, keep)
 
     def _extra_pair_filter(self, pairs: Sequence[Tuple[str, str]],
-                           left_join: bool) -> "DeviceTable":
+                           left_join: bool, keep=None) -> "DeviceTable":
         """Extra equality pairs: post-filter (the first pair drove the
-        merge)."""
+        merge); the last one narrows to ``keep``."""
         out = self
-        for lc2, rc2 in pairs[1:]:
+        last = len(pairs) - 1
+        for i, (lc2, rc2) in enumerate(pairs[1:], start=1):
             a, b = out._cols[lc2], out._cols[rc2]
             if a.kind == "float" or b.kind == "float":
                 # NaN == NaN is False here, matching join semantics
@@ -903,10 +931,11 @@ class DeviceTable(Table):
                     & a.valid & b.valid
             if left_join:
                 # unmatched left rows keep their single null-extended row
-                keep = eq | ~out._cols[rc2].valid
+                rows = eq | ~out._cols[rc2].valid
             else:
-                keep = eq
-            out = out._compact(keep & out.row_ok)
+                rows = eq
+            out = out._compact(rows & out.row_ok,
+                               keep if i == last else None)
         return out
 
     @staticmethod
@@ -1145,7 +1174,7 @@ class DeviceTable(Table):
         out = tmp._compact(l_valid)
         return out._extra_pair_filter(pairs, left_join)
 
-    def _cross_join(self, other: "DeviceTable") -> "DeviceTable":
+    def _cross_join(self, other: "DeviceTable", keep=None) -> "DeviceTable":
         total = self._n * other._n
         out_cap = self.backend.bucket(total)
         # per-live-left-row pair count: the exact device count when the
@@ -1159,8 +1188,8 @@ class DeviceTable(Table):
                          0, max(0, self.capacity - 1))
         seg_start = jnp.where(l_idx > 0, offsets[l_idx - 1], 0)
         within = (t - seg_start) % max(1, other.capacity)
-        out_cols = _gather_cols(self._cols, l_idx)
-        out_cols.update(_gather_cols(other._cols, within))
+        out_cols = self._gather(self._kept(keep), l_idx)
+        out_cols.update(self._gather(other._kept(keep), within))
         live = (offsets[-1].astype(jnp.int32)
                 if (self._live is not None or other._live is not None)
                 and self.capacity > 0 else None)
@@ -1202,7 +1231,7 @@ class DeviceTable(Table):
         t = jnp.arange(out_cap)
         mask = (t < live_a) | ((t >= self._n) & (t < self._n + live_b))
         idx = K.compact_indices(mask, out_cap)
-        return DeviceTable(self.backend, _gather_cols(out, idx), total,
+        return DeviceTable(self.backend, self._gather(out, idx), total,
                            live=(live_a + live_b).astype(jnp.int32))
 
     def _sort_perm(self, keys: List[jnp.ndarray]) -> jnp.ndarray:
@@ -1230,7 +1259,7 @@ class DeviceTable(Table):
             perm = self._sort_perm(keys)
         except UnsupportedOnDevice as ex:
             return self._fallback(str(ex)).distinct()
-        sorted_cols = _gather_cols(self._cols, perm)
+        sorted_cols = self._gather(self._cols, perm)
         change = K.neighbor_change_keys([k[perm] for k in keys])
         # the sort puts dead rows last, so the sorted live mask is the
         # row_ok PREFIX (includes the generic-replay live count, which a
@@ -1253,7 +1282,7 @@ class DeviceTable(Table):
             perm = self._sort_perm(keys)
         except UnsupportedOnDevice as ex:
             return self._fallback(str(ex)).order_by(items)
-        return DeviceTable(self.backend, _gather_cols(self._cols, perm),
+        return DeviceTable(self.backend, self._gather(self._cols, perm),
                            self._n, live=self._live)
 
     def skip(self, n: int) -> "DeviceTable":
@@ -1266,7 +1295,7 @@ class DeviceTable(Table):
         idx = jnp.clip(idx, 0, max(0, self.capacity - 1))
         live = (jnp.maximum(self._live - n, 0).astype(jnp.int32)
                 if self._live is not None else None)
-        return DeviceTable(self.backend, _gather_cols(self._cols, idx),
+        return DeviceTable(self.backend, self._gather(self._cols, idx),
                            new_n, live=live)
 
     def limit(self, n: int) -> "DeviceTable":
@@ -1277,7 +1306,7 @@ class DeviceTable(Table):
         idx = jnp.clip(jnp.arange(out_cap), 0, max(0, self.capacity - 1))
         live = (jnp.minimum(self._live, n).astype(jnp.int32)
                 if self._live is not None else None)
-        return DeviceTable(self.backend, _gather_cols(self._cols, idx),
+        return DeviceTable(self.backend, self._gather(self._cols, idx),
                            new_n, live=live)
 
     # -- aggregation ------------------------------------------------------
@@ -1303,7 +1332,7 @@ class DeviceTable(Table):
             for c in by:
                 keys.extend(_sort_keys(self._cols[c], True, True, pool))
             perm = self._sort_perm(keys)
-            sorted_cols = _gather_cols(self._cols, perm)
+            sorted_cols = self._gather(self._cols, perm)
             row_ok_sorted = self.row_ok[perm]
             change = K.neighbor_change_keys(
                 [k[perm] for k in keys[1:]]) & row_ok_sorted
@@ -1638,7 +1667,7 @@ class DeviceTable(Table):
         out_cap = self.backend.bucket(total)
         row, within, out_valid, _ = K.explode_expand(col.lens, ok, out_cap)
         rest = {c: v for c, v in self._cols.items() if c != list_col}
-        out_cols = _gather_cols(rest, row)
+        out_cols = self._gather(rest, row)
         values = col.data[row, jnp.clip(within, 0, col.data.shape[1] - 1)]
         out_kind = kind_for(out_type)
         if out_kind == "object":
@@ -1726,7 +1755,7 @@ class DeviceTable(Table):
 def _gather_tree(arrays, idx):
     """One fused dispatch for a whole-table gather: every per-column
     row-gather rides a single XLA executable instead of 2-3 dispatches per
-    column (each dispatch is a round trip on remote-device transports)."""
+    column (a launch costs the host ~0.2 ms, PERF.md §6)."""
     return jax.tree_util.tree_map(lambda a: a[idx], arrays)
 
 

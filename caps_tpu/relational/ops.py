@@ -170,6 +170,11 @@ def host_eval(expr: E.Expr, parameters: Mapping[str, Any]) -> Any:
 class RelationalOperator(abc.ABC):
     """Base: caches the computed (header, table) pair."""
 
+    #: what the plan above reads of this operator's output
+    #: (relational/live_columns.py; None = all of it).  Set once per
+    #: plan, so a cached plan keeps it.
+    required = None
+
     def __init__(self, context: RelationalRuntimeContext,
                  children: Sequence["RelationalOperator"] = ()):
         self.context = context
@@ -341,6 +346,47 @@ class RelationalOperator(abc.ABC):
         return ""
 
 
+def _live(required, header: RecordHeader, key: Optional[E.Expr] = None
+          ) -> Tuple[RecordHeader, Optional[List[str]]]:
+    """The header a join or filter writes and its ``keep`` for the Table
+    SPI.  Never zero columns: a table without columns takes its capacity
+    from its row count, which under generic replay is a bound — when
+    nothing is required, ``key`` (a join's probe-side key; else the
+    first entry) stays."""
+    if required is None:
+        return header, None
+    out = required.narrow(header)
+    if not out.columns:
+        out = header.select([key] if key is not None else header.exprs[:1])
+    return out, list(out.columns)
+
+
+def _keeps(op: "RelationalOperator") -> str:
+    """The ``keeps=[...]`` suffix of a join's or filter's EXPLAIN line
+    (empty when it keeps everything), cut to the vars bound below it."""
+    if op.required is None:
+        return ""
+    names: Optional[set] = set()
+    seen, stack = set(), [op]
+    while stack:
+        o = stack.pop()
+        if id(o) in seen:
+            continue
+        seen.add(id(o))
+        if isinstance(o, ScanOp):
+            names.add(o.var)
+        elif isinstance(o, ProjectOp):
+            names.update(n for n, _, _ in o.items)
+        elif isinstance(o, AggregateOp):
+            names.update(n for n, _, _ in o.group + o.aggregations)
+        elif not isinstance(o, (FilterOp, JoinOp, SelectOp, OrderByOp,
+                                SkipOp, LimitOp, DistinctOp, StartOp)):
+            names = None  # binds names this walk does not know
+            break
+        stack.extend(o.children)
+    return " " + op.required.describe(names)
+
+
 class StartOp(RelationalOperator):
     """A single empty driving row (or an externally supplied driving table)."""
 
@@ -394,10 +440,12 @@ class FilterOp(RelationalOperator):
     def _compute(self):
         header, table = self.children[0].result
         pred = resolve_expr(self.predicate, header)
-        return header, table.filter(pred, header, self.parameters)
+        out_header, keep = _live(self.required, header)
+        return out_header, table.filter(pred, header, self.parameters,
+                                        keep=keep)
 
     def _pretty_args(self):
-        return self.predicate.cypher_repr()
+        return self.predicate.cypher_repr() + _keeps(self)
 
 
 class SelectOp(RelationalOperator):
@@ -537,13 +585,14 @@ class JoinOp(RelationalOperator):
         lh, lt = self.children[0].result
         rh, rt = self.children[1].result
         col_pairs = [(lh.column(le), rh.column(re)) for le, re in self.pairs]
-        out_header = lh.concat(rh)
-        return out_header, lt.join(rt, self.how, col_pairs)
+        out_header, keep = _live(self.required, lh.concat(rh),
+                                 self.pairs[0][0])
+        return out_header, lt.join(rt, self.how, col_pairs, keep=keep)
 
     def _pretty_args(self):
         conds = ", ".join(f"{l.cypher_repr()}={r.cypher_repr()}"
                           for l, r in self.pairs)
-        return f"{self.how}: {conds}"
+        return f"{self.how}: {conds}" + _keeps(self)
 
 
 class CrossOp(RelationalOperator):

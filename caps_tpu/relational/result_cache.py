@@ -236,7 +236,9 @@ def _prefix_signature(op) -> Optional[Tuple]:
         child_sig = _prefix_signature(op.children[0])
         if child_sig is None:
             return None
-        return child_sig + (("filter", repr(op.predicate)),)
+        # what the filter writes is part of its identity: it keeps only
+        # the columns its own plan still reads (relational/live_columns.py)
+        return child_sig + (("filter", repr(op.predicate), op.required),)
     return None
 
 

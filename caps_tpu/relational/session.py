@@ -37,6 +37,7 @@ from caps_tpu.okapi.types import (
 )
 from caps_tpu.okapi.values import CypherNode, CypherPath, CypherRelationship
 from caps_tpu.relational import ops as R
+from caps_tpu.relational import live_columns
 from caps_tpu.relational.graphs import EmptyGraph, RelationalCypherGraph, ScanGraph
 from caps_tpu.relational.header import RecordHeader
 from caps_tpu.relational.plan_cache import (
@@ -587,6 +588,8 @@ class RelationalCypherSession(CypherSession):
                                             self._graph_resolver,
                                             cost_model=model)
             root = rel_planner.process(logical)
+            # joins and filters gather only what the plan still reads
+            live_columns.annotate_required(root)
         if model is not None:
             from caps_tpu.relational.cost import annotate_plan
             try:

@@ -123,14 +123,21 @@ class Table(abc.ABC):
 
     @abc.abstractmethod
     def filter(self, expr: Expr, header: RecordHeader,
-               parameters: Mapping[str, Any]) -> "Table":
-        """Keep rows where ``expr`` evaluates to exactly true (3VL)."""
+               parameters: Mapping[str, Any],
+               keep: Optional[Sequence[str]] = None) -> "Table":
+        """Keep rows where ``expr`` evaluates to exactly true (3VL).
+        The output has exactly the columns in ``keep`` (None: all):
+        ``expr``'s operands are read by the step and need not be
+        written by it."""
 
     @abc.abstractmethod
     def join(self, other: "Table", how: JoinType,
-             pairs: Sequence[Tuple[str, str]]) -> "Table":
+             pairs: Sequence[Tuple[str, str]],
+             keep: Optional[Sequence[str]] = None) -> "Table":
         """Join on equality of column pairs; null keys never match.
-        Column sets must be disjoint."""
+        Column sets must be disjoint.  The output has exactly the
+        columns in ``keep`` (None: all of both sides); the keys need not
+        be among them."""
 
     @abc.abstractmethod
     def union_all(self, other: "Table") -> "Table":

@@ -206,3 +206,117 @@ def test_foaf_oracles_honour_relationship_uniqueness():
         assert graph.cypher(foaf.AGE_SPLIT_QUERY, {"seed": seed}) \
             .records.to_maps() \
             == foaf.expected_age_split(src, dst, names, ages, seed)
+
+
+# -- keep= on the two gather sites of the Table SPI --------------------------
+
+def _keep_factories():
+    from caps_tpu.okapi.config import EngineConfig
+    return {
+        "local": lambda: LocalCypherSession().table_factory,
+        "tpu-expand-kernel": lambda: TPUCypherSession().table_factory,
+        "tpu-jnp": lambda: TPUCypherSession(
+            config=EngineConfig(use_pallas=False)).table_factory,
+    }
+
+
+def _keep_tables(factory):
+    from caps_tpu.okapi.types import CTInteger, CTString
+    left = factory.from_columns(
+        {"l_id": [1, 2, 3, 4, None], "l_k": [10, 20, 20, 40, 50],
+         "l_s": ["a", "b", None, "d", "e"]},
+        {"l_id": CTInteger, "l_k": CTInteger, "l_s": CTString})
+    right = factory.from_columns(
+        {"r_id": [1, 2, 2, 3, 7], "r_k": [10, 20, 21, 20, 70],
+         "r_s": ["u", "v", "w", None, "y"]},
+        {"r_id": CTInteger, "r_k": CTInteger, "r_s": CTString})
+    return left, right
+
+
+def _projected(table, cols):
+    return Bag([{c: r[c] for c in cols} for r in table.rows()])
+
+
+JOIN_KEEP_CASES = {
+    "inner-one-side-each": ("inner", [("l_id", "r_id")], ["l_s", "r_k"]),
+    "inner-omits-both-keys": ("inner", [("l_id", "r_id")], ["l_k", "r_s"]),
+    "inner-keys-only": ("inner", [("l_id", "r_id")], ["l_id", "r_id"]),
+    "inner-left-only": ("inner", [("l_id", "r_id")], ["l_s"]),
+    "inner-right-only": ("inner", [("l_id", "r_id")], ["r_s"]),
+    "left-omits-both-keys": ("left", [("l_id", "r_id")], ["l_s", "r_s"]),
+    "left-right-only": ("left", [("l_id", "r_id")], ["r_k"]),
+    "two-pairs-omits-all-keys": (
+        "inner", [("l_id", "r_id"), ("l_k", "r_k")], ["l_s", "r_s"]),
+    "two-pairs-keeps-second-key": (
+        "inner", [("l_id", "r_id"), ("l_k", "r_k")], ["r_k", "l_s"]),
+    "left-two-pairs": (
+        "left", [("l_id", "r_id"), ("l_k", "r_k")], ["l_s", "r_s"]),
+    "cross": ("cross", [], ["l_s", "r_k"]),
+}
+
+
+@pytest.mark.parametrize("case", list(JOIN_KEEP_CASES))
+@pytest.mark.parametrize("backend", list(_keep_factories()))
+def test_join_keep_returns_exactly_those_columns(backend, case):
+    how, pairs, keep = JOIN_KEEP_CASES[case]
+    left, right = _keep_tables(_keep_factories()[backend]())
+    full = left.join(right, how, pairs)
+    pruned = left.join(right, how, pairs, keep=keep)
+    assert set(pruned.columns) == set(keep)
+    assert len(pruned.columns) == len(keep)
+    assert pruned.size == full.size
+    assert _projected(pruned, keep) == _projected(full, keep)
+
+
+FILTER_KEEP_CASES = {
+    "omits-the-operand": ["l_s"],
+    "keeps-the-operand": ["l_k", "l_id"],
+    "one-column": ["l_id"],
+}
+
+
+@pytest.mark.parametrize("case", list(FILTER_KEEP_CASES))
+@pytest.mark.parametrize("backend", list(_keep_factories()))
+def test_filter_keep_returns_exactly_those_columns(backend, case):
+    from caps_tpu.ir import exprs as E
+    from caps_tpu.okapi.types import CTInteger, CTString
+    from caps_tpu.relational.header import RecordHeader
+    keep = FILTER_KEEP_CASES[case]
+    left, _ = _keep_tables(_keep_factories()[backend]())
+    header = RecordHeader([(E.Var("l_id"), "l_id", CTInteger),
+                           (E.Var("l_k"), "l_k", CTInteger),
+                           (E.Var("l_s"), "l_s", CTString)])
+    pred = E.GreaterThan(E.Var("l_k"), E.Lit(10))
+    full = left.filter(pred, header, {})
+    pruned = left.filter(pred, header, {}, keep=keep)
+    assert set(pruned.columns) == set(keep)
+    assert pruned.size == full.size == 4
+    assert _projected(pruned, keep) == _projected(full, keep)
+
+
+def test_keep_counts_gathered_and_pruned_columns():
+    """``backend.gathered_columns`` is every column handed to
+    ``_gather_cols``; ``backend.pruned_columns`` what ``keep`` left out."""
+    import caps_tpu.backends.tpu.table as T
+    session = TPUCypherSession()
+    left, right = _keep_tables(session.table_factory)
+    seen = []
+    orig = T._gather_cols
+
+    def spy(cols, idx):
+        seen.append(len(cols))
+        return orig(cols, idx)
+
+    T._gather_cols = spy
+    try:
+        left.join(right, "inner", [("l_id", "r_id")])
+        assert session.backend.pruned_columns == 0
+        assert session.backend.gathered_columns == sum(seen) == 6
+        left.join(right, "inner", [("l_id", "r_id")], keep=["l_s", "r_k"])
+        assert session.backend.gathered_columns == sum(seen) == 8
+        assert session.backend.pruned_columns == 4
+    finally:
+        T._gather_cols = orig
+    snap = session.metrics_snapshot()
+    assert snap["backend.gathered_columns"] == 8
+    assert snap["backend.pruned_columns"] == 4
