@@ -64,33 +64,5 @@ __all__ = [
     "AnalysisConfig", "Finding", "Project", "Source", "analysis_pass",
     "load_project", "pass_descriptions", "pass_names", "run_passes",
     "check_metrics_doc", "generate_metrics_doc", "write_metrics_doc",
-    "run_shim",
 ]
 
-
-def run_shim(pass_name: str, header: str, clean_message: str,
-             root: str = None) -> int:
-    """Back-compat entry for the legacy lint scripts
-    (scripts/check_serve_errors.py, scripts/check_no_naked_timers.py):
-    run ONE pass over the repo, print findings in the scripts' output
-    contract (header line + two-space-indented ``path:line: message``),
-    return their exit code (0 clean / 1 findings).  Files that fail to
-    parse are reported under their own header, not misattributed as
-    pass findings."""
-    project = load_project(root)
-    findings = run_passes(project, only=[pass_name])
-    parse = [f for f in findings if f.pass_name == "parse"]
-    rest = [f for f in findings if f.pass_name != "parse"]
-    if parse:
-        print("capslint: files failed to parse (nothing was checked "
-              "in them):")
-        for f in parse:
-            print(f"  {f.path}:{f.line}: {f.message}")
-    if rest:
-        print(header)
-        for f in rest:
-            print(f"  {f.path}:{f.line}: {f.message}")
-    if findings:
-        return 1
-    print(clean_message)
-    return 0

@@ -1,13 +1,13 @@
 """capslint ``clock-discipline``: one sanctioned time source.
 
-AST-based replacement for the ``scripts/check_no_naked_timers.py``
-regex.  Every timing read inside ``caps_tpu/`` must go through
+Every timing read inside ``caps_tpu/`` must go through
 ``caps_tpu.obs.clock`` (one monotonic base for spans, operator metrics,
-trace exports — and one seam for fake clocks in tests).  The regex
-matched ``time.perf_counter(`` textually, which caught aliased module
-imports (``import time as _t; _t.perf_counter()``) but NOT name
-imports: ``from time import perf_counter`` rebinds the function so no
-``time.`` attribute access ever appears.  This pass closes that hole by
+trace exports — and one seam for fake clocks in tests).  A textual
+match on ``time.perf_counter(`` catches aliased module imports
+(``import time as _t; _t.perf_counter()``) but NOT name imports:
+``from time import perf_counter`` rebinds the function so no ``time.``
+attribute access ever appears.  This pass (``python -m
+caps_tpu.analysis --only clock-discipline``) closes that hole by
 resolving imports:
 
 * ``from time import <timer> [as x]`` outside the clock module is a

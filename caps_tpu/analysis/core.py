@@ -1,9 +1,7 @@
 """capslint core: one shared AST parse of the package, a pass registry,
 findings, and inline suppressions.
 
-The framework industrializes the repo's one-off lint scripts
-(``scripts/check_serve_errors.py``, ``scripts/check_no_naked_timers.py``)
-into a single multi-pass analyzer:
+One multi-pass analyzer, run as ``python -m caps_tpu.analysis``:
 
 * :func:`load_project` walks ``caps_tpu/`` under a repo root and parses
   every ``.py`` file **once**; all passes share the resulting
@@ -36,8 +34,7 @@ BANNED_TIME_READS = frozenset({
 
 #: serve/ modules the error-taxonomy pass MUST see — a rename/move that
 #: silently drops a module from the walk would turn the check vacuous
-#: for it, so a missing expected file is a finding, not a skip (carried
-#: over from scripts/check_serve_errors.py).
+#: for it, so a missing expected file is a finding, not a skip.
 DEFAULT_SERVE_MODULES = frozenset({
     "__init__.py", "admission.py", "batcher.py", "breaker.py",
     "compaction.py", "deadline.py", "devices.py", "errors.py",

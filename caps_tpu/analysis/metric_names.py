@@ -3,7 +3,7 @@
 ``MetricsRegistry`` is get-or-create by string name, so nothing ever
 validated the names: a typo'd prefix silently forks a metric, and one
 name registered as two different instrument kinds splits its readings
-across instruments (``bench.py`` and ``stats()`` would each see half).
+across instruments (two readers would each see half).
 This pass collects every literal counter/gauge/histogram name in the
 package (f-strings become ``*`` wildcards; dynamic ``metric_prefix``
 f-strings are expanded against every constant prefix found in the

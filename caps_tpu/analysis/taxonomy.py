@@ -1,8 +1,8 @@
 """capslint ``error-taxonomy``: the serving tier's failure contract.
 
-Migrates ``scripts/check_serve_errors.py`` into the framework — pure
-AST now (no package import, so CI can lint before installing jax) — and
-extends it with the PR 4 invariants CHANGES.md only documented:
+Run as ``python -m caps_tpu.analysis --only error-taxonomy``: pure AST
+(no package import, so CI can lint before installing jax), checking the
+serving tier's invariants:
 
 * **E1 — one catchable base type**: every ``raise Name(...)`` inside
   ``caps_tpu/serve/`` resolves to a :class:`ServeError` subclass (class

@@ -80,8 +80,8 @@ def mask_count(mask: jnp.ndarray) -> jnp.ndarray:
 def sort_right(r_key, r_ok):
     """Reference build-side sort (lax.sort, un-gated).  The engine routes
     build-side sorts through DeviceTable._sort_perm so they can ride the
-    bitonic kernel under use_sort_kernel; this stays as the plain-XLA
-    reference the kernel differential tests probe against."""
+    bitonic kernel; this stays as the plain-XLA reference the kernel
+    differential tests probe against."""
     cap_r = r_key.shape[0]
     rk = jnp.where(r_ok, r_key.astype(jnp.int64), _R_NULL)
     rk_sorted, perm = jax.lax.sort((rk, jnp.arange(cap_r)), num_keys=1)

@@ -833,9 +833,8 @@ class DeviceTable(Table):
             return cached[1]
         r_ok = rcol.valid & other.row_ok
         rk = jnp.where(r_ok, self._join_key(rcol, side="r"), K._R_NULL)
-        # route through the shared sort gate so the build-side sort rides
-        # the bitonic kernel when use_sort_kernel is on (same fallback to
-        # lax.sort otherwise) — the last sort site outside _sort_perm
+        # through the shared sort gate, so the build-side sort rides the
+        # bitonic kernel where _sort_perm picks it (lax.sort otherwise)
         perm = other._sort_perm([rk])
         res = (rk[perm], perm)
         rcol._join_sort = (key, res)
@@ -996,8 +995,7 @@ class DeviceTable(Table):
         caller then stays on the single-program GSPMD path."""
         be = self.backend
         cfg = be.config
-        if (be.mesh is None or not cfg.use_dist_join
-                or how not in ("inner", "left")):
+        if be.mesh is None or how not in ("inner", "left"):
             return None
         n = be.n_shards
         if n <= 1:
@@ -1240,9 +1238,7 @@ class DeviceTable(Table):
         ops/kernel_table.py), the lax.sort twin otherwise."""
         cap = self.capacity
         from caps_tpu.ops import sort as S
-        if (self.backend.config.use_sort_kernel
-                and S.sort_cap_supported(cap)
-                and self.backend.use_kernel("sort")):
+        if S.sort_cap_supported(cap) and self.backend.use_kernel("sort"):
             self.backend.kernel_launches["sort"] += 1
             return S.sort_perm_pallas(keys, cap)
         return K.sort_perm(keys, cap)

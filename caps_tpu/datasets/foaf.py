@@ -3,8 +3,7 @@ their numpy oracles.
 
 One ``Person`` table (``name``, ``age``) and one ``KNOWS`` table with
 uniformly random endpoints; ``n_seeds`` people are named ``'Alice'``,
-everyone else ``p<i>``.  ``bench.py`` and ``chip_smoke.py`` build the
-same graph from the same seed, so a row from one describes the other.
+everyone else ``p<i>``.  ``chip_smoke.py`` builds it from ``--seed``.
 
 The oracles compute from the raw ``src``/``dst``/``names``/``ages``
 arrays, independent of the engine, and honour openCypher relationship

@@ -8,8 +8,8 @@ edges-joined/sec.
 The generator is the standard RMAT recursion with the Graph500 reference
 parameters (A, B, C, D) = (0.57, 0.19, 0.19, 0.05), vectorized over numpy
 so scale-20+ lists generate in seconds.  Scale s means 2**s vertices and
-``edgefactor * 2**s`` directed edges (Graph500 edgefactor is 16; tests and
-the in-repo bench use smaller factors to bound runtime).  Determinism: a
+``edgefactor * 2**s`` directed edges (Graph500 edgefactor is 16; the tests
+use smaller factors to bound runtime).  Determinism: a
 seeded ``RandomState`` — same (scale, edgefactor, seed) ⇒ same edge list.
 
 Reference analog: the reference ships no Graph500 module; the config comes

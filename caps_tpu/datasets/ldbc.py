@@ -28,7 +28,7 @@ from BASELINE.json (see BASELINE.md).  The bundled SocialNetworkExample
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
+from typing import Any, Callable, Dict, List, Mapping, Tuple
 
 import numpy as np
 
@@ -559,143 +559,3 @@ COMPLEX_READS: Dict[str, Tuple[str, Callable[[LdbcData, Any], Mapping[str, Any]]
                         "person2Id": _rand_person(d, rng)}),
 }
 
-
-# ---------------------------------------------------------------------------
-# Benchmark driver (bench.py ldbc mode): per-query p50/p95 with oracle
-# parity at a reduced scale, per BASELINE.md's protocol.
-# ---------------------------------------------------------------------------
-
-def _digest(rows) -> str:
-    import hashlib
-    row_digests = sorted(
-        hashlib.sha256(repr(sorted(r.items())).encode()).hexdigest()
-        for r in rows)
-    return hashlib.sha256("".join(row_digests).encode()).hexdigest()[:16]
-
-
-def run_ldbc_bench(scale: float = 11.0, on_tpu: bool = True,
-                   remaining_s: Callable[[], float] = lambda: 1e9,
-                   iters: int = 7, parity_scale: float = 0.1,
-                   seed: int = 7,
-                   result_sink: Optional[Dict[str, Any]] = None
-                   ) -> Dict[str, Any]:
-    """Configs 2–3: run IS1–IS7 + the IC subset with per-query p50/p95
-    over warm iterations (rotating parameters), after checking result
-    parity against the local oracle at ``parity_scale`` (the oracle is
-    pure Python — full-scale parity would dwarf the measurement budget;
-    digests at full scale are recorded for reproducibility instead).
-
-    ``result_sink`` (bench.py's best-so-far dict) is updated after every
-    completed query, so a deadline abort still emits everything measured
-    so far, honestly labelled partial.
-    """
-    import statistics
-    from caps_tpu.obs import clock as _clock
-
-    from caps_tpu.backends.local.session import LocalCypherSession
-    from caps_tpu.backends.tpu.session import TPUCypherSession
-
-    queries = {**SHORT_READS, **COMPLEX_READS}
-    per_query: Dict[str, Dict[str, Any]] = {}
-    all_p50: List[float] = []
-    backend = "tpu" if on_tpu else "cpu-fallback"
-
-    def publish(parity_done: int, parity_total: int, build_s: float,
-                partial: bool) -> Dict[str, Any]:
-        overall = statistics.median(all_p50) if all_p50 else 0.0
-        out = {
-            "metric": f"LDBC-like IS/IC p50 (scale={scale}, "
-                      f"{len(per_query)}/{len(queries)} queries, "
-                      f"parity {parity_done}/{parity_total} "
-                      f"at scale={parity_scale}, {backend}"
-                      f"{', partial' if partial else ''})",
-            "value": round(overall, 4),
-            "unit": "s p50",
-            "vs_baseline": 0.0,
-            "build_s": round(build_s, 1),
-            # suite-level audit rollups (per-query detail in "queries")
-            "fallbacks_total": sum(v.get("fallbacks", 0)
-                                   for v in per_query.values()),
-            "steady_syncs_max": max(
-                (v["steady_syncs"] for v in per_query.values()
-                 if v.get("steady_syncs") is not None), default=None),
-            "queries": dict(per_query),
-        }
-        if result_sink is not None:
-            result_sink.clear()
-            result_sink.update(out)
-        return out
-
-    # -- parity leg (small scale, oracle vs device backend) -------------
-    parity: Dict[str, bool] = {}
-    oracle_g, od = build_graph(LocalCypherSession(), scale=parity_scale,
-                               seed=seed)
-    dev_small = TPUCypherSession()
-    dev_g, _dd = build_graph(dev_small, scale=parity_scale, seed=seed)
-    rng = np.random.RandomState(99)
-    for name, (q, mk) in queries.items():
-        if remaining_s() < 20:
-            break
-        params = mk(od, rng)
-        want = oracle_g.cypher(q, params).records.to_maps()
-        got = dev_g.cypher(q, params).records.to_maps()
-        parity[name] = _digest(want) == _digest(got)
-
-    # -- timing leg (full scale, device backend) ------------------------
-    session = TPUCypherSession()
-    t0 = _clock.now()
-    g, d = build_graph(session, scale=scale, seed=seed)
-    build_s = _clock.now() - t0
-    publish(sum(parity.values()), len(parity), build_s, partial=True)
-
-    for name, (q, mk) in queries.items():
-        if per_query and remaining_s() < 25:
-            break
-        rng = np.random.RandomState(1234)
-        times: List[float] = []
-        syncs: List[int] = []
-        fallbacks = 0
-        # warm (compile) run
-        warm_params = mk(d, rng)
-        t0 = _clock.now()
-        res = g.cypher(q, warm_params)
-        rows = res.records.to_maps()
-        compile_s = _clock.now() - t0
-        fallbacks += (res.metrics or {}).get("device_fallbacks", 0)
-        digest = _digest(rows)
-        for _ in range(iters):
-            if times and remaining_s() < 25:
-                break
-            params = mk(d, rng)
-            # sync delta around execute AND materialization: under
-            # generic fused replay the exact-row-count sync is paid in
-            # to_maps, after the per-query metrics snapshot
-            syncs_before = session.backend.syncs
-            t0 = _clock.now()
-            res = g.cypher(q, params)
-            res.records.to_maps()
-            times.append(_clock.now() - t0)
-            syncs.append(session.backend.syncs - syncs_before)
-            fallbacks += (res.metrics or {}).get("device_fallbacks", 0)
-        if not times:
-            times = [compile_s]
-        times.sort()
-        p50 = statistics.median(times)
-        p95 = times[min(len(times) - 1, int(0.95 * len(times)))]
-        per_query[name] = {
-            "p50_s": round(p50, 4), "p95_s": round(p95, 4),
-            "compile_s": round(compile_s, 2), "iters": len(times),
-            "parity_ok": parity.get(name), "digest": digest,
-            # the round-5 audit columns: device fallbacks must stay 0
-            # (VERDICT r04 item 4) and steady-state syncs near 1 once
-            # generic fused replay engages.  Tail max, not min: the best
-            # single iteration would overstate convergence when
-            # re-records still alternate with replays.
-            "fallbacks": fallbacks,
-            "steady_syncs": (max(syncs[-3:]) if syncs else None),
-        }
-        all_p50.append(p50)
-        publish(sum(parity.values()), len(parity), build_s, partial=True)
-
-    return publish(sum(parity.values()), len(parity), build_s,
-                   partial=len(per_query) < len(queries))

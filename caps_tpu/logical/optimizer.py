@@ -229,8 +229,8 @@ class LogicalOptimizer:
         # and never descended into (and _push won't push predicates across
         # it), so the prefix stays structurally identical on both sides.
         self._barriers = {}
-        #: relational/cost.py CostModel (None = heuristic-only: the
-        #: pre-item-3 behavior, also the bench.py plan-mode baseline)
+        #: relational/cost.py CostModel (None = heuristic-only, the
+        #: reference side of tests/test_cost.py)
         self._model = cost_model
 
     def process(self, plan: L.LogicalPlan) -> L.LogicalPlan:

@@ -17,7 +17,7 @@ Design constraints:
   times as host dispatch and report device time as a per-replay
   aggregate span (docs/tpu.md);
 * one clock — all timestamps come from :mod:`caps_tpu.obs.clock`
-  (enforced by ``scripts/check_no_naked_timers.py``).
+  (enforced by ``python -m caps_tpu.analysis``, clock-discipline).
 """
 from caps_tpu.obs import clock, lockgraph
 from caps_tpu.obs.compile import (CompileLedger, attributed as

@@ -2,7 +2,7 @@
 
 Every timing read inside ``caps_tpu/`` goes through this module; naked
 ``time.perf_counter()`` / ``time.time()`` calls elsewhere are rejected by
-``scripts/check_no_naked_timers.py``.  Centralizing the clock keeps all
+``python -m caps_tpu.analysis`` (clock-discipline).  Centralizing the clock keeps all
 measurements on one monotonic base (spans, per-operator metrics, and the
 chrome-trace export timestamps all compare), and gives tests a single
 seam to stub.

@@ -3,8 +3,8 @@
 This absorbs the stats that used to live in ad hoc dicts and int fields
 scattered across the engine — ``plan_cache.stats()``, fused-replay
 round-trip counts, the device backend's ``ici_payload_bytes`` — behind
-one snapshot API (``session.metrics_snapshot()``, consumed by
-``bench.py``).
+one snapshot API (``session.metrics_snapshot()``, which
+``benchmarks/run.py`` reads its counters from).
 
 Two scopes:
 
@@ -16,7 +16,7 @@ Two scopes:
 
 Snapshots are flat ``{name: number}`` dicts; :func:`diff_snapshots`
 subtracts two of them so callers measure an interval without
-hand-rolling before/after counters (the bench's old pattern).
+hand-rolling before/after counters.
 
 All instruments are thread-safe (fine-grained per-instrument locks,
 plus a registry lock for get-or-create): the serving tier

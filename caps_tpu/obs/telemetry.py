@@ -491,8 +491,8 @@ class ServingTelemetry:
     per-plan-family latency — LRU-bounded), the SLO evaluation, and the
     flight recorder.  Registers live ``telemetry.*`` / ``slo.*`` gauges
     in ``registry`` so the windowed view rides ``metrics_snapshot()``
-    and ``registry.expose_text()``.  A session may run several servers
-    (bench.py's serve mode does): the gauges dispatch to the NEWEST
+    and ``registry.expose_text()``.  A session may run several
+    servers: the gauges dispatch to the NEWEST
     telemetry in the registry's live set, and :meth:`close` (called by
     ``QueryServer.shutdown``) leaves the set — a shut-down server
     neither reports stale windows nor stays pinned by the callbacks

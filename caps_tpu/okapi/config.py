@@ -52,13 +52,6 @@ class EngineConfig:
     # Kernel switches (pallas kernels fall back to jnp when off)
     use_pallas: bool = dataclasses.field(
         default_factory=lambda: _env_bool("CAPS_TPU_USE_PALLAS", True))
-    # Bitonic sort-permutation kernel (ops/sort.py) for order_by /
-    # distinct / group sorts on supported tile capacities (compiled TPU
-    # only; rides use_pallas + the "sort" family of
-    # ops/kernel_table.py).  CAPS_TPU_SORT_KERNEL=0 restores the
-    # lax.sort path.
-    use_sort_kernel: bool = dataclasses.field(
-        default_factory=lambda: _env_bool("CAPS_TPU_SORT_KERNEL", True))
     # HBM-resident CSR adjacency as the relationship scan's physical
     # layout (ops/expand.py DeviceCSR); joins against it probe indptr
     # instead of sorting + binary-searching the edge table.
@@ -68,19 +61,12 @@ class EngineConfig:
     # pattern chains to SpMV over the adjacency instead of join+count.
     use_count_pushdown: bool = dataclasses.field(
         default_factory=lambda: _env_bool("CAPS_TPU_COUNT_PUSHDOWN", True))
-    # Matrix/ring expansion strategies (parallel/ring.py): on a mesh,
-    # uniform pushdown chains and eligible var-expands ride the ppermute
-    # ring schedule instead of XLA-inserted all-reduces; single-chip,
-    # the same eligible var-expands run as one SpMV matrix program
-    # (VarExpandOp strategy "matrix") instead of the join cascade.
-    use_ring: bool = dataclasses.field(
-        default_factory=lambda: _env_bool("CAPS_TPU_USE_RING", True))
     # Worst-case-optimal multiway joins (relational/wcoj.py, ROADMAP
     # item 4): detected cyclic MATCH segments (chain + closing edges)
     # substitute a leapfrog-style multiway intersection over sorted
     # edge keys for the binary join cascade — enumeration AND counting.
     # Cost-selected when the model is on; off = the cascade everywhere
-    # (the bench.py cyclic-mode baseline).
+    # (the reference side of tests/test_wcoj.py's parity checks).
     use_wcoj: bool = dataclasses.field(
         default_factory=lambda: _env_bool("CAPS_TPU_WCOJ", True))
     # Cost-based planning (relational/cost.py + relational/stats.py,
@@ -90,7 +76,7 @@ class EngineConfig:
     # cascade and the sharded distribution strategy, and (c) stamps
     # per-operator row estimates so opstats.divergences measures MODEL
     # error and a diverging cached family re-plans itself.  Off = the
-    # pre-item-3 fixed heuristics (the bench.py plan-mode baseline).
+    # fixed heuristics (the reference side of tests/test_cost.py).
     use_cost_model: bool = dataclasses.field(
         default_factory=lambda: _env_bool("CAPS_TPU_COST_MODEL", True))
     # Divergence-triggered re-planning: model-divergent executions per
@@ -98,14 +84,12 @@ class EngineConfig:
     # path and re-plans with calibrated statistics.  0 disables.
     replan_threshold: int = dataclasses.field(
         default_factory=lambda: _env_int("CAPS_TPU_REPLAN_THRESHOLD", 2))
-    # Hand-scheduled distributed joins (parallel/dist_join.py, SURVEY.md
-    # §5.8): with a 1-D mesh, large-large joins ride an all_to_all radix
-    # exchange (each row crosses ICI once) instead of GSPMD's layout, and
-    # small build sides ride an explicit all_gather broadcast join.
-    use_dist_join: bool = dataclasses.field(
-        default_factory=lambda: _env_bool("CAPS_TPU_DIST_JOIN", True))
-    # Build sides at or under this many rows broadcast instead of
-    # exchanging (Spark's autoBroadcastJoinThreshold analog, in rows).
+    # Distributed joins (parallel/dist_join.py, SURVEY.md §5.8): on a 1-D
+    # mesh, large-large joins ride an all_to_all radix exchange (each row
+    # crosses ICI once) and small build sides an explicit all_gather
+    # broadcast join.  Build sides at or under this many rows broadcast
+    # instead of exchanging (Spark's autoBroadcastJoinThreshold analog,
+    # in rows).
     # With the cost model on this is a model INPUT — the broadcast
     # prior — not a hard cutover (relational/cost.py
     # choose_dist_strategy); <= 0 disables broadcasting either way.
@@ -128,11 +112,6 @@ class EngineConfig:
     # on a query's first run, replay them sync-free on repeats.
     use_fused: bool = dataclasses.field(
         default_factory=lambda: _env_bool("CAPS_TPU_USE_FUSED", True))
-    # Single-program count pushdown (relational/count_pattern.py): compile
-    # the whole seed→hops→masks→correction chain into ONE scatter-free
-    # jitted program, cached per (graph, plan shape, params).
-    use_fused_count: bool = dataclasses.field(
-        default_factory=lambda: _env_bool("CAPS_TPU_FUSED_COUNT", True))
     # Compile-cache capacity (query programs keyed by plan+bucket shapes)
     compile_cache_size: int = dataclasses.field(
         default_factory=lambda: _env_int("CAPS_TPU_COMPILE_CACHE", 512))

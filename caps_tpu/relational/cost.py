@@ -95,9 +95,8 @@ def choose_dist_strategy(probe_rows: int, build_rows: int, n_shards: int,
     ``config.join_hot_factor`` is the salting trigger: a sketch skew at
     or beyond it plans the salt instead of waiting for the runtime
     hot-key sample to react.  With ``config.use_cost_model`` off, only
-    the threshold prior applies — the pre-item-3 fixed heuristic, which
-    is also what the runtime dist-join call site must restore (the
-    ``bench.py plan`` baseline contract)."""
+    the threshold prior applies — the fixed heuristic, which is also
+    what the runtime dist-join call site must restore."""
     probe_rows = max(0, int(probe_rows))
     build_rows = max(0, int(build_rows))
     n = max(2, int(n_shards))
@@ -552,8 +551,7 @@ def annotate_plan(root, model: CostModel) -> Dict[str, Any]:
         elif isinstance(op, R.JoinOp):
             est = _join_est(model, op, l_est, kids[1] if len(kids) > 1
                             else 1.0)
-            if n_shards > 1 and config is not None \
-                    and getattr(config, "use_dist_join", False):
+            if n_shards > 1:
                 rhs = op.children[1]
                 rel_types: Tuple[str, ...] = ()
                 if isinstance(rhs, R.ScanOp):

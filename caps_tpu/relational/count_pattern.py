@@ -455,8 +455,6 @@ class CountPatternOp(RelationalOperator):
         backend = getattr(self.context.factory, "backend", None)
         if backend is None or backend.mesh is not None:
             return None
-        if not backend.config.use_fused_count:
-            return None
         from caps_tpu.backends.tpu.fused import _graph_key, _params_key
         gk = _graph_key(self.graph)
         params = self.context.parameters
@@ -1211,8 +1209,6 @@ class CountPatternOp(RelationalOperator):
         if mesh.devices.ndim != 1:
             # the hand-scheduled ring is a 1-D-mesh optimization; 2-D
             # (DCN x ICI) meshes take the GSPMD spmv-sharded path
-            return None
-        if not getattr(backend.config, "use_ring", True):
             return None
         if len(self.lengths) != 1 or self.lengths[0] < 1:
             return None

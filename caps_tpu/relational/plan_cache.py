@@ -317,8 +317,8 @@ class PlanCache:
     Counters live in a :class:`caps_tpu.obs.metrics.MetricsRegistry`
     (the session passes its own), so ``plan_cache.*`` shows up in
     ``session.metrics_snapshot()`` alongside every other stat and
-    consumers (bench.py) diff snapshots instead of hand-rolling
-    before/after counters.  ``stats()`` and the attribute accessors
+    consumers diff snapshots instead of hand-rolling before/after
+    counters.  ``stats()`` and the attribute accessors
     (``.hits`` etc.) read the same counters — one source of truth."""
 
     def __init__(self, max_size: int = 256, enabled: bool = True,

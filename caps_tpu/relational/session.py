@@ -552,8 +552,8 @@ class RelationalCypherSession(CypherSession):
         """One query's cost model (relational/cost.py): the graph's
         ingest-time statistics sketch + the session shape lattice +
         observed-actuals calibration for ``family``.  None with the
-        model disabled (EngineConfig.use_cost_model=False — the
-        heuristic-only baseline bench.py plan mode compares against)."""
+        model disabled (EngineConfig.use_cost_model=False: heuristic-
+        only planning, the reference side of tests/test_cost.py)."""
         if not self.config.use_cost_model:
             return None
         from caps_tpu.relational.cost import CostModel
@@ -902,8 +902,9 @@ class RelationalCypherSession(CypherSession):
             # upper bound until the result is materialized)
             "rows": records.table.size_hint() if records is not None else 0,
             "operators": context.op_metrics,
-            # roofline numerator: bytes the operators pulled through
-            # memory; achieved GB/s = bytes_touched / execute_s
+            # bytes the operators read, summed from their input
+            # shapes; execute_s is host time, so their quotient is no
+            # device bandwidth
             "bytes_touched": sum(m.get("bytes_in", 0)
                                  for m in context.op_metrics),
             "plan_cache": "miss" if cache_key is not None else "off",

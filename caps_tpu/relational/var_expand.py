@@ -143,7 +143,7 @@ class VarExpandOp(RelationalOperator):
         if self.rel_needed or self.into or self.upper > 3:
             return None
         backend = getattr(self.context.factory, "backend", None)
-        if backend is None or not backend.config.use_ring:
+        if backend is None:
             return None
         import jax.numpy as jnp
         from caps_tpu.backends.tpu import kernels as K

@@ -48,7 +48,7 @@ Established seams the operator rides:
   stamps the decision into EXPLAIN's cost section; the operator's
   ``est_rows`` feeds ``opstats.divergences`` and the existing re-plan
   loop.  ``EngineConfig.use_wcoj=False`` forces the cascade (the
-  ``bench.py cyclic`` baseline contract).
+  reference side of ``tests/test_wcoj.py``'s parity checks).
 """
 from __future__ import annotations
 

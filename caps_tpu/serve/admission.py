@@ -42,8 +42,8 @@ _gauge_guard = make_lock("admission._gauge_guard")
 def _register_depth_gauge(registry: MetricsRegistry,
                           controller: "AdmissionController") -> None:
     """``serve.queue_depth`` reports the TOTAL queued across every live
-    controller on this registry (a session may run several servers —
-    bench.py's serve mode does): controllers join the set here and
+    controller on this registry (a session may run several servers):
+    controllers join the set here and
     leave it in :meth:`AdmissionController.close`, so the gauge never
     gets hijacked by the newest server or pinned by a dead one."""
     with _gauge_guard:

@@ -14,7 +14,7 @@ Two deployment shapes share this class:
 * **in-process** (tests, docs): ``FleetBackend(spec)`` starts the
   server and listener on threads in the caller's process — real
   sockets, real wire frames, deterministic and fast;
-* **multi-process** (bench, production shape): ``spawn_backend(spec)``
+* **multi-process** (the production shape): ``spawn_backend(spec)``
   launches ``python -m caps_tpu.serve.fleet '<spec json>'`` — each
   child owns a full interpreter (its own GIL), prints
   ``CAPS_FLEET_PORT <port>`` on stdout, and serves until killed.
@@ -58,9 +58,7 @@ class BackendSpec:
     #: ring identity (stable across restarts — a rejoining process with
     #: the same name reclaims the same ring segment)
     name: str
-    #: session backend ("local" oracle or "tpu"); bench uses "local"
-    #: for child processes so scale-out is not dominated by per-process
-    #: jax compilation
+    #: session backend ("local" oracle or "tpu")
     backend: str = "local"
     #: graph spec: ``{"kind": "script", "create": "..."}`` (a CREATE
     #: statement through testing/factory), ``{"kind": "foaf",
@@ -80,14 +78,6 @@ class BackendSpec:
     workers: int = 2
     max_queue: int = 256
     default_deadline_s: Optional[float] = None
-    #: simulated per-query device dwell (seconds, via ``obs.clock``):
-    #: the CPU-smoke stand-in for a TPU-attached backend, where the
-    #: process WAITS on its device for most of a query's life.  Fleet
-    #: scale-out buys parallel devices, not parallel host CPUs — with a
-    #: dwell configured, QPS scaling across processes measures exactly
-    #: that serving-path parallelism, deterministically, even on a
-    #: single-core CI host.  0.0 (default) = serve at real speed.
-    service_dwell_s: float = 0.0
     #: snapshot-keyed result-cache byte budget (relational/
     #: result_cache.py); None = serve every read through the device.
     #: The hash-ring's (graph, plan-family) affinity already routes a
@@ -448,8 +438,6 @@ class FleetBackend:
         return rows, handle.info
 
     def _op_query(self, msg) -> Dict[str, Any]:
-        if self.spec.service_dwell_s > 0.0:
-            clock.sleep(self.spec.service_dwell_s)
         rows, info = self._submit(msg)
         out = {"rows": rows,
                "ledger": info.get("ledger"),

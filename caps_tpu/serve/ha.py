@@ -364,8 +364,8 @@ class HARouter:
         return self.router.metrics_text()
 
     def _op_step(self, msg) -> Dict[str, Any]:
-        """Drive one control iteration over the wire — the chaos bench
-        steers subprocess routers deterministically with this."""
+        """Drive one control iteration over the wire, so a caller can
+        steer subprocess routers deterministically."""
         return {"role": self.step(), "epoch": self.epoch}
 
     def _op_shutdown(self, msg) -> Dict[str, Any]:
@@ -514,9 +514,8 @@ def spawn_router(spec: RouterSpec,
                  env: Optional[Dict[str, str]] = None):
     """Launch ``python -m caps_tpu.serve.ha`` with ``spec`` and wait for
     its port line.  Returns ``(process, port)``; the caller owns the
-    process (terminate/kill/wait) — the chaos bench SIGKILLs the active
-    one mid-soak.  A router runs no device code and is pinned to the
-    CPU (serve/fleet.py :func:`spawn_process`)."""
+    process (terminate/kill/wait).  A router runs no device code and is
+    pinned to the CPU (serve/fleet.py :func:`spawn_process`)."""
     return spawn_process("caps_tpu.serve.ha", spec.to_json(),
                          "CAPS_ROUTER_PORT", f"router {spec.name!r}",
                          pin_cpu=True, env=env)

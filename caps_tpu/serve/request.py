@@ -5,8 +5,8 @@ worker pool completes it.  The handle is a minimal Future: ``result()``
 blocks (with an optional wait timeout), ``cancel()`` is cooperative
 (a queued request is dropped at dequeue, a running one stops at its next
 engine checkpoint), and ``info`` carries the per-request serving
-telemetry (queue wait, batch size, total latency) the bench and the
-stress tests assert on.
+telemetry (queue wait, batch size, total latency) the benchmark and
+the stress tests read.
 """
 from __future__ import annotations
 

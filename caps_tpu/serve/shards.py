@@ -115,7 +115,7 @@ _GROUP_KEY = ("group",)
 #: fault scoped to this member's operator stream fails the probe too
 _CANARY_QUERY = "MATCH (n) RETURN n LIMIT 1"
 
-#: bounded ring of group state transitions (bench reporting)
+#: bounded ring of group state transitions (``stats()["shards"]``)
 _MAX_TRANSITIONS = 64
 
 #: routing decisions cached per query text (parse once per text)

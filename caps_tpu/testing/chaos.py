@@ -9,7 +9,7 @@ which target, when — from a seeded PRNG, so a soak exercises fault
 
 * same seed ⇒ the identical event list, byte-for-byte, attested by
   :meth:`ChaosSchedule.digest` (a sha256 over the canonical JSON of the
-  schedule — the bench prints it, CI can diff it);
+  schedule, so two runs can be diffed);
 * every in-process event resolves to a ``testing/faults.py``-style
   injector over the SAME locked patch points (``OPERATOR_PATCH._lock``)
   with the same budget discipline, so chaos and hand-scripted faults
@@ -24,7 +24,7 @@ fire — no hidden thread, no wall-clock reads, so a fake-clock test
 drives an entire schedule in zero real time.
 
 :class:`ChaosInvariants` collects the soak's observations and renders
-the verdicts the chaos bench reports: zero acked-write loss (digest
+a soak's verdicts: zero acked-write loss (digest
 parity against a serial oracle), no stale reads (per-reader snapshot
 versions never regress), an availability floor, and no zombie
 application (every fence probe refused).
@@ -228,7 +228,7 @@ class ChaosSchedule:
         """Draw ``n_events`` fault events from ``random.Random(seed)``
         over ``menu`` — which injector, which target, when — plus the
         optional ``headline`` event pinned at ``headline_at_frac`` of
-        the soak (the chaos bench pins ``kill_router_active`` there).
+        the soak (``kill_router_active`` is the usual one).
         The draw order is fixed (time, injector, target per event, in
         sequence), so the same seed composes the identical schedule on
         any host."""

@@ -11,15 +11,13 @@ The contracts under test:
   matches the source (the CI drift check);
 * the runtime lock graph (caps_tpu/obs/lockgraph.py) records edges,
   raises on cycles in strict mode, ignores re-entrant re-acquisition,
-  and is a plain ``threading`` primitive when the env opt-in is off;
-* the legacy lint scripts still run with their old exit-code contract.
+  and is a plain ``threading`` primitive when the env opt-in is off.
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 import threading
 
@@ -626,10 +624,13 @@ def test_unknown_pass_rejected(tmp_path):
         run_passes(p, only=["no-such-pass"])
 
 
-def test_cli_json_and_exit_codes(tmp_path, capsys):
+@pytest.mark.parametrize("broken", [False, True])
+def test_cli_json_and_exit_codes(tmp_path, capsys, broken):
     (tmp_path / "caps_tpu").mkdir()
     (tmp_path / "caps_tpu" / "bad.py").write_text(
         "from time import perf_counter\n")
+    if broken:  # a file that does not parse is its own finding
+        (tmp_path / "caps_tpu" / "broken.py").write_text("def oops(:\n")
     # satisfy the default config's pinned-module vacuity guard so the
     # single finding below is exactly the naked import
     (tmp_path / "caps_tpu" / "relational").mkdir()
@@ -638,9 +639,11 @@ def test_cli_json_and_exit_codes(tmp_path, capsys):
     rc = capslint_main(["--root", str(tmp_path), "--json",
                         "--only", "clock-discipline"])
     out = json.loads(capsys.readouterr().out)
-    assert rc == 1 and len(out) == 1
-    assert out[0]["pass"] == "clock-discipline"
-    assert out[0]["path"] == "caps_tpu/bad.py"
+    by_pass = {f["pass"]: f["path"] for f in out}
+    assert rc == 1 and len(out) == len(by_pass) == 1 + broken
+    assert by_pass["clock-discipline"] == "caps_tpu/bad.py"
+    if broken:  # reported under "parse", not as a naked timer
+        assert by_pass["parse"] == "caps_tpu/broken.py"
     rc = capslint_main(["--list"])
     assert rc == 0
     listed = capsys.readouterr().out
@@ -751,30 +754,6 @@ def test_metrics_doc_has_no_drift():
     assert check_metrics_doc(project) is None
     doc = generate_metrics_doc(project)
     assert "| `serve.completed` | counter |" in doc
-
-
-def test_run_shim_separates_parse_failures(tmp_path, capsys):
-    from caps_tpu.analysis import run_shim
-    (tmp_path / "caps_tpu").mkdir()
-    (tmp_path / "caps_tpu" / "broken.py").write_text("def oops(:\n")
-    (tmp_path / "caps_tpu" / "relational").mkdir()
-    (tmp_path / "caps_tpu" / "relational" / "result_cache.py").write_text(
-        "from caps_tpu.obs import clock\n")
-    rc = run_shim("clock-discipline", header="naked timers found:",
-                  clean_message="clean", root=str(tmp_path))
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "failed to parse" in out
-    assert "naked timers found:" not in out  # not misattributed
-
-
-def test_legacy_shims_keep_contract():
-    for script in ("check_serve_errors.py", "check_no_naked_timers.py"):
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "scripts", script)],
-            capture_output=True, text=True, timeout=120)
-        assert proc.returncode == 0, proc.stdout + proc.stderr
-        assert "clean" in proc.stdout
 
 
 # -- runtime lock graph ------------------------------------------------------

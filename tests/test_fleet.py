@@ -5,8 +5,7 @@ and rejoin-warms-from-store.
 The in-process fleet fixture runs real sockets and real wire frames —
 each backend is a full QueryServer on its own session behind a
 listener thread — so every cross-process contract except the GIL is
-exercised deterministically (bench.py fleet spawns real interpreters
-for the QPS-scaling acceptance)."""
+exercised deterministically."""
 from __future__ import annotations
 
 import json

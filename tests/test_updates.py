@@ -430,13 +430,16 @@ def test_iu_insert_subset_through_server(make_session):
 # -- the acceptance soak -----------------------------------------------------
 
 def _mixed_soak(make_session, *, writers, readers, writes_each,
-                reads_each, compaction_threshold):
+                reads_each, compaction_threshold, result_cache=None):
     """8-client mixed read/write soak under ~20%+ write aborts.
 
     Asserts the ISSUE acceptance: availability 1.0 (every request
     resolves), ZERO torn reads (every reader's rows equal the serial
     state at its admission-time snapshot version), and at least one
-    background compaction completing under load."""
+    background compaction completing under load.  With ``result_cache``
+    the same reads go through the snapshot-keyed result cache: a hit
+    must never serve a superseded version, and the cache stays inside
+    its budget at every read."""
     from caps_tpu.serve import QueryServer, RetryPolicy, ServeError, \
         ServerConfig
     from caps_tpu.testing.faults import abort_write
@@ -451,7 +454,7 @@ def _mixed_soak(make_session, *, writers, readers, writes_each,
         retry=RetryPolicy(max_attempts=8, backoff_base_s=0.002,
                           backoff_max_s=0.05),
         compaction_threshold_rows=compaction_threshold,
-        compaction_interval_s=0.005))
+        compaction_interval_s=0.005, result_cache=result_cache))
     write_log = {}       # version -> (k, v)
     write_log_lock = threading.Lock()
     observations = []    # (snapshot_version, frozenset of (k, v))
@@ -479,6 +482,9 @@ def _mixed_soak(make_session, *, writers, readers, writes_each,
                     observations.append(
                         (h.info["snapshot_version"],
                          frozenset((r["k"], r["v"]) for r in rows)))
+                if result_cache is not None:
+                    assert server.result_cache.bytes \
+                        <= result_cache.budget_bytes
             except ServeError as ex:  # pragma: no cover — availability
                 failures.append(("read-shed", i, ex))
             except Exception as ex:  # pragma: no cover
@@ -523,14 +529,20 @@ def _mixed_soak(make_session, *, writers, readers, writes_each,
     assert result_digest(vg.cypher(q)) == result_digest(vg2.cypher(q))
     # at least one compaction completed UNDER LOAD
     assert s.metrics_snapshot()["compaction.runs"] >= 1
+    if result_cache is not None:  # the reads really went through it
+        assert s.metrics_snapshot()["rescache.insertions"] >= 1
     assert s.metrics_snapshot()["updates.rolled_back"] >= 1
 
 
-def test_soak_mixed_read_write_with_aborts(make_session):
+@pytest.mark.parametrize("cached", [False, True])
+def test_soak_mixed_read_write_with_aborts(make_session, cached):
     """Tier-1 soak: 8 clients, 3 writers (~27% writes) under injected
-    write aborts."""
+    write aborts; once more with the result cache on."""
+    from caps_tpu.relational.result_cache import ResultCacheConfig
     _mixed_soak(make_session, writers=3, readers=5, writes_each=6,
-                reads_each=8, compaction_threshold=6)
+                reads_each=8, compaction_threshold=6,
+                result_cache=ResultCacheConfig(budget_bytes=4 << 20)
+                if cached else None)
 
 
 @pytest.mark.slow

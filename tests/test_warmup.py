@@ -134,6 +134,7 @@ def test_ragged_batch_coalesces_distinct_texts_exactly():
              "MATCH (p:Person) WHERE p.age > $min RETURN count(*) AS c"]
     for t in texts:
         g.cypher(t, {"min": 20})  # warm each family's plan
+    recompiles = s.metrics_snapshot().get("compile.recompiles", 0)
     server = QueryServer(s, graph=g, start=False, config=ServerConfig(
         workers=1, max_batch=16, ragged_batching=True))
     hs = [server.submit(texts[i % 3], {"min": 20 + 10 * (i % 2)})
@@ -142,6 +143,8 @@ def test_ragged_batch_coalesces_distinct_texts_exactly():
     server.shutdown()
     sizes = [h.info["batch_size"] for h in hs]
     assert max(sizes) > 1, sizes  # distinct texts coalesced
+    # bindings that churn inside a warmed shape bucket recompile nothing
+    assert s.metrics_snapshot().get("compile.recompiles", 0) == recompiles
     for i, h in enumerate(hs):    # every member's result stays exact
         want = g.cypher(texts[i % 3],
                         {"min": 20 + 10 * (i % 2)}).records.to_maps()

@@ -76,6 +76,9 @@ CYCLIC_QUERIES = [
     # the aggregate rides the MultiwayJoin output
     "MATCH (a:P)-[r1:K]->(b)-[r2:K]->(d), (a)-[r3:K]->(c)-[r4:K]->(d) "
     "RETURN count(*) AS c",
+    # the same over the 4-cycle
+    "MATCH (a:P)-[r1:K]->(b)-[r2:K]->(c)-[r3:K]->(d), (d)-[r4:K]->(a) "
+    "RETURN count(*) AS c",
 ]
 
 
