@@ -90,7 +90,11 @@ def sort_right(r_key, r_ok):
 
 @jax.jit
 def probe_count(l_key, l_ok, rk_sorted):
-    """Phase 1: per-left-row match counts against the sorted right keys."""
+    """Phase 1: per-left-row match counts against the sorted right keys.
+    The path of a join whose build side has no resident index: an
+    ingested table's id columns carry one (ops/expand.py ``csr_probe``,
+    two gathers a row); a derived table, or ids ``build_csr`` refuses,
+    are sorted once and searched here."""
     lk = jnp.where(l_ok, l_key.astype(jnp.int64), _L_NULL)
     lo = jnp.searchsorted(rk_sorted, lk, side="left")
     hi = jnp.searchsorted(rk_sorted, lk, side="right")

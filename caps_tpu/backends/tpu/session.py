@@ -192,6 +192,8 @@ class TPUCypherSession(RelationalCypherSession):
             "backend.d2h_bytes": be.d2h_bytes,
             "backend.gathered_columns": be.gathered_columns,
             "backend.pruned_columns": be.pruned_columns,
+            "backend.index_probes": be.index_probes,
+            "backend.search_probes": be.search_probes,
             "backend.kernel.expand": be.kernel_launches["expand"],
             "backend.kernel.segment": be.kernel_launches["segment"],
             "backend.kernel.sort": be.kernel_launches["sort"],
@@ -339,8 +341,10 @@ class TPUCypherSession(RelationalCypherSession):
                     continue
                 seen.add(id(t))
                 t._cols = {c: replace(col) for c, col in t._cols.items()}
+            # rebuild the CSR physical layout on the new placement
+            for nt in getattr(g, "node_tables", ()):
+                self._factory.prepare_node_table(nt)
             for rt in getattr(g, "rel_tables", ()):
-                # rebuild the CSR physical layout on the new placement
                 self._factory.prepare_rel_table(rt)
         return int(backend.mesh.devices.size) if backend.mesh is not None \
             else 1

@@ -149,6 +149,11 @@ class DeviceBackend:
         # did not gather because its ``keep`` left them out
         self.gathered_columns = 0
         self.pruned_columns = 0
+        # equi-joins (_sort_merge_join) that probed the build side's
+        # resident index (ops/expand.py csr_probe), and those that found
+        # none and searched its sorted keys (kernels.probe_count)
+        self.index_probes = 0
+        self.search_probes = 0
         self._wait_lock = make_lock("table.DeviceBackend._wait_lock")
         # Pallas kernel launches per family (ops/kernel_table.py FAMILIES):
         # counted where the engine dispatches the kernel itself, not a
@@ -841,9 +846,13 @@ class DeviceTable(Table):
         return res
 
     def _csr_for(self, other: "DeviceTable", rcol: Column):
-        """The HBM-resident CSR for a build-side column, if the ingest
-        hook (DeviceTableFactory.prepare_rel_table) attached one and the
-        table still has the shape it was built for."""
+        """The HBM-resident CSR for a build-side column, if an ingest
+        hook (DeviceTableFactory.prepare_rel_table / prepare_node_table)
+        attached one to this very ``Column`` and the table still has the
+        shape it was built for.  ``select`` and ``rename`` keep the
+        ``Column``, so a scan of an ingested table finds it; whatever
+        writes new columns (a filter, a union, the snapshot overlay)
+        has none, and the join searches."""
         if not self.backend.config.use_csr:
             return None
         cached = getattr(rcol, "_csr", None)
@@ -881,8 +890,10 @@ class DeviceTable(Table):
         with profiler_span("caps_tpu.table.join.probe"):
             if csr is not None:
                 # CSR probe: two indptr gathers per row, no sort, no search
+                self.backend.index_probes += 1
                 counts, lo = csr.probe(self._masked_left_key(lcol), l_ok)
             else:
+                self.backend.search_probes += 1
                 counts, lo = K.probe_count(self._masked_left_key(lcol), l_ok,
                                            rk_sorted)
             total_dev = K.join_total(counts, l_ok, left_join)
@@ -1833,13 +1844,29 @@ class DeviceTableFactory(TableFactory):
         csr_build on the host when available, one numpy sort otherwise).
         Every later Expand hop against this table probes ``indptr``
         instead of sorting + binary-searching the edge list."""
+        m = rel_table.mapping
+        self._attach_csr(rel_table.table, (m.source_col, m.target_col))
+
+    def prepare_node_table(self, node_table) -> None:
+        """The same index over a node table's id column: the join that
+        closes a hop on ``(b)`` probes it instead of binary-searching the
+        sorted ids.  Not on a mesh, where that join is the one
+        ``DeviceTable._dist_join`` schedules by hand (it is skipped when
+        an index is found)."""
+        if self.backend.mesh is None:
+            self._attach_csr(node_table.table, (node_table.mapping.id_col,))
+
+    def _attach_csr(self, t, names) -> None:
+        """Hang a ``DeviceCSR`` on each named id/int ``Column`` of a
+        resident device table, once, as ``col._csr = ((n,), csr)``: the
+        form ``DeviceTable._csr_for`` reads.  ``csr`` is None where
+        ``build_csr`` refuses the key domain (negative or too sparse):
+        joins against that column keep the search."""
         if not self.backend.config.use_csr:
             return
-        t = rel_table.table
         if not isinstance(t, DeviceTable) or t.is_local:
             return
-        m = rel_table.mapping
-        for name in (m.source_col, m.target_col):
+        for name in names:
             col = t._cols.get(name)
             if col is None or col.kind not in ("id", "int"):
                 continue

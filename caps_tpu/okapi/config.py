@@ -52,9 +52,10 @@ class EngineConfig:
     # Kernel switches (pallas kernels fall back to jnp when off)
     use_pallas: bool = dataclasses.field(
         default_factory=lambda: _env_bool("CAPS_TPU_USE_PALLAS", True))
-    # HBM-resident CSR adjacency as the relationship scan's physical
-    # layout (ops/expand.py DeviceCSR); joins against it probe indptr
-    # instead of sorting + binary-searching the edge table.
+    # HBM-resident CSR index over an ingested table's id columns (a
+    # relationship table's source and target, a node table's id) as the
+    # scan's physical layout (ops/expand.py DeviceCSR); joins against it
+    # probe indptr instead of sorting + binary-searching the table.
     use_csr: bool = dataclasses.field(
         default_factory=lambda: _env_bool("CAPS_TPU_USE_CSR", True))
     # Aggregate pushdown (relational/count_pattern.py): lower count-only

@@ -25,12 +25,14 @@ This kernel restructures the inversion to be VPU-shaped:
   — three dense (2T × T) VPU passes, no gather, no scatter, streaming
   through VMEM.  The window always covers the tile (proof in comments).
 
-``DeviceCSR`` makes the *probe* side of Expand O(1) per row as well: the
-relationship table's physical layout on HBM is a CSR over the source (and
-target) node-id column — built once per graph by the C++ host runtime
-(native/csrc/host_runtime.cpp csr_build) at ingest, or on-device via one cached
-sort — so a hop is two ``indptr`` gathers (lo/hi) instead of a per-hop
-sort + per-row binary search of the edge table.
+``DeviceCSR`` makes the *probe* side of a join O(1) per row as well: an
+ingested table's physical layout on HBM includes a CSR over each id column
+a join probes — a relationship table's source and target, a node table's
+id — built once per graph at ingest by the C++ host runtime
+(native/csrc/host_runtime.cpp csr_build), or by one numpy sort without
+it.  A probe is then two ``indptr`` gathers (lo/hi) per row, in one
+program (``csr_probe``), instead of a sort + per-row binary search of
+the build side.
 """
 from __future__ import annotations
 
@@ -221,10 +223,28 @@ def join_expand_via_positions(counts, lo, perm, l_ok, out_cap: int,
 # ---------------------------------------------------------------------------
 
 
+@jax.jit
+def csr_probe(indptr: jnp.ndarray, keys: jnp.ndarray, ok: jnp.ndarray
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-probe-row (counts, lo) out of a CSR's ``indptr``: two gathers,
+    no search, one program.  The domain is ``indptr.shape[0] - 1``; the
+    comparison happens in the key's own dtype (int64 keys must not be
+    truncated before the range check), so masked, negative and
+    out-of-domain keys count 0."""
+    n_keys = indptr.shape[0] - 1
+    in_domain = ok & (keys >= 0) & (keys < n_keys)
+    safe = jnp.where(in_domain, keys, 0).astype(jnp.int32)
+    lo = indptr[safe]
+    hi = indptr[safe + 1]
+    counts = jnp.where(in_domain, hi - lo, 0)
+    return counts, lo
+
+
 @dataclasses.dataclass
 class DeviceCSR:
-    """HBM-resident CSR index over one int-key column: ``perm`` lists row
-    indices grouped by key; rows for key k live at
+    """HBM-resident CSR index over one int-key column of an ingested
+    table (a relationship table's source or target, a node table's id):
+    ``perm`` lists row indices grouped by key; rows for key k live at
     ``perm[indptr[k] : indptr[k+1]]``.  Domain is [0, n_keys)."""
     indptr: jnp.ndarray   # (n_keys + 1,) int32
     perm: jnp.ndarray     # (capacity,) int32
@@ -232,15 +252,8 @@ class DeviceCSR:
 
     def probe(self, keys: jnp.ndarray, ok: jnp.ndarray
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """Per-probe-row (counts, lo): two indptr gathers, no search.
-        Domain comparison happens in the key's own dtype (int64 keys must
-        not be truncated before the range check)."""
-        in_domain = ok & (keys >= 0) & (keys < self.n_keys)
-        safe = jnp.where(in_domain, keys, 0).astype(jnp.int32)
-        lo = self.indptr[safe]
-        hi = self.indptr[safe + 1]
-        counts = jnp.where(in_domain, hi - lo, 0)
-        return counts, lo
+        """Per-probe-row (counts, lo), see :func:`csr_probe`."""
+        return csr_probe(self.indptr, keys, ok)
 
 
 # CSR domains above this multiple of the column capacity fall back to the

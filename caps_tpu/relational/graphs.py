@@ -151,8 +151,11 @@ class ScanGraph(RelationalCypherGraph):
         self.version = next(ScanGraph._version_counter)
         self.node_tables: Tuple[NodeTable, ...] = tuple(node_tables)
         self.rel_tables: Tuple[RelationshipTable, ...] = tuple(rel_tables)
+        # ingest-time physical layout (on device backends: a CSR index
+        # over every id column a join probes)
+        for nt in self.node_tables:
+            self.factory.prepare_node_table(nt)
         for rt in self.rel_tables:
-            # ingest-time physical layout (CSR adjacency on device backends)
             self.factory.prepare_rel_table(rt)
         schema = Schema.empty()
         for nt in self.node_tables:

@@ -230,4 +230,11 @@ class TableFactory(abc.ABC):
         """Backend hook called once per relationship table at graph
         creation: device backends build their physical adjacency layout
         (HBM-resident CSR over the source/target columns) here so every
-        later Expand hop probes it.  Default: no-op."""
+        later Expand hop probes it; node tables get theirs through
+        :meth:`prepare_node_table`.  Default: no-op."""
+
+    def prepare_node_table(self, node_table) -> None:
+        """Backend hook called once per node table at graph creation,
+        beside :meth:`prepare_rel_table`: device backends index the id
+        column here, so a join against the node scan probes the index.
+        Default: no-op."""

@@ -30,6 +30,22 @@ def make_session():
     return make_backend_session
 
 
+@pytest.fixture(scope="module")
+def fof():
+    """The benchmark's friends-of-friends generator (data from a seed,
+    the served query texts, their numpy reference), loaded by path:
+    ``benchmarks/`` is no package."""
+    import importlib.util
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "bench_generators_fof",
+        os.path.join(root, "benchmarks", "generators", "fof.py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # its dataclass looks its module up
+    spec.loader.exec_module(mod)
+    return mod
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _clear_jax_caches_between_modules():
     """Drop jit/executable caches at every module boundary.

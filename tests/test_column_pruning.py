@@ -8,10 +8,6 @@ break a careless pass, with a check on the sets beside the check on the
 rows; (c) a plan served from the plan cache keeps its sets; (d) an
 operator unknown to the pass keeps everything below it.
 """
-import importlib.util
-import os
-import sys
-
 import pytest
 
 import caps_tpu.backends.tpu.table as T
@@ -27,8 +23,6 @@ from caps_tpu.relational.plan_cache import PlanParams
 from caps_tpu.relational.result_cache import ResultCache, ResultCacheConfig
 from caps_tpu.testing.bag import Bag
 from caps_tpu.testing.factory import create_graph
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _unpruned(monkeypatch):
@@ -58,17 +52,6 @@ def _plan(session, graph, query, params=None):
 
 
 # -- (a) fof3: the sets at the widest bucket, and the counters ---------------
-
-@pytest.fixture(scope="module")
-def fof():
-    spec = importlib.util.spec_from_file_location(
-        "bench_generators_fof",
-        os.path.join(ROOT, "benchmarks", "generators", "fof.py"))
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = mod  # its dataclass looks its module up
-    spec.loader.exec_module(mod)
-    return mod
-
 
 @pytest.fixture(scope="module")
 def fof_graph(fof):

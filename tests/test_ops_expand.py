@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import pytest
 
 from caps_tpu.ops.expand import (
-    DeviceCSR, build_csr, expand_positions, expand_positions_ref,
+    DeviceCSR, build_csr, csr_probe, expand_positions, expand_positions_ref,
     join_expand_via_positions,
 )
 from caps_tpu.backends.tpu import kernels as K
@@ -118,6 +118,77 @@ def test_csr_probe_int64_keys_out_of_range():
     assert list(np.asarray(counts)) == [1, 1, 0, 0, 0]
 
 
+# right keys (the build side, all inside build_csr's domain rule), and
+# left keys that hit, miss, repeat, and leave the domain on either end
+_RIGHT_KEYS = {
+    "unique": lambda rng: rng.permutation(700),
+    "repeated": lambda rng: rng.randint(0, 50, 700),
+    "unique-with-gaps": lambda rng: rng.permutation(2000)[:700],
+}
+def _half(rng, cap, other):
+    """Half the keys among the first 50 ids (every right side has
+    them), half from ``other``."""
+    return np.where(rng.rand(cap) < 0.5, rng.randint(0, 50, cap),
+                    np.asarray(other, np.int64))
+
+
+_LEFT_KEYS = {
+    "in-domain": lambda rng, cap: _half(rng, cap, rng.randint(0, 700, cap)),
+    "below-zero": lambda rng, cap: _half(rng, cap, rng.randint(-40, 0, cap)),
+    "beyond-domain": lambda rng, cap: _half(
+        rng, cap, rng.randint(600, 5000, cap)),
+    "beyond-2^31": lambda rng, cap: _half(
+        rng, cap, 2 ** 31 + rng.randint(0, 700, cap).astype(np.int64)),
+    # wraps to an in-domain key if the range check truncated to int32
+    "2^32-aliases": lambda rng, cap: _half(
+        rng, cap, 2 ** 32 + rng.randint(0, 700, cap).astype(np.int64)),
+}
+
+
+@pytest.mark.parametrize("right", sorted(_RIGHT_KEYS))
+@pytest.mark.parametrize("left", sorted(_LEFT_KEYS))
+@pytest.mark.parametrize("masked_rows,null_keys", [
+    (False, False), (True, False), (False, True), (True, True)])
+def test_csr_probe_matches_probe_count(right, left, masked_rows, null_keys):
+    """The one-program index probe against the binary search it stands
+    in for: same counts a left row, and ``perm[lo + j]`` names the same
+    right rows."""
+    rng = np.random.RandomState(len(right) * 31 + len(left))
+    cap_l, cap_r, n_r = 512, 1024, 700
+    r_key = np.zeros(cap_r, np.int64)
+    r_key[:n_r] = _RIGHT_KEYS[right](rng)
+    r_ok = np.zeros(cap_r, bool)
+    r_ok[:n_r] = rng.rand(n_r) < 0.9 if masked_rows else True
+    l_key = _LEFT_KEYS[left](rng, cap_l).astype(np.int64)
+    l_ok = jnp.asarray(rng.rand(cap_l) < 0.8 if masked_rows
+                       else np.ones(cap_l, bool))
+    if null_keys:
+        # what DeviceTable._masked_left_key hands both probes for a
+        # null key of a live row
+        l_key = np.where(rng.rand(cap_l) < 0.2, int(K._L_NULL), l_key)
+    l_key = jnp.asarray(l_key)
+
+    rk_sorted, perm_s = K.sort_right(jnp.asarray(r_key), jnp.asarray(r_ok))
+    want_counts, want_lo = K.probe_count(l_key, l_ok, rk_sorted)
+    csr = build_csr(jnp.asarray(r_key), jnp.asarray(r_ok), n_r)
+    assert csr is not None and csr.indptr.shape[0] == csr.n_keys + 1
+    got_counts, got_lo = csr_probe(csr.indptr, l_key, l_ok)
+    assert got_counts.dtype == jnp.int32 and got_lo.dtype == jnp.int32
+    for a, b in zip(csr.probe(l_key, l_ok), (got_counts, got_lo)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+    got_counts, got_lo = np.asarray(got_counts), np.asarray(got_lo)
+    want_counts, want_lo = np.asarray(want_counts), np.asarray(want_lo)
+    assert np.array_equal(got_counts, want_counts)
+    assert got_counts.sum() > 0
+    perm_c, perm_s = np.asarray(csr.perm), np.asarray(perm_s)
+    for i in np.nonzero(got_counts)[0]:
+        got = perm_c[got_lo[i]:got_lo[i] + got_counts[i]]
+        want = perm_s[want_lo[i]:want_lo[i] + want_counts[i]]
+        assert sorted(got) == sorted(want), i
+        assert r_ok[got].all() and (r_key[got] == int(l_key[i])).all()
+
+
 def _social(session):
     return make_graph(
         session,
@@ -161,6 +232,56 @@ def test_csr_attached_at_ingest():
     assert getattr(src_col, "_csr", None) is not None
     assert getattr(tgt_col, "_csr", None) is not None
     assert src_col._csr[1] is not None  # suitable dense domain -> built
+
+
+def _node_id_col(graph):
+    (nt,) = graph.node_tables
+    return nt.table, nt.table._cols[nt.mapping.id_col]
+
+
+def test_node_index_attached_at_ingest():
+    session = TPUCypherSession()
+    t, id_col = _node_id_col(_social(session))
+    shape, csr = id_col._csr
+    assert shape == (t._n,) == (30,)
+    assert csr is not None and csr.n_keys == 30
+    # a node id names one row: the index is a permutation of the rows
+    assert list(np.asarray(csr.indptr)) == list(range(31))
+    assert sorted(np.asarray(csr.perm)[:30]) == list(range(30))
+    # the scan keeps the Column, so the join finds the index on it
+    scan = t.select(["_id"]).rename({"_id": "c__id"})
+    assert scan._cols["c__id"] is id_col
+    assert scan._csr_for(scan, id_col) is csr
+
+
+def test_node_index_not_built_when_csr_is_off():
+    session = TPUCypherSession(config=EngineConfig(use_csr=False))
+    t, id_col = _node_id_col(_social(session))
+    assert getattr(id_col, "_csr", None) is None
+    assert t._csr_for(t, id_col) is None
+
+
+@pytest.mark.parametrize("ids", [
+    [i * 1000 for i in range(100)],           # sparse: 99,000 for 100 rows
+    [-1] + list(range(1, 100)),               # one negative id
+], ids=["spaced-1000-apart", "negative"])
+def test_node_index_refused_for_unsuitable_ids(ids):
+    session = TPUCypherSession()
+    g = make_graph(
+        session,
+        {("Person",): [{"_id": i, "name": f"p{i}"} for i in ids]},
+        {"KNOWS": [(ids[i], ids[(i * 7 + 3) % 100], {})
+                   for i in range(100)]})
+    t, id_col = _node_id_col(g)
+    # looked at once, refused by build_csr's own rule: the join searches
+    assert id_col._csr == ((100,), None)
+    before = session.metrics_snapshot()
+    rows = g.cypher("MATCH (a:Person)-[:KNOWS]->(b) WHERE a.name = $n "
+                    "RETURN b.name AS b", {"n": f"p{ids[5]}"}
+                    ).records.to_maps()
+    assert rows == [{"b": f"p{ids[38]}"}]
+    after = session.metrics_snapshot()
+    assert after["backend.search_probes"] > before["backend.search_probes"]
 
 
 def test_distinct_and_group_do_not_collide_large_int64():

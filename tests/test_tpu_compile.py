@@ -120,6 +120,25 @@ def test_compact_indices_compiles(one_chip, n, out_cap, scatter_free):
     assert ("scatter" not in compiled.as_text()) == scatter_free
 
 
+@pytest.mark.parametrize("n_keys,cap_l", [
+    (1 << 20, 262144), ((1 << 26) - 1, 4096)])
+def test_csr_probe_compiles_without_a_loop(one_chip, n_keys, cap_l):
+    """A join against an indexed id column is two gathers a row in one
+    program; the search it stands in for (``probe_count``) is two
+    ``while`` loops of ~21 rounds (140 ms each at 262,144 keys into 2^20
+    ids on the v5e, PR 33).  At the sizes of ``fof-depth3-c4``'s node
+    probe and of a ``KNOWS`` probe."""
+    from caps_tpu.backends.tpu.kernels import probe_count
+    from caps_tpu.ops.expand import csr_probe
+    keys, ok = one_chip((cap_l,), jnp.int64), one_chip((cap_l,), jnp.bool_)
+    indexed = csr_probe.lower(
+        one_chip((n_keys + 1,), jnp.int32), keys, ok).compile()
+    assert "while" not in indexed.as_text()
+    searched = probe_count.lower(
+        keys, ok, one_chip((n_keys,), jnp.int64)).compile()
+    assert "while" in searched.as_text()
+
+
 def test_fused_count_program_compiles(one_chip):
     """Config 1 through the count push-down, from a small graph."""
     from caps_tpu.backends.tpu.session import TPUCypherSession
@@ -180,6 +199,19 @@ def test_ring_two_hop_compiles_at_smoke_size(four_chips):
         rows((e,), jnp.bool_), rows((n,), jnp.int64)).compile()
     text = compiled.as_text()
     assert "collective-permute" in text and "all-to-all" in text
+
+
+def test_csr_probe_compiles_on_a_mesh(four_chips):
+    """On a mesh only relationship tables carry the index; their probe
+    is the same one program, under GSPMD: keys row-sharded, ``indptr``
+    replicated (at ``chip_smoke.py --chips 4``'s sizes)."""
+    from caps_tpu.ops.expand import csr_probe
+    mesh, rows = four_chips
+    indptr = jax.ShapeDtypeStruct(((1 << 20) + 1,), jnp.int32,
+                                  sharding=NamedSharding(mesh, P()))
+    compiled = csr_probe.lower(indptr, rows((65536,), jnp.int64),
+                               rows((65536,), jnp.bool_)).compile()
+    assert "while" not in compiled.as_text()
 
 
 def test_int64_collectives_compile(four_chips):
