@@ -11,8 +11,9 @@ Two-phase pattern: operators whose output size is data-dependent (filter,
 join, explode, group) first run a jitted *count* kernel, sync one scalar to
 the host to pick the output bucket, then run a jitted *materialize* kernel
 with static output shape — the eager-mode analog of bucketed compilation.
-The filter's materialize step (compact_indices) is scatter-free when the
-output bucket is small against the input; its two static shapes decide.
+The filter's materialize step (compact_indices) is scatter-free: a rank
+search where the output bucket is small against the input, one int32
+key sort otherwise; its two static shapes decide.
 """
 from __future__ import annotations
 
@@ -21,6 +22,8 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+
+from caps_tpu.ops.compact import compact_form, compact_indices  # noqa: F401
 
 jax.config.update("jax_enable_x64", True)
 
@@ -41,32 +44,8 @@ def row_mask(capacity: int, n) -> jnp.ndarray:
 
 # -- compaction (filter) ----------------------------------------------------
 
-#: How many elements compact_indices' rank search may gather for each
-#: update the scatter would make.  On the v5e a scattered update costs
-#: 61-70 ns and a gathered element of the search 7.5 ns (PERF.md §6, PR
-#: 28: the shape table), a ratio of 8-9; 8 keeps the scatter wherever
-#: out_cap == n at the engine's buckets.
-_GATHERS_PER_SCATTER = 8
-
-
-@functools.partial(jax.jit, static_argnames=("out_cap",))
-def compact_indices(mask: jnp.ndarray, out_cap: int) -> jnp.ndarray:
-    """Indices of the first ``out_cap`` kept rows, in order, padded with
-    0: what ``jnp.nonzero(mask, size=out_cap, fill_value=0)`` returns.
-
-    jnp.nonzero scatter-adds one update per *input* row, and XLA
-    serializes a scatter on TPU.  Where the output is narrow against the
-    input, the k-th kept row is searched for instead: the first position
-    whose prefix count reaches k — one int32 scan and ``out_cap``
-    binary searches of ``n.bit_length()`` gathers each, no scatter."""
-    n = mask.shape[0]
-    if out_cap * n.bit_length() < _GATHERS_PER_SCATTER * n:
-        kept = jnp.cumsum(mask, dtype=jnp.int32)
-        ranks = jnp.arange(1, out_cap + 1, dtype=jnp.int32)
-        pos = jnp.searchsorted(kept, ranks, side="left")
-        return jnp.where(pos < n, pos, 0).astype(jnp.int64)
-    (idx,) = jnp.nonzero(mask, size=out_cap, fill_value=0)
-    return idx
+# compact_indices (rank search or one key sort, by shape) lives in
+# ops/compact.py, beside the sort the expand kernel's prelude shares
 
 
 @jax.jit

@@ -194,6 +194,8 @@ class TPUCypherSession(RelationalCypherSession):
             "backend.pruned_columns": be.pruned_columns,
             "backend.index_probes": be.index_probes,
             "backend.search_probes": be.search_probes,
+            "backend.sort_compactions": be.sort_compactions,
+            "backend.search_compactions": be.search_compactions,
             "backend.kernel.expand": be.kernel_launches["expand"],
             "backend.kernel.segment": be.kernel_launches["segment"],
             "backend.kernel.sort": be.kernel_launches["sort"],

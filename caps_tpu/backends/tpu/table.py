@@ -154,6 +154,10 @@ class DeviceBackend:
         # none and searched its sorted keys (kernels.probe_count)
         self.index_probes = 0
         self.search_probes = 0
+        # row compactions a table method dispatched (compact_indices), by
+        # the form their two shapes select: one key sort or a rank search
+        self.sort_compactions = 0
+        self.search_compactions = 0
         self._wait_lock = make_lock("table.DeviceBackend._wait_lock")
         # Pallas kernel launches per family (ops/kernel_table.py FAMILIES):
         # counted where the engine dispatches the kernel itself, not a
@@ -264,6 +268,14 @@ class DeviceBackend:
         if on and out_cap % OPS.EXPAND_TILE == 0:
             self.kernel_launches["expand"] += 1
         return on
+
+    def compact_indices(self, mask: jnp.ndarray, out_cap: int) -> jnp.ndarray:
+        """``K.compact_indices``, counted by the form its shapes select."""
+        if K.compact_form(mask.shape[0], out_cap) == "sort":
+            self.sort_compactions += 1
+        else:
+            self.search_compactions += 1
+        return K.compact_indices(mask, out_cap)
 
     def account_wait(self, seconds: float, nbytes: int = 0,
                      syncs: int = 0) -> None:
@@ -785,7 +797,7 @@ class DeviceTable(Table):
         count = K.mask_count(mask)
         new_n, live = self.backend.consume_rows(count)
         out_cap = self.backend.bucket(new_n)
-        idx = K.compact_indices(mask, out_cap)
+        idx = self.backend.compact_indices(mask, out_cap)
         idx = self.backend.place_rows(idx)
         return DeviceTable(self.backend,
                            self._gather(self._kept(keep), idx),
@@ -1239,7 +1251,7 @@ class DeviceTable(Table):
                   else jnp.int32(other._n))
         t = jnp.arange(out_cap)
         mask = (t < live_a) | ((t >= self._n) & (t < self._n + live_b))
-        idx = K.compact_indices(mask, out_cap)
+        idx = self.backend.compact_indices(mask, out_cap)
         return DeviceTable(self.backend, self._gather(out, idx), total,
                            live=(live_a + live_b).astype(jnp.int32))
 
@@ -1355,7 +1367,7 @@ class DeviceTable(Table):
             row_ok_sorted = self.row_ok
         out_cap = self.backend.bucket(n_groups)
         if by:
-            start_idx = K.compact_indices(change, out_cap)
+            start_idx = self.backend.compact_indices(change, out_cap)
         else:
             start_idx = jnp.zeros(out_cap, jnp.int32)
 

@@ -11,8 +11,10 @@ HBM gathers per slot, the worst access pattern a TPU can run.
 
 This kernel restructures the inversion to be VPU-shaped:
 
-* left rows with ``count == 0`` are compacted away (XLA prelude), so a
-  tile of T outputs can touch at most T+1 consecutive live rows;
+* left rows with ``count == 0`` are compacted away (XLA prelude: one
+  key sort that carries each row's count and match start along,
+  ops/compact.py), so a tile of T outputs can touch at most T+1
+  consecutive live rows;
 * per tile, the prelude computes which row *block* the tile starts in
   (one tiny searchsorted over tile starts, n_tiles elements);
 * the kernel holds a 2T-row window of (offsets, lo, row-id) in VMEM and
@@ -45,6 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from caps_tpu.ops.compact import kept_first
 
 
 # ---------------------------------------------------------------------------
@@ -111,15 +115,16 @@ def expand_positions(counts: jnp.ndarray, lo: jnp.ndarray, out_cap: int,
     n_tiles = out_cap // tile
 
     counts32 = counts.astype(jnp.int32)
-    # -- prelude (XLA): compact away zero-count rows ----------------------
-    (nz_idx,) = jnp.nonzero(counts32 > 0, size=cap_l, fill_value=cap_l)
-    slot_live = nz_idx < cap_l
-    safe_idx = jnp.where(slot_live, nz_idx, 0)
-    nz_counts = jnp.where(slot_live, counts32[safe_idx], 0)
+    # -- prelude (XLA): compact away zero-count rows, by one key sort that
+    # carries each row's count and match start along (no gather) --------
+    key, nz_counts, lo_nz = kept_first(counts32 > 0, counts32,
+                                       lo.astype(jnp.int32))
+    slot_live = key < cap_l
+    nz_counts = jnp.where(slot_live, nz_counts, 0)
     offsets = jnp.cumsum(nz_counts, dtype=jnp.int32)        # (cap_l,)
     total = offsets[-1] if cap_l else jnp.int32(0)
-    lo_nz = jnp.where(slot_live, lo.astype(jnp.int32)[safe_idx], 0)
-    orig_nz = jnp.where(slot_live, nz_idx.astype(jnp.int32), 0)
+    lo_nz = jnp.where(slot_live, lo_nz, 0)
+    orig_nz = jnp.where(slot_live, key, 0).astype(jnp.int32)
 
     # pad to a tile multiple so any window [blk*T, blk*T + 2T) is in
     # range; padded offsets repeat `total`, which only ever counts for

@@ -403,7 +403,7 @@ class MultiwayJoinOp(RelationalOperator):
             out_cap = backend.bucket(n_rows)
             idx = charged_shape(
                 f"compact:b{cap}x{out_cap}",
-                lambda: K.compact_indices(mask, out_cap))
+                lambda: backend.compact_indices(mask, out_cap))
             state = {k: v[idx] for k, v in state.items()}
             cap = out_cap
 

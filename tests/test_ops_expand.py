@@ -60,6 +60,46 @@ def test_expand_positions_heavy_skew():
         assert np.array_equal(np.asarray(g), np.asarray(w))
 
 
+def test_expand_positions_sparse_rows_with_zero_ends():
+    """A tileable ``cap_l`` whose rows are mostly empty, the first and
+    last among them: the prelude compacts the live rows by one key sort
+    (ops/compact.py), and neither it nor the program scatters."""
+    cap_l = 4096
+    rng = np.random.RandomState(38)
+    counts = np.where(rng.rand(cap_l) < 0.05, rng.randint(1, 9, cap_l), 0)
+    counts[:3] = 0
+    counts[-5:] = 0
+    lo = rng.randint(0, 1 << 20, cap_l)
+    total = int(counts.sum())
+    out_cap = 1 << (total - 1).bit_length()
+    args = (jnp.asarray(counts), jnp.asarray(lo))
+    got = expand_positions(*args, out_cap, interpret=True)
+    want = expand_positions_ref(*args, out_cap)
+    for g, w, name in zip(got, want, ("l_idx", "r_pos", "valid")):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), name
+    lowered = expand_positions.lower(*args, out_cap=out_cap,
+                                     interpret=True).as_text()
+    assert "scatter" not in lowered
+
+
+@pytest.mark.parametrize("kept", [[], [0], [4095], [0, 5, 4095],
+                                  list(range(4096))])
+def test_kept_first_carries_payload(kept):
+    """The prelude's one sort: kept rows' indices first and in order,
+    every dropped row's key at or past n, each payload beside its key."""
+    from caps_tpu.ops.compact import kept_first
+    n = 4096
+    rows = np.zeros(n, bool)
+    rows[kept] = True
+    vals = np.arange(n, dtype=np.int32) * 7 + 3
+    key, payload = kept_first(jnp.asarray(rows), jnp.asarray(vals))
+    key, payload = np.asarray(key), np.asarray(payload)
+    k = len(kept)
+    assert np.array_equal(key[:k], np.flatnonzero(rows))
+    assert (key[k:] >= n).all()
+    assert np.array_equal(payload, vals[np.where(key < n, key, key - n)])
+
+
 def test_join_expand_via_positions_matches_join_expand():
     rng = np.random.RandomState(3)
     cap_l, cap_r = 512, 1024

@@ -56,33 +56,47 @@ def test_differential_parity(graphs, query):
     assert Bag(actual) == Bag(expected), Bag(expected).diff(Bag(actual))
 
 
-# (n, out_cap, kept rows, whether the shapes select rank search): both
-# sides of compact_indices' shape rule, far from its crossover
+# (n, out_cap, kept rows, the form the shapes select): both sides of
+# compact_indices' shape rule, far from its crossover and one bucket
+# either side of it
 COMPACT_CASES = {
-    "narrow-empty": (4096, 64, [], True),
-    "narrow-first-row": (4096, 64, [0], True),
-    "narrow-last-row": (4096, 64, [4095], True),
-    "narrow-count-is-out_cap": (8192, 64, range(5, 8192, 128), True),
-    "narrow-count-over-out_cap": (8192, 64, range(0, 8192, 3), True),
-    "narrow-dense": (4096, 64, range(4000, 4050), True),
-    "n-far-over-out_cap": (1 << 18, 16, [7, 1 << 17, (1 << 18) - 1], True),
-    "narrow-odd-n": (5000, 32, [0, 1, 2499, 4999], True),
-    "wide-empty": (256, 256, [], False),
-    "wide-first-row": (256, 256, [0], False),
-    "wide-last-row": (256, 256, [255], False),
-    "wide-full": (256, 256, range(256), False),
-    "wide-count-over-out_cap": (1024, 800, range(1024), False),
-    "wide-odd-n": (100, 128, range(1, 100, 2), False),
-    "wide-million": (1 << 20, 1 << 20, range(0, 1 << 20, 1 << 10), False),
+    "narrow-empty": (1 << 16, 64, [], "search"),
+    "narrow-first-row": (1 << 16, 64, [0], "search"),
+    "narrow-last-row": (1 << 16, 64, [(1 << 16) - 1], "search"),
+    "narrow-count-is-out_cap": (1 << 16, 64, range(5, 1 << 16, 1024),
+                                "search"),
+    "narrow-count-over-out_cap": (1 << 16, 64, range(0, 1 << 16, 3), "search"),
+    "narrow-dense": (1 << 16, 64, range(40000, 40050), "search"),
+    "n-far-over-out_cap": (1 << 18, 16, [7, 1 << 17, (1 << 18) - 1], "search"),
+    "narrow-odd-n": (70001, 32, [0, 1, 35000, 70000], "search"),
+    "wide-empty": (256, 256, [], "sort"),
+    "wide-first-row": (256, 256, [0], "sort"),
+    "wide-last-row": (256, 256, [255], "sort"),
+    "wide-full": (256, 256, range(256), "sort"),
+    "wide-count-over-out_cap": (1024, 800, range(1024), "sort"),
+    "wide-odd-n": (100, 128, range(1, 100, 2), "sort"),
+    "wide-million": (1 << 20, 1 << 20, range(0, 1 << 20, 1 << 10), "sort"),
+    "wide-one-row-dropped": (1 << 18, 1 << 18,
+                             [i for i in range(1 << 18) if i != 77_777],
+                             "sort"),
+    "wide-none-kept": (1 << 18, 1 << 18, [], "sort"),
+    "wide-all-kept": (1 << 18, 1 << 18, range(1 << 18), "sort"),
+    "out_cap-over-n-all-kept": (100, 128, range(100), "sort"),
+    "wide-sparse-ends": (4096, 4096, [0, 1, 2047, 4095], "sort"),
+    "narrow-two-rows": (1 << 16, 64, [3, 60000], "search"),
+    "small-narrow-sorts": (4096, 256, [0, 17, 4095], "sort"),
+    "crossover-search-side": (1 << 20, 1024, range(0, 1 << 20, 1031),
+                              "search"),
+    "crossover-sort-side": (1 << 20, 2048, range(0, 1 << 20, 1031), "sort"),
 }
 
 
 @pytest.mark.parametrize("case", list(COMPACT_CASES))
 def test_compact_indices_matches_flatnonzero(case):
     """Values, order, fill, dtype and shape are jnp.nonzero's whichever
-    program the two static shapes select."""
+    form the two static shapes select, and neither form scatters."""
     from caps_tpu.backends.tpu import kernels as K
-    n, out_cap, kept, rank_search = COMPACT_CASES[case]
+    n, out_cap, kept, form = COMPACT_CASES[case]
     rows = np.zeros(n, bool)
     rows[list(kept)] = True
     expected = np.zeros(out_cap, np.int64)
@@ -92,9 +106,49 @@ def test_compact_indices_matches_flatnonzero(case):
     got = K.compact_indices(mask, out_cap)
     assert got.dtype == jnp.int64 and got.shape == (out_cap,)
     np.testing.assert_array_equal(np.asarray(got), expected)
+    assert K.compact_form(n, out_cap) == form
     program = str(jax.make_jaxpr(
         lambda m: K.compact_indices(m, out_cap))(mask))
-    assert ("scatter" not in program) == rank_search
+    assert "scatter" not in program
+    assert ("sort[" in program) == (form == "sort")
+
+
+# (rows in the node table, the filter, rows it keeps, the form its two
+# shapes select): a look-up among 2^18 rows into the smallest bucket
+# searches; a filter that keeps most rows, or any over a small table, sorts
+FILTER_FORMS = {
+    "one-of-262144": (1 << 18, "a.v = 5", 1, "search"),
+    "most-of-65536": (1 << 16, "a.v >= 5", (1 << 16) - 5, "sort"),
+    "one-of-100": (100, "a.v = 5", 1, "sort"),
+}
+
+
+@pytest.mark.parametrize("case", list(FILTER_FORMS))
+def test_filter_counts_its_compaction_by_form(case):
+    """``backend.sort_compactions`` / ``backend.search_compactions`` move
+    by the form ``compact_indices`` takes for the filter's shapes."""
+    from caps_tpu.backends.tpu import kernels as K
+    from caps_tpu.okapi.types import CTInteger
+    from caps_tpu.relational.entity_tables import NodeMapping, NodeTable
+    n, where, kept, form = FILTER_FORMS[case]
+    session = TPUCypherSession()
+    be = session.backend
+    assert K.compact_form(be.bucket(n), be.bucket(kept)) == form
+    people = NodeTable(
+        NodeMapping.on("_id").with_implied_labels("Person").with_property("v"),
+        session.table_factory.from_columns(
+            {"_id": list(range(n)), "v": list(range(n))},
+            {"_id": CTInteger, "v": CTInteger}))
+    g = session.create_graph([people], [])
+    keys = ("backend.sort_compactions", "backend.search_compactions")
+    before = session.metrics_snapshot()
+    rows = g.cypher(f"MATCH (a:Person) WHERE {where} RETURN a.v AS v"
+                    ).records.to_maps()
+    after = session.metrics_snapshot()
+    assert len(rows) == kept
+    moved = tuple(after[k] - before[k] for k in keys)
+    assert moved == ((1, 0) if form == "sort" else (0, 1))
+    assert session.fallback_count == 0
 
 
 def test_hot_path_has_no_fallbacks():

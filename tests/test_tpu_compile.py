@@ -108,16 +108,18 @@ def test_expand_kernel_compiles(one_chip, cap_l, out_cap):
     assert _has_kernel(compiled)
 
 
-@pytest.mark.parametrize("n,out_cap,scatter_free", [
-    (1 << 20, 256, True), (4096, 4096, False)])
-def test_compact_indices_compiles(one_chip, n, out_cap, scatter_free):
-    """The filter's compaction of a million-row mask into a small bucket
-    must not scatter one update per input row (64 ms a read on the v5e,
-    PR 28); the same-capacity compaction keeps jnp.nonzero's program."""
+@pytest.mark.parametrize("n,out_cap,searches", [
+    (1 << 20, 256, True), (4096, 4096, False), (262144, 262144, False)])
+def test_compact_indices_compiles(one_chip, n, out_cap, searches):
+    """No compaction scatters one update per input row (64 ms for the
+    filter's million-row mask on the v5e; 17.8 ms at 262,144 ->
+    262,144): a narrow one searches, a wide one sorts one int32 key."""
     from caps_tpu.backends.tpu.kernels import compact_indices
     compiled = compact_indices.lower(one_chip((n,), jnp.bool_),
                                      out_cap=out_cap).compile()
-    assert ("scatter" not in compiled.as_text()) == scatter_free
+    text = compiled.as_text()
+    assert "scatter" not in text
+    assert ("sort(" in text) != searches
 
 
 @pytest.mark.parametrize("n_keys,cap_l", [
