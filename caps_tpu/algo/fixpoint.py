@@ -1,6 +1,7 @@
 """The shared iterative-fixpoint executor: fixed-shape device programs.
 
-Every procedure's device path is ONE jitted program built per
+Every procedure's device path but ``algo.bfs``'s (a frontier search
+over CSRs, ``ops/bfs.py``) is ONE jitted program built per
 ``(procedure, node capacity, edge capacity)``: the node and edge arrays
 are padded to shape-lattice buckets (``relational/shapes.py``) and the
 iteration runs as a ``lax.while_loop`` whose carried state has a fixed
@@ -32,8 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 
 jax.config.update("jax_enable_x64", True)
-
-from caps_tpu.algo.kernels import UNREACHED  # noqa: E402
 
 
 def _loop(cond_extra, body, state0, cap):
@@ -121,30 +120,6 @@ def _wcc(node_mask, src, tgt, edge_mask, weights, scalars):
     return label, it, done
 
 
-def _bfs(node_mask, src, tgt, edge_mask, weights, scalars):
-    n_pad = node_mask.shape[0]
-    unreached = jnp.asarray(UNREACHED, jnp.int64)
-    source = scalars["source_index"]
-    max_depth = scalars["max_depth"]
-    in_range = (source >= 0) & (source < scalars["n_live"])
-    dist0 = jnp.full(n_pad, unreached, jnp.int64)
-    dist0 = jnp.where((jnp.arange(n_pad) == source) & in_range,
-                      0, dist0)
-    cap = jnp.where(max_depth >= 0, max_depth,
-                    jnp.asarray(n_pad, jnp.int64))
-
-    def body(state):
-        dist = state
-        reach = (dist[src] != unreached) & edge_mask
-        cand = jnp.where(reach, jnp.where(reach, dist[src], 0) + 1,
-                         unreached)
-        nxt = dist.at[tgt].min(cand)
-        return nxt, jnp.all(nxt == dist)
-
-    dist, it, done = _loop(lambda s: jnp.asarray(True), body, dist0, cap)
-    return dist, it, done
-
-
 def _sssp(node_mask, src, tgt, edge_mask, weights, scalars):
     n_pad = node_mask.shape[0]
     source = scalars["source_index"]
@@ -170,7 +145,6 @@ _DEVICE_KERNELS = {
     "algo.degree": _degree,
     "algo.pagerank": _pagerank,
     "algo.wcc": _wcc,
-    "algo.bfs": _bfs,
     "algo.sssp": _sssp,
 }
 
@@ -180,7 +154,6 @@ SCALAR_OPERANDS: Dict[str, Tuple[str, ...]] = {
     "algo.degree": ("direction_code",),
     "algo.pagerank": ("n_live", "damping", "max_iterations", "tolerance"),
     "algo.wcc": ("max_iterations",),
-    "algo.bfs": ("n_live", "source_index", "max_depth"),
     "algo.sssp": ("n_live", "source_index", "max_iterations"),
 }
 
@@ -293,28 +266,6 @@ def _wcc_dense(node_mask, A, W, scalars):
     return label, it, done
 
 
-def _bfs_dense(node_mask, A, W, scalars):
-    n_pad = node_mask.shape[0]
-    D = A > 0
-    source = scalars["source_index"]
-    max_depth = scalars["max_depth"]
-    in_range = (source >= 0) & (source < scalars["n_live"])
-    dist0 = jnp.full(n_pad, _BIG, jnp.int64)
-    dist0 = jnp.where((jnp.arange(n_pad) == source) & in_range,
-                      0, dist0)
-    cap = jnp.where(max_depth >= 0, max_depth,
-                    jnp.asarray(n_pad, jnp.int64))
-
-    def body(state):
-        dist = state
-        cand = jnp.where(D, dist[:, None], _BIG).min(axis=0)
-        nxt = jnp.minimum(dist, jnp.where(cand != _BIG, cand + 1, _BIG))
-        return nxt, jnp.all(nxt == dist)
-
-    dist, it, done = _loop(lambda s: jnp.asarray(True), body, dist0, cap)
-    return dist, it, done
-
-
 def _sssp_dense(node_mask, A, W, scalars):
     n_pad = node_mask.shape[0]
     source = scalars["source_index"]
@@ -340,7 +291,6 @@ _DENSE_KERNELS = {
     "algo.degree": _degree_dense,
     "algo.pagerank": _pagerank_dense,
     "algo.wcc": _wcc_dense,
-    "algo.bfs": _bfs_dense,
     "algo.sssp": _sssp_dense,
 }
 

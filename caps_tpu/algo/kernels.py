@@ -88,26 +88,47 @@ def wcc(n: int, src: np.ndarray, tgt: np.ndarray,
 
 
 def bfs(n: int, src: np.ndarray, tgt: np.ndarray, source: int,
-        max_depth: int) -> Tuple[np.ndarray, int, bool]:
-    """Hop distance from ``source`` along OUTGOING edges; unreached
-    nodes hold :data:`UNREACHED`."""
+        max_depth: int, direction: str = "out"
+        ) -> Tuple[np.ndarray, int, bool]:
+    """Hop distance from ``source`` along ``direction`` (``"out"``,
+    ``"in"`` or ``"both"``) by a frontier search over a CSR built here:
+    each level reads the frontier's rows once.  Unreached nodes hold
+    :data:`UNREACHED`; the iterations are the levels expanded, the last
+    one that finds nothing included."""
     dist = np.full(n, UNREACHED, dtype=np.int64)
     if not 0 <= source < n:
         return dist, 0, True
+    a, b = oriented(src, tgt, direction)
+    order = np.argsort(a, kind="stable")
+    nbr = b[order]
+    indptr = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(np.bincount(a, minlength=n), out=indptr[1:])
     dist[source] = 0
-    depth, frontier = 0, True
-    while frontier and (max_depth < 0 or depth < max_depth):
-        reach = dist[src] != UNREACHED
-        # the sentinel is int64 max: select BEFORE the +1 so the dead
-        # lanes never compute an overflowing candidate
-        cand = np.where(reach, np.where(reach, dist[src], 0) + 1,
-                        UNREACHED)
-        nxt = dist.copy()
-        np.minimum.at(nxt, tgt, cand)
-        frontier = bool((nxt != dist).any())
-        dist = nxt
+    frontier = np.array([source], dtype=np.int64)
+    depth = 0
+    while frontier.size and (max_depth < 0 or depth < max_depth):
+        starts = indptr[frontier]
+        counts = indptr[frontier + 1] - starts
+        ends = np.cumsum(counts)
+        at = np.repeat(starts - (ends - counts), counts) \
+            + np.arange(int(ends[-1]))
+        found = np.unique(nbr[at])
+        found = found[dist[found] == UNREACHED]
         depth += 1
-    return dist, depth, not frontier
+        dist[found] = depth
+        frontier = found
+    return dist, depth, frontier.size == 0
+
+
+def oriented(src: np.ndarray, tgt: np.ndarray, direction: str
+             ) -> Tuple[np.ndarray, np.ndarray]:
+    """The edge list a walk along ``direction`` follows from ``a`` to
+    ``b``: as stored, reversed, or both."""
+    if direction == "in":
+        return tgt, src
+    if direction == "both":
+        return np.concatenate([src, tgt]), np.concatenate([tgt, src])
+    return src, tgt
 
 
 def sssp(n: int, src: np.ndarray, tgt: np.ndarray, weights: np.ndarray,
@@ -144,7 +165,8 @@ def run_host(name: str, n: int, src: np.ndarray, tgt: np.ndarray,
     if name == "algo.wcc":
         return wcc(n, src, tgt, bound["max_iterations"])
     if name == "algo.bfs":
-        return bfs(n, src, tgt, bound["source_index"], bound["max_depth"])
+        return bfs(n, src, tgt, bound["source_index"], bound["max_depth"],
+                   bound["direction"])
     if name == "algo.sssp":
         return sssp(n, src, tgt, weights, bound["source_index"],
                     bound["max_iterations"])

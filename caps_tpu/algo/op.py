@@ -19,6 +19,14 @@ sees them), compacts ids to index space, and dispatches:
   ``algo.fallbacks`` — digest-equal by construction, the degraded-mode
   contract.
 
+``algo.bfs`` has no fixpoint program: its device path is a frontier
+search over CSRs (``ops/bfs.py``; strategy device-fixpoint).  On a
+device graph as ingested (a ``ScanGraph``: no delta overlay) it reads
+no snapshot: it searches the ingest-time CSRs where they live (layout
+``resident-csr``), brings a few numbers a level to the host, and leaves
+its rows on the device.  Otherwise it searches CSRs built from the
+snapshot (layout ``snapshot-csr``).
+
 Convergence metrics (``iterations``, ``converged``, ``strategy``,
 ``procedure``) ride the operator's op_stats entry into PROFILE and the
 observed-statistics store.
@@ -83,6 +91,23 @@ class AlgoProcedureOp(RelationalOperator):
         registry = self._registry()
         values = [host_eval(a, self.context.parameters) for a in self.args]
         bound = self.signature.bind(values)
+        adjacency = None if self.prefer_host else self._resident_adjacency()
+        if adjacency is not None:
+            try:
+                return self._compute_resident(adjacency, bound, registry)
+            except _CancellationError:
+                raise
+            except Exception:
+                # degraded mode, as below: the snapshot's host twin
+                if registry is not None:
+                    registry.counter("algo.fallbacks").inc()
+                data = self._graph_arrays(bound)
+                self._resolve_source(bound, data)
+                out, iters, converged = self._compute_host(data, bound)
+                self.strategy = "fallback-host"
+                self._layout = "host"
+                self._account(registry, iters, converged)
+                return self._emit(data, out)
         data = self._graph_arrays(bound)
         self._resolve_source(bound, data)
         try:
@@ -104,6 +129,10 @@ class AlgoProcedureOp(RelationalOperator):
             out, iters, converged = self._compute_host(data, bound)
             self.strategy = "fallback-host"
             self._layout = "host"
+        self._account(registry, iters, converged)
+        return self._emit(data, out)
+
+    def _account(self, registry, iters: int, converged: bool) -> None:
         if registry is not None:
             registry.counter("algo.executions").inc()
             registry.counter("algo.iterations").inc(int(iters))
@@ -114,11 +143,93 @@ class AlgoProcedureOp(RelationalOperator):
             "iterations": int(iters),
             "converged": bool(converged),
         }
-        return self._emit(data, out)
 
     def _registry(self):
         session = getattr(self.context, "session", None)
         return getattr(session, "metrics_registry", None)
+
+    # -- resident path: BFS over the ingest-time CSRs ----------------------
+
+    def _resident_adjacency(self) -> Optional["Adjacency"]:
+        """The graph's resident adjacency for ``algo.bfs``, built on first
+        use and kept on the graph: on a single-chip device backend whose
+        scanned tables are the ingested ones (a ``ScanGraph``: no delta
+        overlay), every relationship table with a CSR over its source
+        and its target and every node table with its id index.  None
+        sends the call down the snapshot path."""
+        from caps_tpu.backends.tpu.table import DeviceTable
+        from caps_tpu.relational.graphs import ScanGraph
+        backend = getattr(self.context.factory, "backend", None)
+        graph = self.graph
+        if (self.signature.name != "algo.bfs" or backend is None
+                or backend.mesh is not None or type(graph) is not ScanGraph):
+            return None
+        cached = graph.__dict__.get("_bfs_adjacency")
+        if cached is not None:
+            return cached
+
+        def csr(table, col):
+            if not isinstance(table, DeviceTable) or table.is_local:
+                return None
+            column = table._cols.get(col)
+            return None if column is None else table._csr_for(table, column)
+
+        segments = []
+        for rt in graph.rel_tables:
+            m, t = rt.mapping, rt.table
+            for key, other in ((m.source_col, m.target_col),
+                               (m.target_col, m.source_col)):
+                index = csr(t, key)
+                if index is None:
+                    return None
+                segments.append((index, t._cols[other].data))
+        node_csrs = [csr(nt.table, nt.mapping.id_col)
+                     for nt in graph.node_tables]
+        if not node_csrs or any(c is None for c in node_csrs):
+            return None
+        from caps_tpu.ops.bfs import build_adjacency
+        adjacency = build_adjacency(segments, node_csrs, backend.host_read)
+        graph.__dict__["_bfs_adjacency"] = adjacency
+        return adjacency
+
+    def _compute_resident(self, adjacency, bound: Dict[str, Any], registry):
+        """``algo.bfs`` on the device from the ingest-time CSRs: nothing
+        comes to the host but two numbers a level, and the reached
+        vertices stay on the device as the operator's table."""
+        from caps_tpu.backends.tpu.column import Column
+        from caps_tpu.backends.tpu.table import DeviceTable
+        from caps_tpu.obs import profiler_span
+        from caps_tpu.ops import bfs as BFS
+        from caps_tpu.ops.segment import default_interpret
+        backend = self.context.factory.backend
+        # segments alternate (source CSR, target CSR) table by table
+        n_segments = len(adjacency.indptrs)
+        segments = {"out": range(0, n_segments, 2),
+                    "in": range(1, n_segments, 2),
+                    "both": range(n_segments)}[bound["direction"]]
+        with profiler_span("caps_tpu.algo.bfs"):
+            found = BFS.search(
+                adjacency, tuple(segments), bound["source"],
+                bound["max_depth"], bucket=backend.bucket,
+                read=backend.host_read, use_kernel=backend.use_expand_kernel,
+                interpret=default_interpret(),
+                level_span=lambda: profiler_span("caps_tpu.algo.bfs.level"))
+        if registry is not None:
+            registry.counter("algo.bfs_searches").inc()
+            registry.counter("algo.bfs_levels").inc(found.levels)
+            registry.counter("algo.bfs_edges_examined").inc(found.examined)
+        self.strategy = "device-fixpoint"
+        self._layout = "resident-csr"
+        self._account(registry, found.levels, found.converged)
+        values = {"node": found.ids, "dist": found.dist}
+        columns: Dict[str, Column] = {}
+        header = RecordHeader.empty()
+        for yield_name, out_name in self.yields:
+            ctype = self.signature.yield_type(yield_name)
+            columns[out_name] = Column("int", values[yield_name], found.ok,
+                                       ctype)
+            header = header.concat(RecordHeader.for_value(out_name, ctype))
+        return header, DeviceTable(backend, columns, found.reached)
 
     # -- snapshot seam -----------------------------------------------------
 
@@ -221,6 +332,8 @@ class AlgoProcedureOp(RelationalOperator):
                                             scalar_values)
 
         name = self.signature.name
+        if name == "algo.bfs":
+            return self._bfs_snapshot(data, bound, backend)
         n, e = data.n, int(data.src.shape[0])
         n_pad = backend.bucket(max(n, 1))
         e_pad = backend.bucket(max(e, 1))
@@ -285,6 +398,54 @@ class AlgoProcedureOp(RelationalOperator):
             # turns the constant division into a reciprocal multiply)
             out = np.round(out, kernels.SCORE_DECIMALS)
         return out[:data.n], int(iters), bool(converged)
+
+    def _bfs_snapshot(self, data: _GraphArrays, bound: Dict[str, Any],
+                      backend) -> Tuple[np.ndarray, int, bool]:
+        """``algo.bfs`` on a snapshot: the resident path's search
+        (``ops/bfs.py``) over CSRs built here from the snapshot's edges,
+        in index space; the node index is the identity over ``[0, n)``."""
+        import jax.numpy as jnp
+
+        from caps_tpu.obs import profiler_span
+        from caps_tpu.ops import bfs as BFS
+        from caps_tpu.ops.expand import DeviceCSR
+        from caps_tpu.ops.segment import default_interpret
+        n, e = data.n, int(data.src.shape[0])
+        self._layout = "snapshot-csr"
+        if n == 0:
+            return np.zeros(0, dtype=np.int64), 0, True
+        cap = backend.bucket(max(e, 1))
+
+        def csr(keys: np.ndarray) -> DeviceCSR:
+            perm = np.zeros(cap, dtype=np.int32)
+            perm[:e] = np.argsort(keys, kind="stable")
+            indptr = np.searchsorted(keys[perm[:e]], np.arange(n + 1))
+            return DeviceCSR(jnp.asarray(indptr.astype(np.int32)),
+                             jnp.asarray(perm), n)
+
+        def padded(col: np.ndarray):
+            out = np.zeros(cap, dtype=np.int32)
+            out[:e] = col
+            return jnp.asarray(out)
+
+        ids = np.arange(n + 1, dtype=np.int32)
+        nodes = DeviceCSR(jnp.asarray(ids), jnp.asarray(ids[:n]), n)
+        adjacency = BFS.build_adjacency(
+            [(csr(data.src), padded(data.tgt)),
+             (csr(data.tgt), padded(data.src))], [nodes], backend.host_read)
+        found = BFS.search(
+            adjacency, {"out": (0,), "in": (1,),
+                        "both": (0, 1)}[bound["direction"]],
+            bound["source_index"], bound["max_depth"],
+            bucket=backend.bucket, read=backend.host_read,
+            use_kernel=backend.use_expand_kernel,
+            interpret=default_interpret(),
+            level_span=lambda: profiler_span("caps_tpu.algo.bfs.level"))
+        out = np.full(n, kernels.UNREACHED, dtype=np.int64)
+        reached = found.reached
+        out[np.asarray(found.ids)[:reached]] = \
+            np.asarray(found.dist)[:reached]
+        return out, found.levels, found.converged
 
     # -- host path (oracle twin; also the degraded fallback) ---------------
 

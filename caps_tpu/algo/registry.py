@@ -49,6 +49,8 @@ class ArgSpec:
     name: str
     type_tag: str  # "INTEGER" | "FLOAT" | "STRING"
     default: Any = REQUIRED
+    #: the values a STRING argument may take (empty: any)
+    choices: Tuple[str, ...] = ()
 
     @property
     def required(self) -> bool:
@@ -61,7 +63,8 @@ class ArgSpec:
             return (isinstance(value, (int, float))
                     and not isinstance(value, bool))
         if self.type_tag == "STRING":
-            return isinstance(value, str)
+            return isinstance(value, str) and (
+                not self.choices or value in self.choices)
         return True  # pragma: no cover — no other tags registered
 
     def render(self) -> str:
@@ -119,9 +122,11 @@ class ProcedureSignature:
         (parameter bindings are only checkable at bind time)."""
         spec = self.args[position]
         if not spec.accepts(value):
+            expects = (" one of " + ", ".join(map(repr, spec.choices))
+                       if spec.choices else "")
             raise ProcedureArgumentError(
                 f"procedure {self.name} argument {spec.name!r} "
-                f"(position {position}) expects {spec.type_tag}, "
+                f"(position {position}) expects {spec.type_tag}{expects}, "
                 f"got {value!r}; signature: {self.render()}")
 
     def bind(self, values: Sequence[Any]) -> Dict[str, Any]:
@@ -165,12 +170,16 @@ WCC = _register(ProcedureSignature(
     "weakly connected components by min-label propagation",
     est_iterations=8))
 
+#: which relationships a walk may follow, as ``algo.degree`` counts them
+DIRECTIONS = ("out", "in", "both")
+
 BFS = _register(ProcedureSignature(
     "algo.bfs",
     (ArgSpec("source", "INTEGER"),
-     ArgSpec("max_depth", "INTEGER", -1)),
+     ArgSpec("max_depth", "INTEGER", -1),
+     ArgSpec("direction", "STRING", "out", DIRECTIONS)),
     (YieldSpec("node", CTInteger), YieldSpec("dist", CTInteger)),
-    "unweighted hop distance by frontier relaxation (reachable only)",
+    "unweighted hop distance by frontier search (reachable only)",
     est_iterations=8))
 
 SSSP = _register(ProcedureSignature(
@@ -184,7 +193,7 @@ SSSP = _register(ProcedureSignature(
 
 DEGREE = _register(ProcedureSignature(
     "algo.degree",
-    (ArgSpec("direction", "STRING", "both"),),
+    (ArgSpec("direction", "STRING", "both", DIRECTIONS),),
     (YieldSpec("node", CTInteger), YieldSpec("degree", CTInteger)),
     "per-node degree by segment sum (the warm-up case)",
     est_iterations=1))

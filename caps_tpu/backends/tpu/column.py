@@ -123,7 +123,8 @@ class Column:
 
 def make_column(values: List[Any], ctype: CypherType, capacity: int,
                 pool) -> Column:
-    """Host values → device column (padded to capacity)."""
+    """Host values → device column (padded to capacity).  ``values`` is a
+    list, or for an integer column a numpy integer array (no nulls)."""
     kind = kind_for(ctype)
     n = len(values)
     valid_np = np.zeros(capacity, dtype=bool)
@@ -149,6 +150,16 @@ def make_column(values: List[Any], ctype: CypherType, capacity: int,
         codes = np.asarray(pool.encode_many(list(values)), dtype=np.int32)
         data_np[:n] = np.where(codes >= 0, codes, 0)
         valid_np[:n] = codes >= 0
+        return Column(kind, jnp.asarray(data_np), jnp.asarray(valid_np),
+                      ctype, host=(data_np, valid_np))
+    if (isinstance(values, np.ndarray) and values.dtype.kind in "iu"
+            and kind in ("int", "id")):
+        # a column handed over as an integer array: no value is null
+        if kind == "id" and n:
+            _check_id(int(values.max()))
+            _check_id(int(values.min()))
+        data_np[:n] = values
+        valid_np[:n] = True
         return Column(kind, jnp.asarray(data_np), jnp.asarray(valid_np),
                       ctype, host=(data_np, valid_np))
     fast = None if kind in ("date", "datetime") \

@@ -163,6 +163,49 @@ def sorted_segment_agg(values, ok, seg_id, num_segments: int, kind: str):
     prev = jnp.concatenate([jnp.zeros(1, cum.dtype), cum[:-1]])
     return cum - prev
 
+@jax.jit
+def small_int_span(keys, has):
+    """``[lowest key, highest - lowest]`` over the rows ``has`` marks
+    (``[max, 0]`` where it marks none; a span past int64 reads as the
+    int64 maximum)."""
+    top = jnp.iinfo(jnp.int64).max
+    lo = jnp.min(jnp.where(has, keys, top))
+    hi = jnp.max(jnp.where(has, keys, -top - 1))
+    span = jnp.where(hi - lo < 0, top, hi - lo)
+    return jnp.stack([lo, jnp.where(has.any(), span, 0)])
+
+
+@functools.partial(jax.jit, static_argnames=("slots", "kinds"))
+def small_int_group(keys, has, lo, row_ok, values, valid, *, slots: int,
+                    kinds: tuple):
+    """Group-by over int ``keys`` in ``[lo, lo + slots - 1)``, where
+    ``has`` marks a live row with a key (slot ``key - lo``; the others
+    take the last slot): for each slot its live rows, and for each
+    aggregate (``kinds``: count, sum, min, max over ``values``/``valid``)
+    its value and whether it has one -- a masked reduction over every row
+    a slot, fused, with no sort."""
+    codes = jnp.where(has, keys - lo, slots - 1).astype(jnp.int32)
+    hit = ((codes[None, :] == jnp.arange(slots, dtype=jnp.int32)[:, None])
+           & row_ok[None, :])
+    out = []
+    for kind, v, ok in zip(kinds, values, valid):
+        mine = hit & ok[None, :]
+        has = mine.any(axis=1)
+        if kind == "count":
+            out.append((mine.sum(axis=1, dtype=jnp.int64), has))
+        elif kind == "sum":
+            out.append((jnp.where(mine, v.astype(jnp.int64)[None, :], 0)
+                        .sum(axis=1), has))
+        else:
+            info = jnp.iinfo(v.dtype)
+            if kind == "min":
+                data = jnp.where(mine, v[None, :], info.max).min(axis=1)
+            else:
+                data = jnp.where(mine, v[None, :], info.min).max(axis=1)
+            out.append((data, has))
+    return hit.sum(axis=1, dtype=jnp.int64), out
+
+
 @functools.partial(jax.jit, static_argnames=("num_segments", "kind"))
 def segment_agg(values, ok, seg_id, num_segments: int, kind: str):
     """One aggregation over sorted segments.  ``ok`` masks nulls+padding."""

@@ -96,14 +96,17 @@ def _expand_kernel(blk_ref, seg_base_ref, total_ref,
     valid_out[:] = (tt < total_ref[0]).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("out_cap", "interpret"))
+@functools.partial(jax.jit, static_argnames=("out_cap", "interpret",
+                                             "compacted"))
 def expand_positions(counts: jnp.ndarray, lo: jnp.ndarray, out_cap: int,
-                     interpret: bool = False
+                     interpret: bool = False, compacted: bool = False
                      ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """For each output slot t in [0, out_cap): the left row index it
     expands from, the match position ``lo[row] + within``, and validity.
 
     counts: (cap_l,) >=0 int; lo: (cap_l,) int — per-row match start.
+    ``compacted``: the caller's rows hold no empty row before a
+    non-empty one, so the prelude's sort is skipped.
     Returns (l_idx int32, r_pos int32, out_valid bool), each (out_cap,).
     """
     cap_l = counts.shape[0]
@@ -117,8 +120,12 @@ def expand_positions(counts: jnp.ndarray, lo: jnp.ndarray, out_cap: int,
     counts32 = counts.astype(jnp.int32)
     # -- prelude (XLA): compact away zero-count rows, by one key sort that
     # carries each row's count and match start along (no gather) --------
-    key, nz_counts, lo_nz = kept_first(counts32 > 0, counts32,
-                                       lo.astype(jnp.int32))
+    if compacted:
+        key = jnp.arange(cap_l, dtype=jnp.int32)
+        nz_counts, lo_nz = counts32, lo.astype(jnp.int32)
+    else:
+        key, nz_counts, lo_nz = kept_first(counts32 > 0, counts32,
+                                           lo.astype(jnp.int32))
     slot_live = key < cap_l
     nz_counts = jnp.where(slot_live, nz_counts, 0)
     offsets = jnp.cumsum(nz_counts, dtype=jnp.int32)        # (cap_l,)

@@ -534,12 +534,13 @@ def failing_wcoj(exc: ExcSpec = None, n_times: Optional[int] = 1):
 
 @contextlib.contextmanager
 def failing_algo(exc: ExcSpec = None, n_times: Optional[int] = 1):
-    """Fail the graph-algorithm procedure's DEVICE fixpoint path
-    (algo/op.py ``AlgoProcedureOp._compute_device``) — the analytics
-    tier's degraded-mode probe: the operator must catch the fault, count
-    ``algo.fallbacks``, and serve the SAME answer through the NumPy
-    host kernel (``algo/kernels.py``), so fallback-parity tests are
-    deterministic instead of hoping for a real device fault.
+    """Fail the graph-algorithm procedure's DEVICE paths (algo/op.py
+    ``AlgoProcedureOp._compute_device``, the snapshot path, and
+    ``._compute_resident``, the search over the resident CSRs) — the
+    analytics tier's degraded-mode probe: the operator must catch the
+    fault, count ``algo.fallbacks``, and serve the SAME answer through
+    the NumPy host kernel (``algo/kernels.py``), so fallback-parity tests
+    are deterministic instead of hoping for a real device fault.
 
     A FRESH exception per injection (``exc`` semantics as
     :func:`failing_operator`; default a realistic device OOM), stamped
@@ -552,25 +553,29 @@ def failing_algo(exc: ExcSpec = None, n_times: Optional[int] = 1):
     ``faults.injected.algo``.  Yields the budget (``.injected``)."""
     from caps_tpu.algo.op import AlgoProcedureOp
     budget = _Budget(n_times)
+    names = ("_compute_device", "_compute_resident")
 
-    with OPERATOR_PATCH._lock:
-        orig = AlgoProcedureOp._compute_device
-
-        def faulted(op_self, data, bound):
+    def faulty(orig):
+        def faulted(op_self, *args):
             if budget.take():
                 _count_injection("algo")
                 e = _fresh_exception(exc)
                 if getattr(e, "caps_algo_fault", None) is None:
                     e.caps_algo_fault = True
                 raise e
-            return orig(op_self, data, bound)
+            return orig(op_self, *args)
+        return faulted
 
-        AlgoProcedureOp._compute_device = faulted
+    with OPERATOR_PATCH._lock:
+        origs = {n: getattr(AlgoProcedureOp, n) for n in names}
+        for n in names:
+            setattr(AlgoProcedureOp, n, faulty(origs[n]))
     try:
         yield budget
     finally:
         with OPERATOR_PATCH._lock:
-            AlgoProcedureOp._compute_device = orig
+            for n in names:
+                setattr(AlgoProcedureOp, n, origs[n])
 
 
 def _make_device_down(device_index: int) -> BaseException:

@@ -95,7 +95,8 @@ def test_self_loops_and_parallel_edges_parity():
 def test_dense_tile_layout_parity():
     """A graph dense enough to approach the full capacity tile routes to
     the matrix-product (dense-tile) program family — a physical layout
-    choice that must never change results vs the NumPy oracle."""
+    choice that must never change results vs the NumPy oracle; BFS
+    searches CSRs at any density."""
     def dense(session, n=64, m=8192, seed=5):
         rng = np.random.RandomState(seed)
         nodes = {("P",): [{"_id": i} for i in range(n)]}
@@ -104,15 +105,25 @@ def test_dense_tile_layout_parity():
                      np.round(rng.rand(m) * 9 + 1, 3))]
         return make_graph(session, nodes, {"K": edges})
 
+    from caps_tpu.relational.updates import versioned
     local = dense(LocalCypherSession())
-    device = dense(TPUCypherSession())
+    s = TPUCypherSession()
+    device = dense(s)
+    # algo.bfs searches CSRs, never a tile (tests/test_algo_bfs.py): the
+    # ingested graph's own, or through a versioned handle the snapshot's
+    snapshot = versioned(s, device)
     for q in PROCEDURE_QUERIES:
-        profiled = device.cypher("PROFILE " + q)
+        bfs = q.startswith("CALL algo.bfs")
+        profiled = (s.cypher_on_graph(snapshot, "PROFILE " + q) if bfs
+                    else device.cypher("PROFILE " + q))
         (op,) = _algo_op(profiled)
         assert op["strategy"] == "device-fixpoint", q
-        assert op["layout"] == "dense-tile", q
+        assert op["layout"] == ("snapshot-csr" if bfs else "dense-tile"), q
         assert profiled.records.to_maps() == \
             local.cypher(q).records.to_maps(), q
+        if bfs:
+            (op,) = _algo_op(device.cypher("PROFILE " + q))
+            assert op["layout"] == "resident-csr"
     # the ordinary sparse graph keeps the edge-list layout
     sparse = _random_graph(TPUCypherSession())
     (op,) = _algo_op(sparse.cypher("PROFILE " + PROCEDURE_QUERIES[1]))

@@ -199,6 +199,86 @@ def test_distinct_aggregates_stay_on_device():
     assert session.fallback_count == before, session.backend.fallback_reasons
 
 
+# -- keyed group-by over a small int range (table.py _group_small_int) --------
+
+SMALL_INT_GROUPS = {
+    "every_aggregate": "MATCH (p:P) RETURN p.k AS k, count(*) AS c, "
+                       "count(p.v) AS cv, sum(p.v) AS s, min(p.v) AS lo, "
+                       "max(p.v) AS hi ORDER BY k",
+    "bool_sum_no_order": "MATCH (p:P) RETURN p.k AS k, count(*) AS c, "
+                         "sum(p.b) AS sb",
+    "null_key_only": "MATCH (p:P) WHERE p.k IS NULL "
+                     "RETURN p.k AS k, count(*) AS c, sum(p.v) AS s",
+    "no_rows": "MATCH (p:P) WHERE p.k > 100 RETURN p.k AS k, count(*) AS c",
+    "wide_key_sorts": "MATCH (p:P) RETURN p.v AS k, count(*) AS c ORDER BY k",
+    "avg_sorts": "MATCH (p:P) RETURN p.k AS k, avg(p.v) AS a ORDER BY k",
+}
+
+
+def _small_int_graphs():
+    from caps_tpu.okapi.types import CTBoolean, CTInteger
+    from caps_tpu.relational.entity_tables import NodeMapping, NodeTable
+    rng = np.random.default_rng(3)
+    n = 300
+    cols = {
+        "_id": list(range(n)),
+        "k": [None if rng.random() < 0.1 else int(x)
+              for x in rng.integers(-3, 9, n)],
+        "v": [None if rng.random() < 0.2 else int(x)
+              for x in rng.integers(-2 ** 40, 2 ** 40, n)],
+        "b": [None if rng.random() < 0.2 else bool(x)
+              for x in rng.integers(0, 2, n)]}
+    types = {"_id": CTInteger, "k": CTInteger.nullable,
+             "v": CTInteger.nullable, "b": CTBoolean.nullable}
+    out = []
+    for session in (TPUCypherSession(), LocalCypherSession()):
+        table = NodeTable(
+            NodeMapping.on("_id").with_implied_labels("P")
+            .with_property("k").with_property("v").with_property("b"),
+            session.table_factory.from_columns(cols, types))
+        out.append(session.create_graph([table], []))
+    return out
+
+
+@pytest.fixture(scope="module")
+def small_int_graphs():
+    return _small_int_graphs()
+
+
+@pytest.mark.parametrize("case", list(SMALL_INT_GROUPS))
+def test_small_int_group_by_matches_the_oracle(small_int_graphs, case,
+                                               monkeypatch):
+    """A keyed group-by over one int key spanning a few values takes
+    masked reductions, not a sort: the same groups, values and order as
+    the sorted path (key ascending, the null key last), and the oracle's
+    multiset; a key too wide, or an aggregate it does not take, keeps
+    the sorted path."""
+    from caps_tpu.backends.tpu import kernels as K
+    device, local = small_int_graphs
+    q = SMALL_INT_GROUPS[case]
+    calls = []
+    orig = K.small_int_group
+    monkeypatch.setattr(K, "small_int_group",
+                        lambda *a, **k: calls.append(1) or orig(*a, **k))
+    got = device.cypher(q).records.to_maps()
+    assert Bag(got) == Bag(local.cypher(q).records.to_maps())
+    assert bool(calls) == (case not in ("wide_key_sorts", "avg_sorts"))
+    import caps_tpu.backends.tpu.table as T
+    monkeypatch.setattr(T, "SMALL_INT_GROUPS", 0)
+    assert device.cypher(q).records.to_maps() == got
+
+
+def test_small_int_group_by_replays_wider_spans_exactly():
+    """The span is a ``cap`` read: a generic replay recorded on a narrow
+    span serves a wider one only through the violation check."""
+    device, local = _small_int_graphs()
+    q = ("MATCH (p:P) WHERE p.k < $m RETURN p.k AS k, count(*) AS c, "
+         "sum(p.v) AS s ORDER BY k")
+    for m in (0, 2, 9, 1, 9):
+        assert device.cypher(q, {"m": m}).records.to_maps() \
+            == local.cypher(q, {"m": m}).records.to_maps()
+
+
 # -- persistent compile cache placement (backends/tpu/table.py) -------------
 
 @pytest.mark.parametrize("env_dir", [None, "/some/dir"])

@@ -21,6 +21,7 @@ compiled here from a small graph's own argument shapes.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,6 +140,33 @@ def test_csr_probe_compiles_without_a_loop(one_chip, n_keys, cap_l):
     searched = probe_count.lower(
         keys, ok, one_chip((n_keys,), jnp.int64)).compile()
     assert "while" in searched.as_text()
+
+
+def test_bfs_level_programs_compile(one_chip):
+    """The search's programs (caps_tpu/ops/bfs.py) at a small graph's
+    shapes: a level's row layout and a chunk of its stream with the
+    expand kernel in it; the only scatter marks the vertices found, in
+    a loop, and no program sorts more than a chunk's keys."""
+    from caps_tpu.ops import bfs as B
+    domain, pad, chunk = 1 << 16, B.CHUNK + 1, B.CHUNK
+    i32 = lambda *shape: one_chip(shape, jnp.int32)         # noqa: E731
+    dist, ips = i32(domain), (i32(domain + 1), i32(domain + 1))
+    flag = one_chip((), jnp.bool_)
+    rows = B.bfs_rows.lower(dist, i32(), flag, ips, ips, i32(2), i32(),
+                            i32(), pad=pad).compile()
+    assert "scatter" not in rows.as_text()
+    n = 2 * domain + chunk + pad
+    step = B.bfs_chunk.lower(
+        dist, i32(1 << 21), i32(n), i32(n), i32(n), i32(n), i32(), i32(),
+        flag, chunk=chunk, kernel=True, interpret=False).compile()
+    assert _has_kernel(step)
+    assert step.as_text().count("scatter(") == 1
+    emit = B.bfs_emit.lower(dist, cap=domain).compile()
+    assert "scatter" not in emit.as_text()
+    for program in (rows, step, emit):
+        sorts = re.findall(r"= \(?[a-z0-9]+\[(\d+)\][^=]* sort\(",
+                           program.as_text())
+        assert sorts and max(int(x) for x in sorts) <= chunk
 
 
 def test_fused_count_program_compiles(one_chip):
