@@ -194,7 +194,7 @@ class AlgoProcedureOp(RelationalOperator):
 
     def _compute_resident(self, adjacency, bound: Dict[str, Any], registry):
         """``algo.bfs`` on the device from the ingest-time CSRs: nothing
-        comes to the host but two numbers a level, and the reached
+        comes to the host but a few numbers a level, and the reached
         vertices stay on the device as the operator's table."""
         from caps_tpu.backends.tpu.column import Column
         from caps_tpu.backends.tpu.table import DeviceTable
@@ -218,6 +218,8 @@ class AlgoProcedureOp(RelationalOperator):
             registry.counter("algo.bfs_searches").inc()
             registry.counter("algo.bfs_levels").inc(found.levels)
             registry.counter("algo.bfs_edges_examined").inc(found.examined)
+            registry.counter("algo.bfs_passes").inc(found.passes)
+            registry.counter("algo.bfs_chunks").inc(found.chunks)
         self.strategy = "device-fixpoint"
         self._layout = "resident-csr"
         self._account(registry, found.levels, found.converged)

@@ -17,18 +17,22 @@ Asanovic, Patterson, SC 2012):
   first, and the rest only of the rows that found nothing: most unseen
   vertices of a large level find the frontier at once.
 
-Either way a level is a stream of adjacency entries: ``bfs_rows`` lays
-the chosen rows out (the non-empty rows first, and a prefix sum of their
-lengths), and ``bfs_chunk`` walks the stream ``CHUNK`` entries at a
-time: the expand kernel (``expand_positions``) over the rows the window
-covers, the neighbour ids, a gather of their distances, a key sort that
-keeps each vertex found once, and a scatter of those, ``_MARK`` lanes at
-a time: a search writes each vertex it reaches once, and fewer than
-``_MARK`` lanes more a chunk.  Every program has a shape fixed by the
-graph, not by the level, so a search compiles the same few programs
-whatever its root.  The host reads a few numbers a level; the distances
-stay on the device and come out as a table of reached vertices in id
-order.
+Either way a level is one or two passes over a stream of adjacency
+entries, and a pass is one program (``bfs_pass``): it lays the chosen
+rows out (``_rows``: the non-empty rows first, and a prefix sum of
+their lengths), then walks the stream ``CHUNK`` entries at a time in a
+device loop whose carry is the distances (``_chunk``): the expand
+kernel (``expand_positions``) over the rows the window covers, the
+neighbour ids, a gather of their distances, a key sort that keeps each
+vertex found once, and a scatter of those, ``_MARK`` lanes at a time:
+a search writes each vertex it reaches once, and fewer than ``_MARK``
+lanes more a chunk.  The pass's length stays on the device; the host
+launches one program a pass and reads one stack of numbers a level
+(``bfs_level``: the next frontier's size and rows, and the level's
+pass lengths).  Every program has a shape fixed by the graph, not by
+the level, so a search compiles the same few programs whatever its
+root.  The distances stay on the device and come out as a table of
+reached vertices in id order.
 
 No program sorts more than ``CHUNK`` keys at once, and no prefix sum
 runs as one scan over a whole array: the TPU's compiler takes about a
@@ -186,10 +190,9 @@ def bfs_start(root, node_indptrs, degree, rdegree):
     return dist.astype(jnp.int32), head
 
 
-@functools.partial(jax.jit, static_argnames=("pad",))
-def bfs_rows(dist, level, down, indptrs, rindptrs, offsets, skip, take, *,
-             pad: int):
-    """A level's stream: top-down (``down``) the rows of the vertices at
+def _rows(dist, level, down, indptrs, rindptrs, offsets, skip, take, *,
+          pad: int):
+    """A pass's stream: top-down (``down``) the rows of the vertices at
     ``level``, else the rows the other way (``rindptrs``) of the unseen
     ones; of each row the entries from ``skip`` on, at most ``take``
     (``offsets``: where each row set's entries start in ``nbr``).
@@ -216,11 +219,9 @@ def bfs_rows(dist, level, down, indptrs, rindptrs, offsets, skip, take, *,
             grow(vertex), ends[-1])
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "kernel",
-                                             "interpret"))
-def bfs_chunk(dist, nbr, ends, counts, lo, vertex, base, level, down, *,
-              chunk: int, kernel: bool, interpret: bool):
-    """Entries ``[base, base + chunk)`` of a level's stream (``bfs_rows``):
+def _chunk(dist, nbr, ends, counts, lo, vertex, base, level, down, *,
+           chunk: int, kernel: bool, interpret: bool):
+    """Entries ``[base, base + chunk)`` of a pass's stream (``_rows``):
     the vertices they find -- top-down the unseen neighbours, bottom-up
     the rows' own vertices whose neighbour is at ``level`` -- each marked
     ``level + 1`` once."""
@@ -261,14 +262,38 @@ def bfs_chunk(dist, nbr, ends, counts, lo, vertex, base, level, down, *,
                              mark, dist)
 
 
+@functools.partial(jax.jit, static_argnames=("pad", "chunk", "kernel",
+                                             "interpret"))
+def bfs_pass(dist, nbr, level, down, indptrs, rindptrs, offsets, skip,
+             take, *, pad: int, chunk: int, kernel: bool, interpret: bool):
+    """One pass of a level in one program: its stream laid out
+    (``_rows``), then walked ``chunk`` entries at a time by a device loop
+    over ``_chunk`` that carries the distances.  Returns them and the
+    stream's length, which stays on the device."""
+    *rows, length = _rows(dist, level, down, indptrs, rindptrs, offsets,
+                          skip, take, pad=pad)
+
+    def step(carry):
+        base, dist = carry
+        return base + chunk, _chunk(dist, nbr, *rows, base, level, down,
+                                    chunk=chunk, kernel=kernel,
+                                    interpret=interpret)
+
+    _end, dist = jax.lax.while_loop(lambda carry: carry[0] < length, step,
+                                    (jnp.int32(0), dist))
+    return dist, length
+
+
 @jax.jit
-def bfs_level(dist, level, degree, rdegree):
+def bfs_level(dist, level, degree, rdegree, lengths):
     """``[vertices at level, their entries, their entries the other
-    way]``."""
+    way]``, then the lengths of the passes that found them
+    (``lengths``): one read brings back all the host needs of a level."""
     at = dist == level
     return jnp.stack([at.sum(dtype=jnp.int32),
                       jnp.where(at, degree, 0).sum(dtype=jnp.int32),
-                      jnp.where(at, rdegree, 0).sum(dtype=jnp.int32)])
+                      jnp.where(at, rdegree, 0).sum(dtype=jnp.int32),
+                      *lengths])
 
 
 @functools.partial(jax.jit, static_argnames=("cap",))
@@ -285,14 +310,17 @@ def bfs_emit(dist, *, cap: int):
 @dataclasses.dataclass
 class Search:
     """What one search leaves: the reached vertices (``ids``, ``dist``,
-    ``ok``: device arrays of one bucket), how many, the levels expanded
-    and the adjacency entries read."""
+    ``ok``: device arrays of one bucket), how many, the levels expanded,
+    the adjacency entries read, and the passes and ``CHUNK``-entry
+    chunks they took."""
     ids: jnp.ndarray
     dist: jnp.ndarray
     ok: jnp.ndarray
     reached: int
     levels: int
     examined: int
+    passes: int
+    chunks: int
     converged: bool
 
 
@@ -303,52 +331,54 @@ def search(adj: Adjacency, segments: Sequence[int], root: int,
     """Hop distances from ``root`` over ``segments`` (indices into
     ``adj``; segment ``k ^ 1`` is ``k`` the other way), at most
     ``max_depth`` levels (< 0: no limit).  ``bucket`` rounds a size to a
-    capacity, ``read`` is the counted device->host read,
-    ``use_kernel(chunk)`` says whether the expand kernel serves a chunk,
-    ``interpret`` whether it runs in Pallas' interpreter,
-    ``level_span()`` opens the span of one level."""
+    capacity, ``read`` is the counted device->host read (one for the
+    root, then one a level), ``use_kernel(chunk)`` says whether the
+    expand kernel serves a pass's chunks, ``interpret`` whether it runs
+    in Pallas' interpreter, ``level_span()`` opens the span of one
+    level."""
     back = tuple(k ^ 1 for k in segments)
     indptrs = tuple(adj.indptrs[k] for k in segments)
     rindptrs = tuple(adj.indptrs[k] for k in back)
-    offsets = {True: jnp.asarray([adj.offsets[k] for k in segments],
-                                 jnp.int32),
-               False: jnp.asarray([adj.offsets[k] for k in back],
-                                  jnp.int32)}
+    offsets = {True: np.asarray([adj.offsets[k] for k in segments],
+                                np.int32),
+               False: np.asarray([adj.offsets[k] for k in back], np.int32)}
     degree, rdegree = bfs_degree(indptrs), bfs_degree(rindptrs)
-    dist, head = bfs_start(jnp.asarray(root, jnp.int64), adj.node_indptrs,
-                           degree, rdegree)
+    dist, head = bfs_start(np.int64(root), adj.node_indptrs, degree,
+                           rdegree)
     ok, f_rows, f_back = (int(x) for x in read(head))
     f_n, reached = ok, ok
     # entries the other way of the vertices not reached yet
     unseen_rows = adj.rows(back) - f_back
-    levels = examined = 0
+    levels = examined = passes = chunks = 0
 
-    def walk(down: bool, skip: int, take: int, length=None) -> int:
-        """Stream one pass of a level; returns its length."""
+    def walk(down: bool, skip: int, take: int):
+        """One pass of the level; its length, on the device.  The host
+        scalars go in as numpy ones: no conversion program each."""
         nonlocal dist
-        rows = bfs_rows(dist, jnp.int32(levels), down, indptrs, rindptrs,
-                        offsets[down], jnp.int32(skip), jnp.int32(take),
-                        pad=CHUNK + 1)
-        if length is None:
-            length = int(read(rows[-1]))
-        for base in range(0, length, CHUNK):
-            dist = bfs_chunk(dist, adj.nbr, *rows[:-1], jnp.int32(base),
-                             jnp.int32(levels), down, chunk=CHUNK,
-                             kernel=use_kernel(CHUNK), interpret=interpret)
+        dist, length = bfs_pass(dist, adj.nbr, np.int32(levels), down,
+                                indptrs, rindptrs, offsets[down],
+                                np.int32(skip), np.int32(take),
+                                pad=CHUNK + 1, chunk=CHUNK,
+                                kernel=use_kernel(CHUNK),
+                                interpret=interpret)
         return length
 
     big = int(np.iinfo(np.int32).max)
     while f_n and (max_depth < 0 or levels < max_depth):
         with level_span():
             if unseen_rows + len(segments) * adj.domain < f_rows:
-                examined += walk(False, 0, _TRIES)
-                examined += walk(False, _TRIES, big)
+                lengths = (walk(False, 0, _TRIES), walk(False, _TRIES, big))
             else:
-                examined += walk(True, 0, big, f_rows)
-            f_n, f_rows, f_back = (int(x) for x in read(
-                bfs_level(dist, jnp.int32(levels + 1), degree, rdegree)))
+                lengths = (walk(True, 0, big),)
+            f_n, f_rows, f_back, *lengths = (int(x) for x in read(
+                bfs_level(dist, np.int32(levels + 1), degree, rdegree,
+                          lengths)))
         levels += 1
         reached += f_n
         unseen_rows -= f_back
+        examined += sum(lengths)
+        passes += len(lengths)
+        chunks += sum(-(-x // CHUNK) for x in lengths)
     ids, d, ok_rows = bfs_emit(dist, cap=bucket(max(reached, 1)))
-    return Search(ids, d, ok_rows, reached, levels, examined, f_n == 0)
+    return Search(ids, d, ok_rows, reached, levels, examined, passes,
+                  chunks, f_n == 0)

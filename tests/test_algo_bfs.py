@@ -157,14 +157,55 @@ def test_levels_go_both_ways(kron, monkeypatch):
     unseen: that level goes bottom-up, the first and the last top-down."""
     n, src, tgt, _session, graph = kron
     ways = []
-    orig = BFS.bfs_rows
-    monkeypatch.setattr(BFS, "bfs_rows", lambda *a, **k: ways.append(
-        "top-down" if a[2] else "bottom-up") or orig(*a, **k))
+    orig = BFS.bfs_pass
+    monkeypatch.setattr(BFS, "bfs_pass", lambda *a, **k: ways.append(
+        "top-down" if a[3] else "bottom-up") or orig(*a, **k))
     # a neighbour of the hub: the hub's rows are the second level's
     root = int(tgt[np.flatnonzero(src == _hub(src, tgt))[0]])
     assert _run(graph, root, -1, "both") \
         == _queue_bfs(n, src, tgt, root, "both")
     assert ways[0] == "top-down" and "bottom-up" in ways
+
+
+@pytest.mark.parametrize("scale", [SCALE, 13])
+def test_a_search_reads_once_a_level(monkeypatch, scale):
+    """One counted read for the root and one a level, each bringing the
+    level's pass lengths back; the counters add those up.  At scale 13
+    the hub's level streams more than one ``CHUNK``, so the pass walks
+    several chunks in its device loop."""
+    n, src, tgt = _edges(scale)
+    session = TPUCypherSession()
+    graph = _graph(session, n, src, tgt)
+    reads, searches = [], []
+    orig = BFS.search
+
+    def spy(*a, read, **k):
+        def counted(x):
+            out = read(x)
+            reads.append([int(v) for v in out])
+            return out
+        searches.append(orig(*a, read=counted, **k))
+        return searches[-1]
+
+    monkeypatch.setattr(BFS, "search", spy)
+    root = int(tgt[np.flatnonzero(src == _hub(src, tgt))[0]])
+    before = session.metrics_snapshot()
+    assert _run(graph, root, -1, "both") \
+        == _queue_bfs(n, src, tgt, root, "both")
+    after = session.metrics_snapshot()
+    (found,) = searches
+    assert len(reads) == found.levels + 1
+    lengths = [x for level in reads[1:] for x in level[3:]]
+    assert any(len(level) == 5 for level in reads[1:])   # a bottom-up one
+    assert found.examined == sum(lengths)
+    assert found.passes == len(lengths)
+    assert found.chunks == sum(-(-x // BFS.CHUNK) for x in lengths)
+    if scale > SCALE:
+        assert max(lengths) > BFS.CHUNK
+    for name, want in (("algo.bfs_passes", found.passes),
+                       ("algo.bfs_chunks", found.chunks),
+                       ("algo.bfs_edges_examined", found.examined)):
+        assert after[name] - before.get(name, 0) == want
 
 
 def test_the_local_oracle_agrees(kron):

@@ -142,21 +142,51 @@ def test_csr_probe_compiles_without_a_loop(one_chip, n_keys, cap_l):
     assert "while" in searched.as_text()
 
 
+def _sorts_within(compiled, keys: int) -> bool:
+    sorts = re.findall(r"= \(?[a-z0-9]+\[(\d+)\][^=]* sort\(",
+                       compiled.as_text())
+    return bool(sorts) and max(int(x) for x in sorts) <= keys
+
+
+def _kernel_only_in_loops(compiled) -> bool:
+    """The compiled program runs a Pallas kernel, and every computation
+    that holds one is reached from the entry only through a ``while``'s
+    body: the kernel runs inside a device loop."""
+    text = compiled.as_text()
+    bodies = dict(re.findall(r"^(?:ENTRY )?%(\S+) [^\n]*\{\n(.*?)\n\}$",
+                             text, re.M | re.S))
+    entry = re.search(r"^ENTRY %(\S+) ", text, re.M).group(1)
+    outside, todo = set(), [entry]
+    while todo:
+        name = todo.pop()
+        if name in outside:
+            continue
+        outside.add(name)
+        body = re.sub(r"body=%\S+", "", bodies[name])
+        todo += re.findall(r"%([\w.\-]+)", " ".join(
+            re.findall(r"(?:calls|to_apply|condition|branch_computations)"
+                       r"=\{?([^}\s]+(?:, %[^}\s,]+)*)", body)))
+    holders = [k for k, v in bodies.items() if "tpu_custom_call" in v]
+    return bool(holders) and not outside.intersection(holders)
+
+
 def test_bfs_level_programs_compile(one_chip):
-    """The search's programs (caps_tpu/ops/bfs.py) at a small graph's
-    shapes: a level's row layout and a chunk of its stream with the
-    expand kernel in it; the only scatter marks the vertices found, in
-    a loop, and no program sorts more than a chunk's keys."""
+    """The search's traced pieces (caps_tpu/ops/bfs.py) at a small
+    graph's shapes, each compiled alone: a pass's row layout and a chunk
+    of its stream with the expand kernel in it; the only scatter marks
+    the vertices found, in a loop, and no program sorts more than a
+    chunk's keys."""
     from caps_tpu.ops import bfs as B
     domain, pad, chunk = 1 << 16, B.CHUNK + 1, B.CHUNK
     i32 = lambda *shape: one_chip(shape, jnp.int32)         # noqa: E731
     dist, ips = i32(domain), (i32(domain + 1), i32(domain + 1))
     flag = one_chip((), jnp.bool_)
-    rows = B.bfs_rows.lower(dist, i32(), flag, ips, ips, i32(2), i32(),
-                            i32(), pad=pad).compile()
+    rows = jax.jit(B._rows, static_argnames="pad").lower(
+        dist, i32(), flag, ips, ips, i32(2), i32(), i32(), pad=pad).compile()
     assert "scatter" not in rows.as_text()
     n = 2 * domain + chunk + pad
-    step = B.bfs_chunk.lower(
+    step = jax.jit(B._chunk, static_argnames=(
+        "chunk", "kernel", "interpret")).lower(
         dist, i32(1 << 21), i32(n), i32(n), i32(n), i32(n), i32(), i32(),
         flag, chunk=chunk, kernel=True, interpret=False).compile()
     assert _has_kernel(step)
@@ -164,9 +194,25 @@ def test_bfs_level_programs_compile(one_chip):
     emit = B.bfs_emit.lower(dist, cap=domain).compile()
     assert "scatter" not in emit.as_text()
     for program in (rows, step, emit):
-        sorts = re.findall(r"= \(?[a-z0-9]+\[(\d+)\][^=]* sort\(",
-                           program.as_text())
-        assert sorts and max(int(x) for x in sorts) <= chunk
+        assert _sorts_within(program, chunk)
+
+
+def test_bfs_pass_is_one_program(one_chip):
+    """A level pass (``bfs_pass``) is one program at a small graph's
+    shapes: the row layout, then the chunk loop on the device with the
+    expand kernel inside the ``while``; one scatter (the mark), and no
+    sort over a chunk's keys."""
+    from caps_tpu.ops import bfs as B
+    domain = 1 << 16
+    i32 = lambda *shape: one_chip(shape, jnp.int32)         # noqa: E731
+    ips = (i32(domain + 1), i32(domain + 1))
+    one_pass = B.bfs_pass.lower(
+        i32(domain), i32(1 << 21), i32(), one_chip((), jnp.bool_), ips, ips,
+        i32(2), i32(), i32(), pad=B.CHUNK + 1, chunk=B.CHUNK, kernel=True,
+        interpret=False).compile()
+    assert _kernel_only_in_loops(one_pass)
+    assert one_pass.as_text().count("scatter(") == 1
+    assert _sorts_within(one_pass, B.CHUNK)
 
 
 def test_fused_count_program_compiles(one_chip):
